@@ -1,7 +1,8 @@
 """Split device time of the packed verify pipeline: XLA prelude (unpack,
 SHA-512, scalar reduce, window build) vs the fused pallas tail.
 
-Run on real TPU (no platform override). Slope-timed like prof_calls.py.
+Run on real TPU (no platform override). Slope-timed: k back-to-back
+dispatches minus one.
 """
 
 import os

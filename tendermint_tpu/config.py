@@ -284,12 +284,6 @@ class CryptoConfig:
     genesis validator must use it, with proofs of possession in the
     genesis doc (MIGRATION.md).
 
-    compile_cache_dir roots the compile-once kernel layer
-    (crypto/kernel_cache.py): the persistent XLA compilation cache plus
-    the AOT-serialized executable store live under it, so device
-    kernels compile once per MACHINE instead of per process. "" turns
-    both layers off (every process compiles from scratch).
-
     coalesce_window_ms > 0 turns on the cross-height verify scheduler:
     verify_async calls arriving within the window are merged into one
     device dispatch (up to coalesce_max_batch signatures), so pipelined
@@ -300,7 +294,6 @@ class CryptoConfig:
     async_dispatch: bool = True
     sig_cache_size: int = 65536
     key_type: str = "ed25519"
-    compile_cache_dir: str = "~/.cache/tendermint-tpu/xla"
     coalesce_window_ms: float = 0.0
     coalesce_max_batch: int = 8192
 

@@ -37,6 +37,7 @@ Two cross-cutting layers sit in front of every backend:
 
 from __future__ import annotations
 
+import logging
 import os
 import queue as _queue
 import threading
@@ -44,6 +45,8 @@ import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from ..libs import tracing
+
+LOG = logging.getLogger("crypto.batch")
 
 Triple = Tuple[bytes, bytes, bytes]  # (message, signature, pubkey)
 
@@ -683,9 +686,7 @@ def set_calibrated_batch_min(n: int) -> None:
     """Record the MEASURED device break-even (verify.warmup calibrates:
     one compiled-dispatch round trip vs the serial per-signature cost on
     the hardware actually attached). Consulted whenever TM_TPU_BATCH_MIN
-    is not explicitly set, so the device is only used where it wins —
-    e.g. a remote-tunnel TPU with ~64ms round trips calibrates to
-    hundreds, while direct-attached hardware calibrates to ~tens."""
+    is not explicitly set, so the device is only used where it wins."""
     global _calibrated_min
     with _default_lock:
         _calibrated_min = max(1, int(n))
@@ -732,7 +733,11 @@ def default_backend_name() -> str:
     with _default_lock:
         if _default_name is None:
             env = os.environ.get("TM_TPU_CRYPTO_BACKEND")
-            if env and env in _registry:
+            if env:
+                if env not in _registry:
+                    raise ValueError(
+                        f"TM_TPU_CRYPTO_BACKEND={env!r} names no "
+                        f"batch-verify backend; have {backends()}")
                 _default_name = env
             elif "adaptive" in _registry:
                 _default_name = "adaptive"
@@ -770,11 +775,8 @@ def _register_jax_backend():
     try:
         from .jaxed25519.verify import JAXBatchVerifier
     except ImportError as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "jax batch-verify backend unavailable, falling back to cpu: %s", e
-        )
+        LOG.warning("jax batch-verify backend unavailable (%s): the "
+                    "default backend is the serial host path", e)
         return
     register_backend("jax", JAXBatchVerifier)
     register_backend(

@@ -118,12 +118,6 @@ def _build_jax():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import enable_x64
-
-    # int64 limbs need the x64 trace context; scoping it here (instead
-    # of flipping jax_enable_x64 globally) keeps the jaxed25519 kernels'
-    # int32 world untouched
-    _x64 = enable_x64
 
     # FOLD[i] = limbs(2^(15*(26+i)) mod p): positional fold table for
     # conv coefficients 26..51 (numpy so the x64 trace keeps int64)
@@ -322,7 +316,10 @@ def _build_jax():
             xs[:, i] = _int_to_limbs_py(x)
             ys[:, i] = _int_to_limbs_py(y)
             zs[0, i] = 1
-        with _x64():
+        # int64 limbs need the x64 trace context; scoping it here
+        # (instead of flipping jax_enable_x64 globally) keeps the
+        # jaxed25519 kernels' int32 world untouched
+        with jax.enable_x64(True):
             jx, jy, jz = jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(zs)
             shift = 1
             while shift < n:

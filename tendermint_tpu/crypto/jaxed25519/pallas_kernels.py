@@ -356,7 +356,11 @@ def _make_straus_kernel(interpret: bool):
 
 
 def _pick_block(b: int) -> int:
-    # blk=1024 overflows the 16MB VMEM budget (17.9M measured); 512 fits
+    # widest lane block that divides the batch. Buckets under one
+    # 128-lane tile (8..64) run as a single narrower block; Mosaic
+    # compiles those too (v5e, jax 0.9.0, PR 21). blk=1024 overflowed
+    # the default scoped-VMEM limit when measured before the Kogge-Stone
+    # freeze landed; not re-measured since, 512 fits.
     for blk in (512, 256, 128):
         if b % blk == 0:
             return blk

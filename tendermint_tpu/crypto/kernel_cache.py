@@ -5,34 +5,40 @@ Ed25519 kernel costs multi-second compiles per (shape, device) key, the
 BLS jax-MSM kernel minutes — and every PROCESS used to pay it again.
 This module makes kernels compile once per MACHINE:
 
-1. The persistent XLA compilation cache (``jax_compilation_cache_dir``)
-   is enabled under a configurable directory (``[crypto]``
-   ``compile_cache_dir``, default ``~/.cache/tendermint-tpu/xla``), so
-   XLA itself reuses compiled modules across processes.
-2. An AOT artifact store layers on top: known kernels are
+1. The persistent XLA compilation cache. Where ``JAX_COMPILATION_CACHE_DIR``
+   is set, jax itself reads it and this module sets no directory; where
+   it is not, the cache lives at ONE fixed path inside the checkout
+   (``DEFAULT_CACHE_DIR``, git-ignored) — the path is part of XLA's
+   cache key, so a directory that moves never hits.
+2. An AOT artifact store beneath it: known kernels are
    ``.lower().compile()``d once, serialized with
    ``jax.experimental.serialize_executable``, and written (atomically)
    under ``<cache_dir>/aot/``. A later process deserializes the native
    executable in milliseconds — no tracing, no XLA compile at all.
 
 Artifacts are keyed by (jax version, backend platform, device kind,
-device count, kernel name, static key, argument avals); a corrupted,
-truncated, or version-mismatched artifact is IGNORED (fresh compile +
-miss counter), never a crash. Writes go through a same-directory
+device count, a digest of the kernel source files, kernel name, static
+key, argument avals) — the digest keeps two checkouts that share one
+cache directory from running each other's executables. A corrupted,
+truncated, or foreign-keyed artifact is IGNORED (fresh compile + miss
+counter), never a crash. Writes go through a same-directory
 tempfile + ``os.replace`` so concurrent processes racing one entry
 cannot corrupt it — last writer wins, both end up with a valid file.
 
 Trust model: artifacts deserialize via pickle, the same local-user
 trust boundary as XLA's own persistent cache directory — do not point
-``compile_cache_dir`` at an untrusted location.
+``JAX_COMPILATION_CACHE_DIR`` at an untrusted location.
 
-Everything here is best-effort: any failure in the cache layer falls
-back to the plain jit path. The module never imports jax at import
-time (mirroring crypto/batch's deferred-registration idiom).
+A failure of the STORE (unreadable artifact, unwritable directory) only
+costs the cache and is logged at WARNING; a failure to COMPILE a kernel
+is the kernel's failure and propagates. The module never imports jax at
+import time (mirroring crypto/batch's deferred-registration idiom).
 """
 
 from __future__ import annotations
 
+import functools
+import glob
 import hashlib
 import json
 import logging
@@ -46,7 +52,12 @@ from typing import Callable, Optional
 
 LOG = logging.getLogger("crypto.kernel_cache")
 
-DEFAULT_CACHE_DIR = "~/.cache/tendermint-tpu/xla"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+# the one cache location when ENV_CACHE_DIR is unset: fixed, inside the
+# checkout, git-ignored — never ~, a temp name, a pid or a time
+DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 # artifact header: magic + one json metadata line, then the pickled
 # serialize_executable payload
@@ -54,13 +65,17 @@ _MAGIC = b"TMTPU-AOT1 "
 
 _lock = threading.RLock()
 _dir: Optional[str] = None  # resolved cache dir; None = not yet configured
-_disabled = False  # explicit opt-out (compile_cache_dir = "")
+_unusable = False  # the resolved dir could not be created: store is off
 _stats = {"hits": 0, "misses": 0, "compiles": 0, "load_errors": 0}
 # in-progress compiles: unique token -> (kernel, perf_counter() start);
 # tokens (not kernel names) so two shapes of one kernel compiling
 # concurrently both stay visible until each finishes
 _compiling: dict = {}
 _compile_seq = 0
+# one record per kernel shape made ready in this process (status()
+# "kernels"): name, static key, seconds, and whether it was compiled or
+# loaded from the store — bounded by the number of distinct shapes
+_ready_log: list = []
 # weakrefs to every live aot_wrap in-memory cache (clear_memory's only
 # purpose); weak so an aot_wrap dropped by its caller (e.g. lru_cache
 # eviction of a kernel shape) actually frees its loaded executables
@@ -82,120 +97,63 @@ def _metrics():
     return _batch.get_metrics()
 
 
-def configure(cache_dir: Optional[str]) -> Optional[str]:
-    """Set the compile-cache root: enables jax's persistent compilation
-    cache there and roots the AOT artifact store at ``<dir>/aot``.
-    ``""`` (or None) disables both layers. Returns the resolved dir.
+def ensure_configured() -> Optional[str]:
+    """Resolve the cache root once per process and return it.
 
-    Safe to call before OR after jax backend init, and repeatedly (a
-    node reconfiguring to the same dir is a no-op)."""
-    global _dir, _disabled
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax already keeps XLA's cache
+    there (it reads the variable itself), the AOT store goes to
+    ``<dir>/aot`` and no directory is set in code. Unset: both layers
+    live at DEFAULT_CACHE_DIR and jax is pointed there. Safe before or
+    after backend init. An uncreatable directory turns the store off
+    (WARNING) — kernels then compile per process."""
+    global _dir, _unusable
     with _lock:
-        if not cache_dir:
-            if _dir is not None:
-                try:  # pragma: no cover - depends on jax build
-                    import jax
-
-                    jax.config.update("jax_compilation_cache_dir", None)
-                except Exception as e:  # noqa: BLE001 - best-effort
-                    LOG.debug("persistent XLA cache not disabled: %s", e)
-            _disabled = True
-            _dir = None
-            return None
-        resolved = os.path.abspath(os.path.expanduser(cache_dir))
-        _disabled = False
-        if resolved == _dir:
+        if _dir is not None or _unusable:
             return _dir
-        _dir = resolved
+        env = os.environ.get(ENV_CACHE_DIR)
+        resolved = os.path.abspath(env or DEFAULT_CACHE_DIR)
         try:
             os.makedirs(os.path.join(resolved, "aot"), exist_ok=True)
         except OSError as e:
-            LOG.warning("compile cache dir %s unusable, caching disabled: %s",
-                        resolved, e)
-            _dir, _disabled = None, True
+            LOG.warning("compile cache dir %s unusable, kernels compile "
+                        "per process: %s", resolved, e)
+            _unusable = True
             return None
-        _prune_stale(resolved)
-        try:  # pragma: no cover - depends on jax build
-            import jax
+        _dir = resolved
+        _prune_tempfiles(resolved)
+        import jax
 
+        if not env:
             jax.config.update("jax_compilation_cache_dir", resolved)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-        except Exception as e:  # noqa: BLE001 - cache is best-effort
-            LOG.debug("persistent XLA cache not enabled: %s", e)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
         return _dir
 
 
 _TMP_MAX_AGE_S = 24 * 3600.0  # crashed writers' tempfiles age out
 
 
-def _prune_stale(root: str) -> None:
-    """Best-effort GC of the aot/ store, run once per configure():
-    artifacts written by a DIFFERENT jax version are permanently
-    unreachable (the version is part of the key hash in the filename)
-    and multi-MB each, so without this they accumulate forever across
-    upgrades; unparseable artifacts can never load either. Live
-    same-version artifacts are never touched."""
-    try:
-        import jax
-
-        version = jax.__version__
-    except Exception:  # noqa: BLE001 - no jax, nothing to compare to
-        return
+def _prune_tempfiles(root: str) -> None:
+    """Remove day-old tempfiles crashed writers left in aot/."""
     aot = os.path.join(root, "aot")
-    try:
-        names = os.listdir(aot)
-    except OSError:
-        return
     now = time.time()
-    for name in names:
+    for name in os.listdir(aot):
+        if not name.startswith(".tmp-aot-"):
+            continue
         path = os.path.join(aot, name)
         try:
-            if name.startswith(".tmp-aot-"):
-                if now - os.path.getmtime(path) > _TMP_MAX_AGE_S:
-                    os.unlink(path)
-                continue
-            if not name.endswith(".aot"):
-                continue
-            with open(path, "rb") as f:
-                head = f.read(65536)  # meta line sits right after magic
-            keep = False
-            if head.startswith(_MAGIC):
-                nl = head.find(b"\n", len(_MAGIC))
-                if nl != -1:
-                    try:
-                        meta = json.loads(head[len(_MAGIC):nl].decode())
-                        keep = json.loads(meta["key"])[0] == version
-                    except Exception:  # noqa: BLE001 - junk never loads
-                        keep = False
-            if not keep:
+            if now - os.path.getmtime(path) > _TMP_MAX_AGE_S:
                 os.unlink(path)
         except OSError:
             continue  # racing process: it won the unlink, fine
 
 
 def unconfigure() -> None:
-    """Return to the never-configured state (test fixtures): unlike
-    configure(""), which pins the layer DISABLED, the next
-    ensure_configured() re-reads the environment/default."""
-    global _dir, _disabled
+    """Return to the never-configured state (test fixtures): the next
+    ensure_configured() re-reads the environment."""
+    global _dir, _unusable
     with _lock:
         _dir = None
-        _disabled = False
-
-
-def ensure_configured() -> Optional[str]:
-    """Configure with the environment/default dir unless a configure()
-    call already happened. TM_TPU_COMPILE_CACHE wins, then the legacy
-    TM_TPU_JAX_CACHE spelling, then DEFAULT_CACHE_DIR; an empty
-    TM_TPU_COMPILE_CACHE disables caching."""
-    with _lock:
-        if _dir is not None or _disabled:
-            return _dir
-    env = os.environ.get("TM_TPU_COMPILE_CACHE")
-    if env is None:
-        env = os.environ.get("TM_TPU_JAX_CACHE") or DEFAULT_CACHE_DIR
-    return configure(env)
+        _unusable = False
 
 
 def cache_dir() -> Optional[str]:
@@ -223,6 +181,7 @@ def status() -> dict:
             "enabled": _dir is not None,
             **_stats,
             "compiling": compiling,
+            "kernels": [dict(r) for r in _ready_log],
         }
 
 
@@ -230,6 +189,7 @@ def reset_stats() -> None:
     with _lock:
         for k in _stats:
             _stats[k] = 0
+        _ready_log.clear()
 
 
 def clear_memory() -> None:
@@ -267,16 +227,30 @@ def _aval_part(a) -> tuple:
     return ("other", str(np.asarray(a).shape), str(np.asarray(a).dtype))
 
 
+@functools.lru_cache(maxsize=1)
+def _code_digest() -> str:
+    """sha256 over the kernel source files (crypto/jaxed25519/*.py +
+    crypto/bls/msm.py), once per process: the program's component of
+    the artifact key."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    paths = glob.glob(os.path.join(here, "jaxed25519", "*.py"))
+    paths.append(os.path.join(here, "bls", "msm.py"))
+    h = hashlib.sha256()
+    for path in sorted(paths):
+        h.update(os.path.relpath(path, here).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read() + b"\0")
+    return h.hexdigest()[:16]
+
+
 def _full_key(kernel: str, static_key: tuple, args) -> str:
     import jax
 
-    try:
-        dev = jax.devices()[0]
-        platform, kind, ndev = dev.platform, dev.device_kind, len(jax.devices())
-    except Exception:  # noqa: BLE001 - no backend: key still stable
-        platform, kind, ndev = "none", "none", 0
-    return json.dumps([jax.__version__, platform, kind, ndev, kernel,
-                       list(static_key), [list(_aval_part(a)) for a in args]],
+    devs = jax.devices()
+    return json.dumps([jax.__version__, devs[0].platform,
+                       devs[0].device_kind, len(devs), _code_digest(),
+                       kernel, list(static_key),
+                       [list(_aval_part(a)) for a in args]],
                       sort_keys=True)
 
 
@@ -288,8 +262,9 @@ def _artifact_path(kernel: str, key: str) -> Optional[str]:
 
 
 def _try_load(kernel: str, key: str, path: str):
-    """Deserialize a stored executable; None on ANY mismatch/corruption
-    (counted, logged at debug — the fresh-compile path takes over)."""
+    """Deserialize a stored executable onto the devices it was compiled
+    for; None on ANY mismatch/corruption (counted, logged — the fresh
+    compile takes over and rewrites the artifact)."""
     try:
         with open(path, "rb") as f:
             blob = f.read()
@@ -302,15 +277,17 @@ def _try_load(kernel: str, key: str, path: str):
         nl = rest.index(b"\n")
         meta = json.loads(rest[:nl].decode())
         if meta.get("key") != key:
-            raise ValueError("key mismatch (different jax/backend/shape)")
+            raise ValueError("key mismatch (different jax/backend/code/shape)")
         payload = pickle.loads(rest[nl + 1:])
+        import jax
         from jax.experimental import serialize_executable as _se
 
-        compiled = _se.deserialize_and_load(*payload)
-        return compiled
+        by_id = {d.id: d for d in jax.devices()}
+        return _se.deserialize_and_load(
+            *payload, execution_devices=[by_id[i] for i in meta["devices"]])
     except Exception as e:  # noqa: BLE001 - corrupt/foreign artifact
         _bump("load_errors")
-        LOG.debug("ignoring unusable AOT artifact %s: %s", path, e)
+        LOG.warning("ignoring unusable AOT artifact %s: %s", path, e)
         return None
 
 
@@ -320,7 +297,10 @@ def _try_store(kernel: str, key: str, path: str, compiled) -> None:
         from jax.experimental import serialize_executable as _se
 
         payload = pickle.dumps(_se.serialize(compiled))
-        meta = json.dumps({"key": key, "kernel": kernel}).encode()
+        devices = [d.id for d in
+                   compiled._executable.xla_executable.local_devices()]
+        meta = json.dumps({"key": key, "kernel": kernel,
+                           "devices": devices}).encode()
         fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
                                    prefix=".tmp-aot-")
         try:
@@ -334,7 +314,7 @@ def _try_store(kernel: str, key: str, path: str, compiled) -> None:
                 pass
             raise
     except Exception as e:  # noqa: BLE001 - store is best-effort
-        LOG.debug("could not persist AOT artifact for %s: %s", kernel, e)
+        LOG.warning("could not persist AOT artifact for %s: %s", kernel, e)
 
 
 def _timed_compile(kernel: str, jitted, args):
@@ -356,44 +336,46 @@ def _timed_compile(kernel: str, jitted, args):
     m = _metrics()
     if m is not None:
         m.compile_seconds.with_labels(kernel).observe(dt)
-    LOG.info("compiled kernel %s in %.1fs", kernel, dt)
     return compiled
 
 
 def load_or_compile(kernel: str, static_key: tuple, jitted, args):
     """One kernel instance: AOT-load from disk if a matching artifact
     exists, else lower+compile from `args` (concrete arrays or
-    jax.ShapeDtypeStruct) and write the artifact back. Any cache-layer
-    failure degrades to the fresh-compile result."""
+    jax.ShapeDtypeStruct) and write the artifact back. A compile error
+    propagates: it is the kernel's, not the cache's."""
     ensure_configured()
     m = _metrics()
-    try:
-        key = _full_key(kernel, static_key, args)
-        path = _artifact_path(kernel, key)
-    except Exception as e:  # noqa: BLE001 - never block verification
-        LOG.debug("AOT key derivation failed for %s: %s", kernel, e)
-        key = path = None
+    key = _full_key(kernel, static_key, args)
+    path = _artifact_path(kernel, key)
+    t0 = time.perf_counter()
     if path is not None:
         compiled = _try_load(kernel, key, path)
         if compiled is not None:
             _bump("hits")
             if m is not None:
                 m.compile_cache_hits.inc()
+            _note_ready(kernel, static_key, args, t0, "aot-store")
             return compiled
         _bump("misses")
         if m is not None:
             m.compile_cache_misses.inc()
-    try:
-        compiled = _timed_compile(kernel, jitted, args)
-    except Exception as e:  # noqa: BLE001 - AOT lowering unsupported
-        # e.g. an arg form .lower() can't take: the plain jit function
-        # is always a correct (lazily compiling) stand-in
-        LOG.debug("AOT compile path unavailable for %s (%s); "
-                  "falling back to plain jit", kernel, e)
-        return jitted
+    compiled = _timed_compile(kernel, jitted, args)
+    _note_ready(kernel, static_key, args, t0, "compiled")
     if path is not None:
         _try_store(kernel, key, path, compiled)
     return compiled
+
+
+def _note_ready(kernel: str, static_key: tuple, args, t0: float,
+                source: str) -> None:
+    rec = {"kernel": kernel, "static_key": list(static_key),
+           "arg0_shape": list(getattr(args[0], "shape", ())),
+           "seconds": round(time.perf_counter() - t0, 3), "source": source}
+    LOG.info("kernel %s %s %s ready in %.1fs (%s)", kernel,
+             rec["static_key"], rec["arg0_shape"], rec["seconds"], source)
+    with _lock:
+        _ready_log.append(rec)
 
 
 def aot_wrap(kernel: str, static_key: tuple, jitted) -> Callable:
@@ -418,15 +400,5 @@ def aot_wrap(kernel: str, static_key: tuple, jitted) -> Callable:
                     cache[k] = fn
         return fn(*args)
 
-    def prepare(*args) -> None:
-        """Force the load-or-compile for this signature without
-        executing (args may be jax.ShapeDtypeStruct placeholders) —
-        bench warmstart measures exactly this readiness step."""
-        k = tuple(_aval_part(a) for a in args)
-        with lock:
-            if k not in cache:
-                cache[k] = load_or_compile(kernel, static_key, jitted, args)
-
-    call.prepare = prepare
     call.kernel_name = kernel
     return call

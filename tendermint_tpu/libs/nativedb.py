@@ -4,7 +4,8 @@ cgo→C++ LevelDB backend (libs/db/c_level_db.go, build tag `gcc`;
 SURVEY §2.6 item 1).
 
 Selected with db_backend = "native". Builds the shared library with
-g++ on first use if it isn't already present.
+g++ on first use when it is absent or older than nativedb.cpp (the .so
+is git-ignored: a checkout never ships one).
 """
 
 from __future__ import annotations
@@ -32,8 +33,9 @@ def _load_lib() -> ctypes.CDLL:
     with _build_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            src = os.path.join(_NATIVE_DIR, "nativedb.cpp")
+        src = os.path.join(_NATIVE_DIR, "nativedb.cpp")
+        if (not os.path.exists(_LIB_PATH)
+                or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
             subprocess.run(
                 ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-shared",
                  "-o", _LIB_PATH, src],

@@ -187,16 +187,13 @@ class Node:
             coalesce_max_batch=config.crypto.coalesce_max_batch,
         )
         self._installed_sig_cache = crypto_batch.get_sig_cache()
-        # compile-once kernel layer: root the persistent XLA cache + AOT
-        # executable store under [crypto] compile_cache_dir (env
-        # TM_TPU_COMPILE_CACHE — or the legacy TM_TPU_JAX_CACHE
-        # spelling — wins for this process; "" disables). Safe before
-        # jax backend init, so boot-time warmup loads warm.
-        from ..crypto import kernel_cache
-
-        if ("TM_TPU_COMPILE_CACHE" not in os.environ
-                and "TM_TPU_JAX_CACHE" not in os.environ):
-            kernel_cache.configure(config.crypto.compile_cache_dir)
+        # which verifier this node got: filled in by the warm-up thread
+        # once the backend is up, served at /debug/crypto
+        self._verifier = {
+            "backend": crypto_batch.default_backend_name(),
+            "platform": None, "device_kind": None, "device_count": 0,
+            "fused_kernel": None, "warmup": "pending",
+        }
         self._enabled_tracing = False
         if config.instrumentation.tracing:
             tracer = tracing.get_tracer()
@@ -894,37 +891,61 @@ class Node:
             self._grpc_server.start()
 
     def _start_verify_warmup(self) -> None:
-        """Pre-compile the hot TPU verify-kernel bucket shapes on a daemon
-        thread so the 20-40s first-compile cost never lands inside the
-        live vote path (crypto/jaxed25519/verify.warmup). Failures are
-        non-fatal: the kernel compiles lazily on first use instead.
-        Skipped entirely when the crypto backend is the host OpenSSL path
-        ("cpu" — the jax kernels would never run) or TM_TPU_WARMUP=0."""
+        """Resolve the batch verifier once, on a daemon thread, and say
+        which device it got: one log line plus the /debug/crypto fields
+        (backend, platform, device_kind, device_count, fused_kernel,
+        warmup, batch_cutoff). With a device backend ("jax"/"adaptive")
+        the thread then pre-compiles the hot verify-kernel bucket shapes
+        and calibrates the adaptive cutoff (crypto/jaxed25519/
+        verify.warmup), so the first-compile cost never lands inside the
+        live vote path; a warm-up that fails — backend init, a shape the
+        compiler refuses — is an ERROR here, not a surprise in the first
+        live batch. The host OpenSSL backend ("cpu") never touches jax.
+        TM_TPU_WARMUP=0 skips the compile, not the report."""
+        from ..crypto import batch as crypto_batch
+
+        info = self._verifier
+
         def _go():
+            if info["backend"] == "cpu":
+                info["warmup"] = "disabled"
+                LOG.info("crypto verifier: backend=cpu (host OpenSSL); "
+                         "no device initialised")
+                return
             try:
-                from ..crypto import batch as _batch
-                from ..crypto.jaxed25519.verify import warmup
+                import jax
 
-                if (os.environ.get("TM_TPU_WARMUP", "1") == "0"
-                        or _batch.default_backend_name() == "cpu"):
-                    LOG.info("verify warmup disabled (backend/env)")
+                from ..crypto.jaxed25519 import verify as jv
+
+                dev = jax.devices()[0]
+                use_pallas, interp = jv._pallas_flags()
+                info.update(
+                    platform=dev.platform, device_kind=dev.device_kind,
+                    device_count=len(jax.devices()),
+                    fused_kernel=("interpret" if interp else "compiled")
+                    if use_pallas else "off")
+                LOG.info(
+                    "crypto verifier: backend=%s platform=%s device_kind=%r "
+                    "devices=%d fused_kernel=%s", info["backend"],
+                    info["platform"], info["device_kind"],
+                    info["device_count"], info["fused_kernel"])
+                if os.environ.get("TM_TPU_WARMUP", "1") == "0":
+                    info["warmup"] = "disabled"
                     return
-
                 env = os.environ.get("TM_TPU_WARMUP_BUCKETS")
                 buckets = (tuple(int(x) for x in env.split(",") if x)
                            if env else (8, 16, 64))
-                cutoff = warmup(buckets=buckets)
-                if cutoff is not None:
-                    LOG.info(
-                        "verify warmup: adaptive batch cutoff calibrated "
-                        "to %d (measured dispatch vs serial break-even)",
-                        cutoff,
-                    )
-                self._verify_warmed = True
-            except Exception as e:  # noqa: BLE001 - warmup is best-effort
-                LOG.info("verify warmup skipped: %s", e)
+                jv.warmup(buckets=buckets)
+                info["warmup"] = "ok"
+                LOG.info("verify warm-up ok: buckets=%s, adaptive batch "
+                         "cutoff %d", list(buckets),
+                         crypto_batch.effective_batch_min())
+            except Exception as e:  # noqa: BLE001 - thread boundary
+                info["warmup"] = f"error: {type(e).__name__}: {e}"
+                LOG.exception("verify warm-up FAILED with the %s backend: "
+                              "the first live batch will meet the same "
+                              "error", info["backend"])
 
-        self._verify_warmed = False
         t = threading.Thread(target=_go, name="verify-warmup", daemon=True)
         t.start()
         self._verify_warmup_thread = t
@@ -1053,16 +1074,22 @@ class Node:
         return self._rpc_server.debug_status()
 
     def _crypto_status(self) -> dict:
-        """The /debug/crypto bundle: compile-once layer state (cache
-        dir, AOT hit/miss counters, any compile in progress — a node
-        wedged compiling at boot shows up here), plus the coalescing
-        scheduler config and live async-batch count."""
+        """The /debug/crypto bundle: which verifier the node resolved at
+        start-up (backend, platform, device_kind, device_count,
+        fused_kernel, warm-up outcome, the adaptive cutoff in force),
+        compile-once layer state (cache dir, AOT hit/miss counters, any
+        compile in progress — a node wedged compiling at boot shows up
+        here), plus the coalescing scheduler config and live async-batch
+        count."""
         from ..crypto import batch as crypto_batch
         from ..crypto import kernel_cache
 
         out = kernel_cache.status()
         out["coalesce"] = crypto_batch.coalesce_status()
         out["inflight_batches"] = crypto_batch.inflight_count()
+        out["verifier"] = dict(
+            self._verifier,
+            batch_cutoff=crypto_batch.effective_batch_min())
         return out
 
     def _lockdep_status(self) -> dict:

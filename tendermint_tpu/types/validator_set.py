@@ -490,13 +490,14 @@ class ValidatorSet:
                         return jv.sharded_commit_verify(
                             list(msgs), list(sigs), list(pks), powers,
                             for_block)
-            except ImportError:
-                pass
             except Exception as e:  # noqa: BLE001 - host path is authoritative
                 # any device-side failure (compile error, OOM, topology
-                # change) must not abort commit verification: the host
-                # batch path below verifies identically
-                LOG.warning("sharded commit verify failed, host fallback: %s", e)
+                # change) must not abort commit verification: the batch
+                # path below verifies identically — but say so loudly,
+                # the sharded path is what this deployment was built for
+                LOG.warning("sharded commit verify failed (%s: %s); "
+                            "re-verifying through the batch path",
+                            type(e).__name__, e, exc_info=True)
         return bv.verify(), None
 
     # --- updates (reference :411-472 via state.updateState) ---------------

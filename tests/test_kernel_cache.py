@@ -30,20 +30,18 @@ import jax.numpy as jnp  # noqa: E402
 
 @pytest.fixture
 def cache_dir(tmp_path):
-    """Point the module-global store at a fresh dir for one test, then
-    restore whatever the session (conftest env) had configured."""
-    prev = kernel_cache.cache_dir()
+    """Place the store from outside, the way a deployment does: export
+    JAX_COMPILATION_CACHE_DIR and let the module resolve it. Restores
+    the session's location afterwards."""
     d = str(tmp_path / "kc")
-    kernel_cache.configure(d)
-    kernel_cache.reset_stats()
-    yield d
-    if prev:
-        kernel_cache.configure(prev)
-    else:
-        # back to UNCONFIGURED, not disabled: a later test's
-        # ensure_configured() must still pick up the session cache env
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(kernel_cache.ENV_CACHE_DIR, d)
         kernel_cache.unconfigure()
-        kernel_cache.ensure_configured()
+        assert kernel_cache.ensure_configured() == d
+        kernel_cache.reset_stats()
+        yield d
+    kernel_cache.unconfigure()
+    kernel_cache.ensure_configured()
     kernel_cache.reset_stats()
 
 
@@ -83,38 +81,23 @@ class TestAOTStore:
         assert s["hits"] == 1
         np.testing.assert_array_equal(fresh, warm)
 
-    def test_stale_version_artifacts_pruned_at_configure(self, cache_dir,
-                                                         tmp_path):
-        """configure() GCs aot/ entries a different jax version wrote
-        (their filename hash embeds the version, so they are
-        permanently unreachable) and day-old crashed-writer tempfiles —
-        live same-version artifacts survive untouched."""
-        import json as _json
-
+    def test_crashed_writer_tempfiles_pruned(self, cache_dir):
+        """Resolving the store GCs day-old crashed-writer tempfiles in
+        aot/; live artifacts survive and still warm-load."""
         fn = _tiny_kernel()
         x = np.arange(4, dtype=np.int32)
         want = np.asarray(fn(x))
         live = os.path.basename(_artifacts(cache_dir)[0])
 
         aot = os.path.join(cache_dir, "aot")
-        meta = _json.dumps(
-            {"key": _json.dumps(["0.0.0-foreign"]), "kernel": "x"}).encode()
-        with open(os.path.join(aot, "x-deadbeef.aot"), "wb") as f:
-            f.write(kernel_cache._MAGIC + meta + b"\npayload")
-        with open(os.path.join(aot, "y-cafebabe.aot"), "wb") as f:
-            f.write(b"not an artifact at all")
         stale_tmp = os.path.join(aot, ".tmp-aot-crashed")
         open(stale_tmp, "wb").close()
         os.utime(stale_tmp, (1, 1))
 
-        # prune runs on dir CHANGE: bounce configure through another dir
-        kernel_cache.configure(str(tmp_path / "elsewhere"))
-        kernel_cache.configure(cache_dir)
+        kernel_cache.unconfigure()
+        kernel_cache.ensure_configured()
         names = os.listdir(aot)
-        assert live in names, "live same-version artifact must survive"
-        assert "x-deadbeef.aot" not in names
-        assert "y-cafebabe.aot" not in names
-        assert ".tmp-aot-crashed" not in names
+        assert live in names and ".tmp-aot-crashed" not in names
 
         kernel_cache.clear_memory()
         kernel_cache.reset_stats()
@@ -235,30 +218,57 @@ class TestAOTStore:
         assert np.asarray(fn(np.arange(4, dtype=np.int32))).tolist() \
             == [1, 4, 7, 10]
 
-    def test_disabled_cache_still_verifies(self, tmp_path):
-        prev = kernel_cache.cache_dir()
-        try:
-            kernel_cache.configure("")  # explicit opt-out
-            kernel_cache.reset_stats()
-            fn = _tiny_kernel()
-            assert np.asarray(fn(np.arange(4, dtype=np.int32))).tolist() \
-                == [1, 4, 7, 10]
-            s = kernel_cache.stats()
-            assert s["hits"] == 0 and s["misses"] == 0  # store bypassed
-        finally:
-            kernel_cache.configure(prev)
-            kernel_cache.reset_stats()
+    def test_different_code_digest_is_a_miss_never_a_load(
+            self, cache_dir, monkeypatch):
+        """Two checkouts sharing one cache directory: same kernel name,
+        same shapes, different kernel source -> the second one compiles
+        its own executable, it never loads the first one's."""
+        name = "test_shared_name"
+        x = np.arange(8, dtype=np.int32)
+        monkeypatch.setattr(kernel_cache, "_code_digest", lambda: "aaaa")
+        parent = kernel_cache.aot_wrap(name, (), jax.jit(lambda v: v + 1))
+        assert np.asarray(parent(x)).tolist() == list(range(1, 9))
+        assert len(_artifacts(cache_dir)) == 1
 
-    def test_prepare_readies_without_executing(self, cache_dir):
-        """prepare() (bench warmstart's readiness probe) compiles from a
-        ShapeDtypeStruct; the later concrete call reuses the executable
-        with no second compile."""
-        fn = _tiny_kernel()
-        fn.prepare(jax.ShapeDtypeStruct((8,), jnp.int32))
-        assert kernel_cache.stats()["compiles"] == 1
-        out = np.asarray(fn(np.arange(8, dtype=np.int32)))
-        assert kernel_cache.stats()["compiles"] == 1
-        assert out.tolist() == list(range(1, 25, 3))
+        kernel_cache.reset_stats()
+        monkeypatch.setattr(kernel_cache, "_code_digest", lambda: "bbbb")
+        change = kernel_cache.aot_wrap(name, (), jax.jit(lambda v: v + 2))
+        assert np.asarray(change(x)).tolist() == list(range(2, 10))
+        s = kernel_cache.stats()
+        assert s["hits"] == 0 and s["misses"] == 1 and s["compiles"] == 1
+        assert s["load_errors"] == 0  # a clean miss, not a rejected load
+        assert len(_artifacts(cache_dir)) == 2  # neither evicts the other
+
+    def test_code_digest_covers_kernel_sources(self):
+        """The digest is taken over the kernel source files and is
+        stable within a process."""
+        d = kernel_cache._code_digest()
+        assert d == kernel_cache._code_digest() and len(d) == 16
+        assert d in kernel_cache._full_key("k", (), [])
+
+    def test_loads_onto_the_devices_it_was_compiled_for(self, cache_dir):
+        """A single-device kernel reloads as a single-device executable
+        on a multi-device backend (jax 0.9.0's deserialize_and_load
+        defaults to EVERY device, which made the warm run raise 'expected
+        8 shards, got [1]'); a mesh kernel reloads over its mesh."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        ndev = len(jax.devices())
+        assert ndev > 1, "conftest pins 8 virtual cpu devices"
+        mesh = Mesh(np.asarray(jax.devices()), ("dp",))
+        sh = NamedSharding(mesh, P("dp"))
+        meshed = kernel_cache.aot_wrap(
+            "test_meshed", (ndev,),
+            jax.jit(lambda v: v * 2, in_shardings=sh, out_shardings=sh))
+        x = jax.device_put(np.arange(ndev * 2, dtype=np.int32), sh)
+        want = np.asarray(meshed(x))
+        kernel_cache.clear_memory()
+        kernel_cache.reset_stats()
+        got = meshed(x)
+        np.testing.assert_array_equal(want, np.asarray(got))
+        assert len(got.sharding.device_set) == ndev
+        s = kernel_cache.stats()
+        assert s["hits"] == 1 and s["compiles"] == 0
 
     def test_donated_equals_undonated(self, cache_dir):
         """donate_argnums is a compile-key dimension, not a semantics
@@ -278,6 +288,47 @@ class TestAOTStore:
         st = kernel_cache.status()
         assert st["enabled"] and st["dir"] == cache_dir
         assert st["compiles"] == 1 and st["compiling"] == {}
+
+
+class TestCacheRule:
+    """Where the compile cache lives: JAX_COMPILATION_CACHE_DIR when
+    set (and then no code sets jax_compilation_cache_dir), else one
+    fixed git-ignored path inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+        kernel_cache.unconfigure()
+        kernel_cache.ensure_configured()
+
+    def test_env_dir_honoured_and_not_overwritten(self, tmp_path,
+                                                  monkeypatch):
+        d = str(tmp_path / "placed")
+        monkeypatch.setenv(kernel_cache.ENV_CACHE_DIR, d)
+        # what jax would hold had the variable been set at start-up
+        jax.config.update("jax_compilation_cache_dir", d)
+        updates = []
+        real = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real(k, v))[1])
+        kernel_cache.unconfigure()
+        assert kernel_cache.ensure_configured() == d
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == d
+        assert os.path.isdir(os.path.join(d, "aot"))
+
+    def test_unset_means_fixed_path_inside_the_checkout(self, monkeypatch):
+        monkeypatch.delenv(kernel_cache.ENV_CACHE_DIR, raising=False)
+        kernel_cache.unconfigure()
+        got = kernel_cache.ensure_configured()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
 
 
 def _triple(i=0, valid=True):
@@ -500,11 +551,16 @@ class TestObservability:
 
         from tendermint_tpu.node.node import Node
 
-        out = Node._crypto_status(None)  # uses only module state
+        class _Stub:  # the bundle reads module state + this one field
+            _verifier = {"backend": "cpu", "warmup": "disabled"}
+
+        out = Node._crypto_status(_Stub)
         json.dumps(out)
         assert out["dir"] == cache_dir and out["enabled"]
         assert "compiling" in out and "coalesce" in out
-        assert out["inflight_batches"] == 0
+        assert out["kernels"] == [] and out["inflight_batches"] == 0
+        assert out["verifier"]["backend"] == "cpu"
+        assert out["verifier"]["batch_cutoff"] >= 1
 
     def test_monitor_surfaces_compiling_node(self):
         """A node stuck compiling at boot is visible in the monitor

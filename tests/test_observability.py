@@ -247,6 +247,48 @@ def test_watchdog_fires_on_injected_stall(tmp_path):
         node.stop()
 
 
+def test_debug_crypto_names_the_verifier_the_node_got(
+        tmp_path, monkeypatch, caplog):
+    """A node resolves its batch verifier once at start-up and says so:
+    one log line, and /debug/crypto fields for backend, platform,
+    device_kind, device count, fused-kernel mode, warm-up outcome and
+    the cutoff in force. Under the test platform that device is `cpu` —
+    the case that used to be silent on a machine whose chip was taken."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.node import default_new_node
+
+    monkeypatch.setenv("TM_TPU_WARMUP", "0")  # the report, not the compile
+    prev = crypto_batch.default_backend_name()
+    crypto_batch.set_default_backend("adaptive")
+    c = make_config(tmp_path, "verifier")
+    c.base.prof_laddr = "tcp://127.0.0.1:0"
+    init_files(c)
+    try:
+        with caplog.at_level("INFO", logger="node"):
+            node = default_new_node(c)
+            node.start()
+            try:
+                node._verify_warmup_thread.join(timeout=60)
+                assert not node._verify_warmup_thread.is_alive()
+                with urllib.request.urlopen(
+                        f"http://{node._prof_server.listen_addr}"
+                        "/debug/crypto", timeout=10) as r:
+                    v = json.load(r)["verifier"]
+            finally:
+                node.stop()
+    finally:
+        crypto_batch.set_default_backend(prev)
+    assert v["backend"] == "adaptive"
+    assert v["platform"] == "cpu" and v["device_kind"] == "cpu"
+    assert v["device_count"] == 8  # conftest's virtual mesh
+    assert v["fused_kernel"] == "off"  # Mosaic only lowers on a TPU
+    assert v["warmup"] == "disabled"
+    assert v["batch_cutoff"] == crypto_batch.effective_batch_min()
+    line = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("crypto verifier:")]
+    assert len(line) == 1 and "platform=cpu" in line[0], line
+
+
 # --- e2e: timeline + net_info over a live two-node net -----------------
 
 
