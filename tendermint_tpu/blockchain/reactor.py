@@ -22,6 +22,7 @@ import threading
 import time
 from typing import Optional
 
+from ..libs import tracing
 from ..p2p.base_reactor import ChannelDescriptor, Reactor
 from ..types import serde
 from ..types.basic import BlockID
@@ -88,6 +89,10 @@ class BlockchainReactor(Reactor):
         self._stop = threading.Event()
         self._pool_thread: Optional[threading.Thread] = None
         self.blocks_synced = 0
+        # height -> cause of the p2p.recvBlock that decoded it, so the
+        # sync loop's fastsync.block names the download as its parent;
+        # filled only while the recorder is on, emptied as blocks apply
+        self._recv_cause: dict = {}
 
         from .pool import BlockPool
 
@@ -242,6 +247,8 @@ class BlockchainReactor(Reactor):
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:174-214."""
+        tracer = tracing.get_tracer()
+        t_recv = time.perf_counter_ns() if tracer.enabled else 0
         obj = serde.unpack(msg_bytes)
         kind = obj[0]
         if self.switch is not None and peer.is_running():
@@ -264,6 +271,16 @@ class BlockchainReactor(Reactor):
             if self.tree is not None:
                 self.tree.note_delivery(peer.id)
             self.pool.add_block(peer.id, block, len(msg_bytes))
+            if t_recv:
+                # decode + hand-over on the p2p thread, known to be a
+                # block only now: recorded from its two clock readings
+                height = block.header.height
+                if len(self._recv_cause) > 4096:  # blocks never applied
+                    self._recv_cause.clear()
+                self._recv_cause[height] = tracer.record(
+                    "p2p.recvBlock", t_recv, time.perf_counter_ns(), "p2p",
+                    request=("block", height), height=height,
+                    txs=len(block.data.txs), bytes=len(msg_bytes))
         elif kind == "no_block_response":
             LOG.debug("peer %s has no block at %d", peer.id[:8], obj[1])
         elif kind == "status_request":
@@ -327,6 +344,12 @@ class BlockchainReactor(Reactor):
         status_interval = (TAIL_STATUS_UPDATE_INTERVAL if self.tail_forever
                            else STATUS_UPDATE_INTERVAL)
         self._broadcast_status_request()
+        # fastsync.poolWait: from the first pass that found no pair of
+        # blocks ready to the pass that found one — the joiner starved
+        # by its peers. Recorded when it ends, so it never covers a
+        # loop that has nothing more to wait for.
+        tracer = tracing.get_tracer()
+        wait_t0 = 0
         while not self._stop.is_set() and self.pool.is_running():
             now = time.monotonic()
             if now - last_status >= status_interval:
@@ -336,7 +359,15 @@ class BlockchainReactor(Reactor):
                 last_switch_check = now
                 if self._maybe_switch_to_consensus():
                     return
-            if not self._try_sync_batch():
+            t_try = time.perf_counter_ns() if wait_t0 else 0
+            if self._try_sync_batch():
+                if wait_t0:
+                    tracer.record("fastsync.poolWait", wait_t0, t_try,
+                                  "fastsync")
+                    wait_t0 = 0
+            else:
+                if not wait_t0 and tracer.enabled:
+                    wait_t0 = time.perf_counter_ns()
                 time.sleep(TRY_SYNC_INTERVAL)
 
     def _maybe_switch_to_consensus(self) -> bool:
@@ -415,6 +446,16 @@ class BlockchainReactor(Reactor):
                                             block_id)
         return pre
 
+    def _block_span(self, block):
+        """fastsync.block: the root of everything done for one height in
+        the sync loop, caused by the p2p.recvBlock that decoded it."""
+        height = block.header.height
+        return tracing.span(
+            "fastsync.block", cat="fastsync",
+            cause=self._recv_cause.pop(height, None),
+            request=("block", height), height=height,
+            txs=len(block.data.txs))
+
     def _try_sync_batch_serial(self) -> bool:
         processed = 0
         pre = self._preverify_agg_window()
@@ -422,45 +463,57 @@ class BlockchainReactor(Reactor):
             first, second = self.pool.peek_two_blocks()
             if first is None or second is None:
                 break
-            hit = pre.pop(first.header.height, None)
-            if (hit is not None and hit[1] is first
-                    and hit[2] is second.last_commit
-                    and hit[0] == self.state.validators.hash()):
-                # certificate already verified in the window batch
-                first_parts, first_id = hit[3], hit[4]
-            else:
+            with self._block_span(first):
+                if not self._sync_one_serial(first, second, pre):
+                    return processed > 0
+            processed += 1
+        return processed > 0
+
+    def _sync_one_serial(self, first, second, pre) -> bool:
+        """Verify, save and apply one block; False if its commit failed."""
+        height = first.header.height
+        hit = pre.pop(height, None)
+        if (hit is not None and hit[1] is first
+                and hit[2] is second.last_commit
+                and hit[0] == self.state.validators.hash()):
+            # certificate already verified in the window batch
+            first_parts, first_id = hit[3], hit[4]
+        else:
+            with tracing.span("fastsync.partSet", cat="fastsync",
+                              height=height):
                 first_parts = make_part_set(first)
                 first_id = BlockID(hash=first.hash(),
                                    parts_header=first_parts.header())
-                try:
-                    # ★ batch-verify the +2/3 commit for `first` carried
-                    # in `second.last_commit` (reactor.go:310) — one TPU
-                    # batch
+            try:
+                # ★ batch-verify the +2/3 commit for `first` carried
+                # in `second.last_commit` (reactor.go:310) — one TPU
+                # batch
+                with tracing.span("fastsync.verifyWait", cat="fastsync",
+                                  height=height):
                     self.state.validators.verify_commit(
-                        self.state.chain_id, first_id, first.header.height,
+                        self.state.chain_id, first_id, height,
                         second.last_commit,
                     )
-                except Exception as e:
-                    LOG.warning("invalid block %d during fast sync: %s",
-                                first.header.height, e)
-                    self.pool.redo_request(first.header.height)
-                    return processed > 0
-            self.pool.pop_request()
-            self.store.save_block(first, first_parts, second.last_commit)
-            # the pool head moved to k+1 after pop: stage it so the
-            # executor can run it speculatively on k's un-promoted
-            # overlay ([execution] speculate_depth >= 2; no-op default)
-            stage = getattr(self.block_exec, "stage_next_block", None)
-            if stage is not None:
-                nfirst, _ = self.pool.peek_two_blocks()
-                if nfirst is not None:
-                    stage(nfirst)
-            self.state = self.block_exec.apply_block(self.state, first_id, first)
-            self.blocks_synced += 1
-            processed += 1
-            if self.blocks_synced % 100 == 0:
-                LOG.info("fast sync at height %d", self.state.last_block_height)
-        return processed > 0
+            except Exception as e:
+                LOG.warning("invalid block %d during fast sync: %s",
+                            height, e)
+                self.pool.redo_request(height)
+                return False
+        self.pool.pop_request()
+        self.store.save_block(first, first_parts, second.last_commit)
+        # the pool head moved to k+1 after pop: stage it so the
+        # executor can run it speculatively on k's un-promoted
+        # overlay ([execution] speculate_depth >= 2; no-op default)
+        stage = getattr(self.block_exec, "stage_next_block", None)
+        if stage is not None:
+            nfirst, _ = self.pool.peek_two_blocks()
+            if nfirst is not None:
+                stage(nfirst)
+        self.state = self.block_exec.apply_block(self.state, first_id, first)
+        self.blocks_synced += 1
+        if self.blocks_synced % 100 == 0:
+            LOG.info("fast sync at height %d", self.state.last_block_height)
+        return True
 
     # -- pipelined sync (verify k+1 on-device while k applies) ---------
 
@@ -480,39 +533,55 @@ class BlockchainReactor(Reactor):
                 first, second = self.pool.peek_two_blocks()
                 if first is None or second is None:
                     break
-                spec = self._begin_block_verify(first, second)
-            err = self._resolve_block_verify(spec)
-            if err is not None:
-                LOG.warning(
-                    "invalid block %d during fast sync: %s",
-                    spec.first.header.height, err,
-                )
-                self.pool.redo_request(spec.first.header.height)
+            else:
+                first = spec.first
+            # everything from here to the end of apply(k) is block k's,
+            # except the dispatch of verify(k+1), which carries k+1's
+            # request id under k's span
+            with self._block_span(first):
+                if spec is None:
+                    spec = self._begin_block_verify(first, second)
+                nxt = self._sync_one_pipelined(
+                    spec, more=processed + 1 < SYNC_BATCH)
+            if nxt is False:
                 return processed > 0
-            self.pool.pop_request()
-            self.store.save_block(spec.first, spec.parts, spec.second.last_commit)
-            # dispatch verify(k+1) before apply(k): the pool head moved
-            # to k+1 after pop, so peek now yields the next pair
-            nxt = None
-            if processed + 1 < SYNC_BATCH:
-                nfirst, nsecond = self.pool.peek_two_blocks()
-                if nfirst is not None and nsecond is not None:
-                    nxt = self._begin_block_verify(nfirst, nsecond)
-                    # cross-height speculation: let k+1 execute on k's
-                    # un-promoted overlay while k applies (no-op unless
-                    # [execution] speculate_depth >= 2)
-                    stage = getattr(self.block_exec, "stage_next_block",
-                                    None)
-                    if stage is not None:
-                        stage(nfirst)
-            self.state = self.block_exec.apply_block(
-                self.state, spec.block_id, spec.first)
-            self.blocks_synced += 1
             processed += 1
-            if self.blocks_synced % 100 == 0:
-                LOG.info("fast sync at height %d", self.state.last_block_height)
             spec = nxt
         return processed > 0
+
+    def _sync_one_pipelined(self, spec, more: bool):
+        """Resolve block k's verify, save it, dispatch verify(k+1), apply
+        k. Returns k+1's speculative verify (None if there is no next
+        pair yet), or False if k's commit failed."""
+        height = spec.first.header.height
+        with tracing.span("fastsync.verifyWait", cat="fastsync",
+                          height=height):
+            err = self._resolve_block_verify(spec)
+        if err is not None:
+            LOG.warning("invalid block %d during fast sync: %s", height, err)
+            self.pool.redo_request(height)
+            return False
+        self.pool.pop_request()
+        self.store.save_block(spec.first, spec.parts, spec.second.last_commit)
+        # dispatch verify(k+1) before apply(k): the pool head moved
+        # to k+1 after pop, so peek now yields the next pair
+        nxt = None
+        if more:
+            nfirst, nsecond = self.pool.peek_two_blocks()
+            if nfirst is not None and nsecond is not None:
+                nxt = self._begin_block_verify(nfirst, nsecond)
+                # cross-height speculation: let k+1 execute on k's
+                # un-promoted overlay while k applies (no-op unless
+                # [execution] speculate_depth >= 2)
+                stage = getattr(self.block_exec, "stage_next_block", None)
+                if stage is not None:
+                    stage(nfirst)
+        self.state = self.block_exec.apply_block(
+            self.state, spec.block_id, spec.first)
+        self.blocks_synced += 1
+        if self.blocks_synced % 100 == 0:
+            LOG.info("fast sync at height %d", self.state.last_block_height)
+        return nxt
 
     def _begin_block_verify(self, first, second) -> "_SpeculativeVerify":
         """Start (async) commit verification of `first` against
@@ -521,18 +590,26 @@ class BlockchainReactor(Reactor):
         changed while the batch was in flight."""
         from ..types.validator_set import PendingCommitVerify
 
-        parts = make_part_set(first)
-        block_id = BlockID(hash=first.hash(), parts_header=parts.header())
+        height = first.header.height
+        request = ("block", height)
+        with tracing.span("fastsync.partSet", cat="fastsync",
+                          request=request, height=height):
+            parts = make_part_set(first)
+            block_id = BlockID(hash=first.hash(),
+                               parts_header=parts.header())
         vals = self.state.validators
-        try:
-            pending = vals.begin_verify_commit(
-                self.state.chain_id, block_id, first.header.height,
-                second.last_commit,
-            )
-        except Exception as e:  # structural pre-check failed synchronously
-            pending = PendingCommitVerify(exc=e)
+        with tracing.span("fastsync.verifyBegin", cat="fastsync",
+                          request=request, height=height):
+            try:
+                pending = vals.begin_verify_commit(
+                    self.state.chain_id, block_id, height,
+                    second.last_commit,
+                )
+            except Exception as e:  # structural pre-check failed synchronously
+                pending = PendingCommitVerify(exc=e)
+            val_hash = vals.hash()
         return _SpeculativeVerify(first, second, parts, block_id, pending,
-                                  vals.hash())
+                                  val_hash)
 
     def _resolve_block_verify(self, spec) -> Optional[Exception]:
         """Wait for a speculative verification; returns the failure (or

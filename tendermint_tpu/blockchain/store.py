@@ -23,6 +23,7 @@ import struct
 import threading
 from typing import Optional
 
+from ..libs import tracing
 from ..libs.db import DB
 from ..types import serde
 from ..types.basic import BlockID
@@ -93,7 +94,9 @@ class BlockStore:
         if block is None:
             raise ValueError("cannot save nil block")
         height = block.header.height
-        with self._lock:
+        with tracing.span("store.saveBlock", cat="store",
+                          request=("block", height), height=height,
+                          parts=part_set.total()), self._lock:
             if height != self._height + 1:
                 raise ValueError(
                     f"cannot save block at height {height}; expected {self._height + 1}"

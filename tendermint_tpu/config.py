@@ -460,7 +460,7 @@ class InstrumentationConfig:
     # ring-buffered span tracing of the consensus/crypto/WAL hot path;
     # exported as chrome://tracing JSON from the prof server
     tracing: bool = False
-    tracing_buffer_size: int = 65536
+    tracing_buffer_size: int = 131072
     # consensus stall watchdog (ours): a round dwelling past this many
     # seconds increments consensus_stalls_total{reason} and snapshots a
     # diagnostic bundle served at /debug/consensus on prof_laddr;

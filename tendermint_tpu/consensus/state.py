@@ -302,6 +302,7 @@ class ConsensusState:
         validators = state.validators.copy()
 
         rs.height = height
+        self.wal.height = height  # the request id of its spans
         rs.round = 0
         rs.step = STEP_NEW_HEIGHT
         if rs.commit_time == 0:
@@ -388,14 +389,14 @@ class ConsensusState:
         reference transition (enterPropose, …) plus one sample in the
         consensus_step_duration_seconds{step=...} histogram. Both are
         no-ops until the node enables instrumentation."""
-        t0 = time.perf_counter()
+        sp = self.tracer.timed("consensus." + span_name, cat="consensus",
+                               request=("block", height), height=height,
+                               round=round_)
         try:
-            with self.tracer.span("consensus." + span_name, cat="consensus",
-                                  height=height, round=round_):
+            with sp:
                 yield
         finally:
-            self.metrics.step_duration.with_labels(step).observe(
-                time.perf_counter() - t0)
+            self.metrics.step_duration.with_labels(step).observe(sp.seconds)
 
     # --- the receive loop ---------------------------------------------------
 
@@ -596,14 +597,15 @@ class ConsensusState:
 
             def finish() -> List[bool]:
                 with tracer.span("consensus.preverifyVotes", cat="consensus",
-                                 n=n, height=height):
+                                 request=("block", height), n=n,
+                                 height=height):
                     return _map(fut.result())
 
             return finish
 
         def finish_sync() -> List[bool]:
             with tracer.span("consensus.preverifyVotes", cat="consensus",
-                             n=n, height=height):
+                             request=("block", height), n=n, height=height):
                 return _map(crypto_batch.batch_verify(triples))
 
         return finish_sync

@@ -70,6 +70,12 @@ class WAL:
         # plain process-local count mirroring the metric — the
         # /debug/recovery provider reads it without a registry scrape
         self.corrupted_records = 0
+        # the height consensus is writing for (ConsensusState keeps it
+        # current): the request id of the wal.* spans, 0 before the first
+        self.height = 0
+
+    def _request(self):
+        return ("block", self.height) if self.height else None
 
     def start(self) -> None:
         self._started = True
@@ -88,14 +94,15 @@ class WAL:
 
     def write(self, msg) -> None:
         """Log a message (no fsync; reference Save → Write)."""
-        with tracing.span("wal.write", cat="wal"):
+        with tracing.span("wal.write", cat="wal", request=self._request()):
             payload = serde.pack(_msg_obj(msg))
             self.group.write(_encode_record(payload))
 
     def write_sync(self, msg) -> None:
         """Log + fsync — used for self-originated messages and EndHeight
         (reference consensus/state.go:609,1280)."""
-        with tracing.span("wal.writeSync", cat="wal"):
+        with tracing.span("wal.writeSync", cat="wal",
+                          request=self._request()):
             self.write(msg)
             self.group.sync()
 

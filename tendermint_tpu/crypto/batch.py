@@ -70,15 +70,6 @@ def get_metrics():
     return _metrics
 
 
-def record_device_split(transfer_s: float, compute_s: float) -> None:
-    """Called by the jax backend with the last batch's host->device
-    pack+transfer time vs on-device compute/wait time."""
-    m = _metrics
-    if m is not None:
-        m.device_transfer_seconds.set(transfer_s)
-        m.device_compute_seconds.set(compute_s)
-
-
 # --- process-wide [crypto] configuration (sig cache + async flag) ------
 #
 # Like the metrics sink above, these are process-global so every call
@@ -140,13 +131,17 @@ class VerifyFuture:
     backend exception — errors never die in the dispatch thread."""
 
     __slots__ = ("_event", "_mask", "_exc", "_t_submit", "_t_done",
-                 "_overlap_recorded")
+                 "_overlap_recorded", "_cause")
 
     def __init__(self):
         self._event = threading.Event()
         self._mask: Optional[List[bool]] = None
         self._exc: Optional[BaseException] = None
         self._t_submit = time.perf_counter()
+        # the submitting thread's open span: crypto.batchVerify on the
+        # dispatch thread names it as parent, and crypto.dispatchWait
+        # is the measured gap between the two
+        self._cause = tracing.cause()
         self._t_done: Optional[float] = None
         self._overlap_recorded = False
 
@@ -236,11 +231,13 @@ class _Dispatcher:
 
     @staticmethod
     def _execute(fn, fut: VerifyFuture, m) -> None:
+        _dispatched.fut = fut
         try:
             fut._set_result(fn())
         except BaseException as e:  # noqa: BLE001 - surfaces at result()
             fut._set_exception(e)
         finally:
+            _dispatched.fut = None
             _inflight_add(-1)
             if m is not None:
                 m.inflight_batches.add(-1)
@@ -265,6 +262,10 @@ class _Dispatcher:
 
 _dispatchers: dict = {}
 _dispatchers_lock = threading.Lock()
+
+# the future a dispatch thread is running, until the verify it carries
+# opens its crypto.batchVerify span (a fully cached batch opens none)
+_dispatched = threading.local()
 
 
 def _dispatcher(name: str) -> _Dispatcher:
@@ -423,6 +424,7 @@ class _Coalescer:
         merged = [t for _, items, _, _ in entries for t in items]
         mask = None
         exc: Optional[BaseException] = None
+        _dispatched.fut = entries[0][2]  # the oldest call's cause and wait
         try:
             saved = host._items
             host._items = merged
@@ -432,6 +434,8 @@ class _Coalescer:
                 host._items = saved
         except BaseException as e:  # noqa: BLE001 - surfaces at result()
             exc = e
+        finally:
+            _dispatched.fut = None
         if len(entries) > 1:
             m0 = entries[0][3]
             if m0 is not None:
@@ -480,6 +484,9 @@ class BatchVerifier:
     (test fakes do) — they just opt out of the built-in telemetry."""
 
     BACKEND = "unknown"
+    # how the batch got to this verifier: "direct", or the adaptive
+    # router's decision ("device" | "cpu") on the verifier it built
+    _route = "direct"
 
     def __init__(self):
         self._items: List[Triple] = []
@@ -538,7 +545,7 @@ class BatchVerifier:
             # for the dispatch (single-caller contract, like add/verify)
             self._items = [items[i] for i in miss_idx]
             try:
-                submask = self._verify_instrumented()
+                submask = self._verify_instrumented(cache_hits=hits)
             finally:
                 self._items = items
             for pos, i in enumerate(miss_idx):
@@ -550,21 +557,31 @@ class BatchVerifier:
                 verdicts[i] = verdicts[miss_idx[miss_pos[k]]]
         return verdicts
 
-    def _verify_instrumented(self) -> List[bool]:
-        """_verify() wrapped with latency/size/validity telemetry."""
+    def _verify_instrumented(self, cache_hits: int = 0) -> List[bool]:
+        """_verify() wrapped with latency/size/validity telemetry: the
+        histogram and the crypto.batchVerify span share two clock reads."""
         m = _metrics
         tracer = tracing.get_tracer()
         if m is None and not tracer.enabled:
             return self._verify()
         n = len(self._items)
-        with tracer.span("crypto.batchVerify", cat="crypto",
-                         backend=self.BACKEND, n=n):
-            t0 = time.perf_counter()
+        fut = cause = request = None
+        if tracer.enabled:
+            fut = getattr(_dispatched, "fut", None)
+            _dispatched.fut = None
+            cause = fut._cause if fut is not None else tracer.cause()
+            request = (cause and cause[1]) or tracer.request("batch")
+        with tracer.timed("crypto.batchVerify", cat="crypto", cause=cause,
+                          request=request, backend=self.BACKEND, n=n,
+                          route=self._route, cache_hits=cache_hits) as sp:
+            if fut is not None:
+                tracer.record("crypto.dispatchWait",
+                              int(fut._t_submit * 1e9), sp.start_ns,
+                              "crypto", backend=self.BACKEND, n=n)
             mask = self._verify()
-            dt = time.perf_counter() - t0
         if m is not None:
-            m.batch_verify_seconds.with_labels(self.BACKEND).observe(dt)
-            m.batch_size.observe(n)
+            m.batch_verify_seconds.with_labels(self.BACKEND).observe(sp.seconds)
+            m.batch_size.with_labels(self.BACKEND).observe(n)
             ok = sum(1 for b in mask if b)
             if ok:
                 m.signatures_verified.inc(ok)
@@ -671,6 +688,7 @@ class AdaptiveBatchVerifier(BatchVerifier):
             m.routing_decisions.with_labels(
                 "device" if use_device else "cpu").inc()
         inner = self._device_factory() if use_device else CPUBatchVerifier()
+        inner._route = "device" if use_device else "cpu"
         for msg, sig, pk in self._items:
             inner.add(msg, sig, pk)
         return inner.verify()

@@ -382,6 +382,7 @@ def _straus_call(bdim: int, interpret: bool):
         out_specs=[fe_spec] * 4,
         out_shape=[out_sh] * 4,
         interpret=interpret,
+        name="ed25519_straus",
     )
 
 
@@ -478,6 +479,7 @@ def _verify_tail_call(bdim: int, interpret: bool):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((1, bdim), jnp.int32),
         interpret=interpret,
+        name="ed25519_straus_fused",  # the kernel's name in a device trace
     )
 
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import batch as batch_mod
+from ...libs import tracing
 from .. import kernel_cache
 from ..batch import BatchVerifier
 from . import curve, pack, pallas_kernels, scalar, sha512
@@ -48,20 +48,26 @@ def on_tpu() -> bool:
 
 def _verify_core(msg_words, nblocks, a_y, a_sign, r_y, r_sign, s_limbs,
                  use_pallas: bool = False, pallas_interpret: bool = False):
-    digest = sha512.sha512_batch(msg_words, nblocks)
-    k = scalar.reduce_512(sha512.digest_to_scalar_limbs(digest))
+    # the scopes put each stage's name into its operations' names, so a
+    # device trace says which stage a `while` loop or a fusion belongs to
+    with jax.named_scope("sha512"):
+        digest = sha512.sha512_batch(msg_words, nblocks)
+        k = scalar.reduce_512(sha512.digest_to_scalar_limbs(digest))
     if use_pallas:
         # fused VMEM-resident tail: decompress -> Straus -> encode -> compare
         # (one Mosaic kernel, no HBM intermediates — see PROFILE.md);
         # interpret=True runs the SAME kernel path on a CPU mesh (dryrun)
         return pallas_kernels.verify_tail(a_y, a_sign, r_y, r_sign, s_limbs, k,
                                           interpret=pallas_interpret)
-    a_pt, ok_a = curve.decompress(a_y, a_sign)
-    # R' = [S]B + [k](−A) in ONE Straus chain (shared doublings)
-    r_prime = curve.straus_mul_sub(s_limbs, k, curve.negate(a_pt))
-    y, parity = curve.encode(r_prime)
-    eq = jnp.all(y == r_y, axis=0) & (parity == r_sign)
-    return ok_a & eq
+    with jax.named_scope("decompress"):
+        a_pt, ok_a = curve.decompress(a_y, a_sign)
+    with jax.named_scope("scalar_mul"):
+        # R' = [S]B + [k](−A) in ONE Straus chain (shared doublings)
+        r_prime = curve.straus_mul_sub(s_limbs, k, curve.negate(a_pt))
+    with jax.named_scope("compare"):
+        y, parity = curve.encode(r_prime)
+        eq = jnp.all(y == r_y, axis=0) & (parity == r_sign)
+        return ok_a & eq
 
 
 def _bytes_from_rows(rows_i32, nbytes: int):
@@ -233,6 +239,12 @@ def _jitted_packed_impl(nb: int, mrows: int, bpad: int, ndev: int,
                         use_pallas: bool, interp: bool,
                         donate: bool = False):
     donate_kw = {"donate_argnums": (0,)} if donate else {}
+
+    def body(buf):
+        return _verify_packed_core(buf, nb=nb, mrows=mrows,
+                                   use_pallas=use_pallas,
+                                   pallas_interpret=interp)
+
     if ndev > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -240,15 +252,15 @@ def _jitted_packed_impl(nb: int, mrows: int, bpad: int, ndev: int,
         # hands the body per-device blocks — exactly the shape the pallas
         # kernel wants — so the fused kernel runs per chip with no
         # cross-device traffic except the output concat
-        body = partial(_verify_packed_core, nb=nb, mrows=mrows,
-                       use_pallas=use_pallas, pallas_interpret=interp)
-        fn = jax.jit(_shard_map(body, _dp_mesh(ndev),
-                                in_specs=(P(None, "dp"),),
-                                out_specs=P("dp")), **donate_kw)
-    else:
-        fn = jax.jit(partial(_verify_packed_core, nb=nb, mrows=mrows,
-                             use_pallas=use_pallas,
-                             pallas_interpret=interp), **donate_kw)
+        body = _shard_map(body, _dp_mesh(ndev), in_specs=(P(None, "dp"),),
+                          out_specs=P("dp"))
+
+    # jitted from a named function, not a partial: the device trace then
+    # shows the program as jit_ed25519_verify_packed, not jit__unknown
+    def ed25519_verify_packed(buf):
+        return body(buf)
+
+    fn = jax.jit(ed25519_verify_packed, **donate_kw)
     if interp:
         # pallas interpret mode is a CPU-mesh dryrun path; its artifacts
         # are worthless cross-process and its lowering is the slow part
@@ -385,8 +397,6 @@ def verify_batch(msgs, sigs, pks, devices: int | None = None):
     n = len(msgs)
     if n == 0:
         return []
-    sig_arr, pk_arr, ok_host = _pack_well_formed(msgs, sigs, pks)
-
     ndev = devices if devices is not None else len(jax.devices())
     try:
         chunks = int(os.environ.get("TM_TPU_VERIFY_CHUNKS", "1"))
@@ -396,53 +406,67 @@ def verify_batch(msgs, sigs, pks, devices: int | None = None):
         chunks, chunk_min = 1, 2048
     if chunks < 2 or n < chunk_min:
         chunks = 1
-
-    # one jit key for every chunk, derived from GLOBAL maxima: a chunk
-    # with its own (nb, mrows, bpad) would trigger a fresh multi-second
-    # compile inside the live path, which warmup() exists to prevent
     per = (n + chunks - 1) // chunks
-    maxlen = max((len(m) for m in msgs), default=0)
-    nb = (64 + maxlen + 17 + 127) // 128
-    mrows = max(16, ((maxlen + 3) // 4 + 15) // 16 * 16)
-    bpad = _bucket(per)
-    if ndev > 1:
-        bpad = max(bpad, ndev)
-        bpad = (bpad + ndev - 1) // ndev * ndev
-    fn = _jitted_packed(nb, mrows, bpad, ndev, donate=_donate_default())
 
-    # host-buffer reuse only where device_put copies out of the host
-    # array (accelerators); the CPU backend can alias numpy memory, and
-    # an aliased buffer must never be repacked under an in-flight kernel
-    reuse_host = chunks > 1 and jax.default_backend() != "cpu"
-    bufs = (_host_buf_ring(chunks, (ROWS_AUX + mrows, bpad))
-            if reuse_host else None)
-
-    # transfer-vs-compute attribution for the CryptoMetrics split gauges
-    # (PROFILE.md round 4 measured this with one-off scripts; now it is
-    # always on). device_put and the dispatch are async, so "transfer"
-    # is host pack + h2d submission and "compute" is the blocking wait
-    # for result materialization — the same split the profiling scripts
-    # reported, measured per live batch.
-    t_transfer = 0.0
-    t0 = time.perf_counter()
-    masks = []
-    for idx, lo in enumerate(range(0, n, per)):
+    # The host side of a device batch as five spans under the jax
+    # backend's crypto.batchVerify (README "Spans"): together they are
+    # the batch's whole wall. device_put and the dispatch are async, so
+    # verify.h2d and verify.launch are submissions and verify.wait is
+    # the blocking read-back of the masks. The first verify.pack also
+    # holds the length checks and the kernel lookup.
+    def pack_chunk(idx):
+        lo = idx * per
         hi = min(lo + per, n)
         buf, _, _, _ = pack_buffer(
             msgs[lo:hi], sig_arr[lo:hi], pk_arr[lo:hi], ndev,
             dims=(nb, mrows, bpad),
             out=bufs[idx] if reuse_host else None)
+        return buf, hi - lo
+
+    with tracing.span("verify.pack", cat="crypto", n=n, chunk=0) as sp:
+        sig_arr, pk_arr, ok_host = _pack_well_formed(msgs, sigs, pks)
+        # one jit key for every chunk, derived from GLOBAL maxima: a chunk
+        # with its own (nb, mrows, bpad) would trigger a fresh multi-second
+        # compile inside the live path, which warmup() exists to prevent
+        maxlen = max((len(m) for m in msgs), default=0)
+        nb = (64 + maxlen + 17 + 127) // 128
+        mrows = max(16, ((maxlen + 3) // 4 + 15) // 16 * 16)
+        bpad = _bucket(per)
+        if ndev > 1:
+            bpad = max(bpad, ndev)
+            bpad = (bpad + ndev - 1) // ndev * ndev
+        fn = _jitted_packed(nb, mrows, bpad, ndev, donate=_donate_default())
+
+        # host-buffer reuse only where device_put copies out of the host
+        # array (accelerators); the CPU backend can alias numpy memory, and
+        # an aliased buffer must never be repacked under an in-flight kernel
+        reuse_host = chunks > 1 and jax.default_backend() != "cpu"
+        bufs = (_host_buf_ring(chunks, (ROWS_AUX + mrows, bpad))
+                if reuse_host else None)
+        shape = {"bucket": bpad, "nb": nb, "mrows": mrows}
+        sp.set(**shape)
+        buf, cn = pack_chunk(0)
+
+    masks = []
+    for idx in range((n + per - 1) // per):
+        if idx:
+            with tracing.span("verify.pack", cat="crypto", n=n, chunk=idx,
+                              **shape):
+                buf, cn = pack_chunk(idx)
         # device_put + dispatch are async: the NEXT chunk's pack and
         # h2d transfer overlap this chunk's kernel (with chunks=1 this
         # is the plain single-dispatch pipeline)
-        dev = _put(buf, ndev)
-        t_transfer += time.perf_counter() - t0
-        masks.append((fn(dev), hi - lo))
-        t0 = time.perf_counter()
-    out = np.concatenate([np.asarray(m)[:cn] for m, cn in masks]) & ok_host
-    t_compute = time.perf_counter() - t0
-    batch_mod.record_device_split(t_transfer, t_compute)
-    return [bool(v) for v in out]
+        with tracing.span("verify.h2d", cat="crypto", n=cn, chunk=idx,
+                          **shape):
+            dev = _put(buf, ndev)
+        with tracing.span("verify.launch", cat="crypto", n=cn, chunk=idx,
+                          **shape):
+            masks.append((fn(dev), cn))
+    with tracing.span("verify.wait", cat="crypto", n=n, **shape):
+        host = [np.asarray(m)[:cn] for m, cn in masks]
+    with tracing.span("verify.unpack", cat="crypto", n=n, **shape):
+        out = np.concatenate(host) & ok_host
+        return [bool(v) for v in out]
 
 
 # --- aggregate (random-linear-combination) verification --------------------
@@ -609,14 +633,17 @@ def make_sharded_commit_step(mesh, force_pallas=None):
         hi = jnp.sum(counted >> 16)
         return mask, jax.lax.psum(lo, "dp"), jax.lax.psum(hi, "dp")
 
-    return jax.jit(
-        _shard_map(
-            step,
-            mesh,
-            in_specs=(dp(4), dp(1), dp(2), dp(1), dp(2), dp(1), dp(2), dp(1), dp(1)),
-            out_specs=(dp(1), P(), P()),
-        )
+    sharded = _shard_map(
+        step,
+        mesh,
+        in_specs=(dp(4), dp(1), dp(2), dp(1), dp(2), dp(1), dp(2), dp(1), dp(1)),
+        out_specs=(dp(1), P(), P()),
     )
+
+    def ed25519_commit_sharded(*args):  # the program's name on the device
+        return sharded(*args)
+
+    return jax.jit(ed25519_commit_sharded)
 
 
 def tallied_power(lo, hi) -> int:

@@ -50,6 +50,8 @@ import time
 import weakref
 from typing import Callable, Optional
 
+from ..libs import tracing
+
 LOG = logging.getLogger("crypto.kernel_cache")
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
@@ -348,9 +350,13 @@ def load_or_compile(kernel: str, static_key: tuple, jitted, args):
     m = _metrics()
     key = _full_key(kernel, static_key, args)
     path = _artifact_path(kernel, key)
+    span_key = str(list(static_key))
     t0 = time.perf_counter()
     if path is not None:
-        compiled = _try_load(kernel, key, path)
+        with tracing.span("kernel.load", cat="crypto", kernel=kernel,
+                          key=span_key) as sp:
+            compiled = _try_load(kernel, key, path)
+            sp.set(hit=compiled is not None)
         if compiled is not None:
             _bump("hits")
             if m is not None:
@@ -360,7 +366,9 @@ def load_or_compile(kernel: str, static_key: tuple, jitted, args):
         _bump("misses")
         if m is not None:
             m.compile_cache_misses.inc()
-    compiled = _timed_compile(kernel, jitted, args)
+    with tracing.span("kernel.compile", cat="crypto", kernel=kernel,
+                      key=span_key):
+        compiled = _timed_compile(kernel, jitted, args)
     _note_ready(kernel, static_key, args, t0, "compiled")
     if path is not None:
         _try_store(kernel, key, path, compiled)
@@ -401,4 +409,5 @@ def aot_wrap(kernel: str, static_key: tuple, jitted) -> Callable:
         return fn(*args)
 
     call.kernel_name = kernel
+    call.jitted = jitted  # for lowering without running (tests, rehearsals)
     return call

@@ -20,6 +20,8 @@ from dataclasses import dataclass, field
 from datetime import date, datetime, timezone
 from typing import Callable, Dict, List, Optional
 
+from . import tracing
+
 
 class QueryError(ValueError):
     pass
@@ -174,6 +176,9 @@ class Query:
 class Message:
     data: object
     tags: Dict[str, str] = field(default_factory=dict)
+    # libs/tracing cause() of the publishing thread (None while the
+    # recorder is off): a subscriber's span names it as its parent
+    cause: Optional[tuple] = None
 
 
 class Subscription:
@@ -305,7 +310,7 @@ class PubSub:
             s.cancel()
 
     def publish(self, data: object, tags: Dict[str, str]) -> None:
-        msg = Message(data, tags)
+        msg = Message(data, tags, tracing.cause())
         with self._lock:
             subs = list(self._subs.values())
         for sub in subs:
@@ -324,7 +329,8 @@ class PubSub:
         a `tm.event = 'Tx'` subscription costs one evaluation, not N;
         a per-hash query still evaluates per message (every shape is
         distinct) and loses nothing."""
-        msgs = [Message(d, t) for d, t in items]
+        cause = tracing.cause()
+        msgs = [Message(d, t, cause) for d, t in items]
         if not msgs:
             return
         with self._lock:

@@ -46,6 +46,7 @@ from dataclasses import dataclass
 from typing import List, Optional
 
 from ..abci import types as abci
+from ..libs import tracing
 
 LOG = logging.getLogger("mempool.preverify")
 
@@ -172,6 +173,9 @@ class TxFuture(_futures.Future):
     def __init__(self):
         super().__init__()
         self.submitted_at = time.perf_counter()
+        # the submitting thread's open span (an rpc.call): the
+        # ingest.drain that takes this tx names it as its cause
+        self.cause = tracing.cause()
 
 
 class IngestQueue:
@@ -281,6 +285,24 @@ class IngestQueue:
                         fut.set_exception(e)
 
     def _process(self, batch: List[tuple]) -> None:
+        # one span per drain, never per tx; its cause is the oldest
+        # tx's rpc.call (`causes` counts the distinct ones it took from)
+        tracer = tracing.get_tracer()
+        with tracer.span("ingest.drain", cat="mempool",
+                         cause=batch[0][1].cause,
+                         request=tracer.request("drain"),
+                         n=len(batch)) as sp:
+            rejected = self._drain(batch)
+            if tracer.enabled:
+                now = time.perf_counter()
+                sp.set(rejected=rejected,
+                       causes=len({fut.cause for _, fut in batch}),
+                       wait_max_ms=round(max(
+                           now - fut.submitted_at for _, fut in batch) * 1e3, 3))
+
+    def _drain(self, batch: List[tuple]) -> int:
+        """Pre-verify and admit one drained batch; returns how many
+        enveloped txs were rejected for a bad signature."""
         from ..crypto import batch as crypto_batch
 
         metrics = self.mempool.metrics
@@ -315,20 +337,25 @@ class IngestQueue:
         # per drain, instead of a lock + app round trip per tx
         admit_slots = []
         admit_items = []
+        rejected = 0
         for i, (tx, fut) in enumerate(batch):
             p = parsed[i]
             if p is not None and not verdict.get(i, False):
                 metrics.preverify_rejected.inc()
                 fut.set_result(reject_response())
+                rejected += 1
                 continue
             admit_slots.append(i)
             admit_items.append((tx, p))
         if not admit_items:
-            return
-        results = self.mempool._admit_preverified_batch(admit_items)
+            return rejected
+        with tracing.span("ingest.checkTx", cat="mempool",
+                          n=len(admit_items)):
+            results = self.mempool._admit_preverified_batch(admit_items)
         for i, res in zip(admit_slots, results):
             fut = batch[i][1]
             if isinstance(res, BaseException):
                 fut.set_exception(res)
             else:
                 fut.set_result(res)
+        return rejected

@@ -72,15 +72,12 @@ class CryptoMetrics:
 
     # wall time of one verify() call, labeled by the backend that ran it
     batch_verify_seconds: object = NOP
-    # signatures per verify() call
+    # signatures per verify() call, labeled like batch_verify_seconds
     batch_size: object = NOP
     signatures_verified: object = NOP
     signatures_invalid: object = NOP
     # adaptive router choices, labeled route=cpu|device
     routing_decisions: object = NOP
-    # last jax call's host->device transfer vs on-device compute split
-    device_transfer_seconds: object = NOP
-    device_compute_seconds: object = NOP
     # verified-signature cache (crypto/sigcache.py): triples served from
     # cache vs dispatched to a backend
     sig_cache_hits: object = NOP
@@ -641,7 +638,8 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
                      0.01, 0.025, 0.05, 0.1, 0.25, 1)),
         batch_size=r.histogram(
             f"{ns}_crypto_batch_size",
-            "Signatures per batch-verify call.",
+            "Signatures per batch-verify call, by backend.",
+            ("backend",),
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024,
                      4096)),
         signatures_verified=r.counter(
@@ -653,12 +651,6 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
         routing_decisions=r.counter(
             f"{ns}_crypto_batch_routing_total",
             "Adaptive batch-verify routing decisions.", ("route",)),
-        device_transfer_seconds=r.gauge(
-            f"{ns}_crypto_device_transfer_seconds",
-            "Host->device pack+transfer time of the last jax batch."),
-        device_compute_seconds=r.gauge(
-            f"{ns}_crypto_device_compute_seconds",
-            "On-device compute/wait time of the last jax batch."),
         sig_cache_hits=r.counter(
             f"{ns}_crypto_sig_cache_hits_total",
             "Triples served from the verified-signature cache."),

@@ -39,6 +39,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional
 from urllib.parse import parse_qsl, urlparse
 
+from ..libs import tracing
 from ..libs.events import Query
 from . import jsonrpc
 from .cache import RPCCache
@@ -324,6 +325,16 @@ def _make_handler(server: RPCServer):
             self._send_body(jsonrpc.dumps(obj), status=status)
 
         def do_POST(self):
+            # rpc.handle is one POST from its first body byte to its last
+            # response byte; its children split it into read (body +
+            # JSON decode), one rpc.call per run of one method in a
+            # batch (never one per request) and write
+            tracer = tracing.get_tracer()
+            with tracer.span("rpc.handle", cat="rpc",
+                             request=tracer.request("post")) as sp:
+                self._post(sp)
+
+        def _post(self, sp) -> None:
             try:
                 length = int(self.headers.get("Content-Length", 0))
             except (TypeError, ValueError):
@@ -336,17 +347,41 @@ def _make_handler(server: RPCServer):
                         None, jsonrpc.ERR_INVALID_REQUEST,
                         f"request body exceeds {MAX_BODY_BYTES} bytes"),
                     status=413)
-            raw = self.rfile.read(length)
-            try:
-                req = jsonrpc.loads(raw)
-            except RPCError as e:
+            with tracing.span("rpc.read", cat="rpc", bytes=length):
+                raw = self.rfile.read(length)
+                try:
+                    req = jsonrpc.loads(raw)
+                except RPCError as e:
+                    req = e
+            if isinstance(req, RPCError):
                 return self._send_json(
-                    jsonrpc.error_response(None, e.code, e.message))
+                    jsonrpc.error_response(None, req.code, req.message))
             if isinstance(req, list):  # batch
-                return self._send_body(
-                    b"[" + b",".join(self._handle_one(r) for r in req)
-                    + b"]")
-            self._send_body(self._handle_one(req))
+                sp.set(n=len(req), bytes=length)
+                body = b"[" + b",".join(self._handle_runs(req)) + b"]"
+            else:
+                sp.set(n=1, bytes=length)
+                body = self._handle_runs([req])[0]
+            with tracing.span("rpc.write", cat="rpc", bytes=len(body)):
+                self._send_body(body)
+
+        def _handle_runs(self, reqs: list) -> list:
+            """_handle_one over a batch, one rpc.call span per run of
+            consecutive requests naming the same method."""
+            out = []
+            i = 0
+            while i < len(reqs):
+                method = (reqs[i].get("method")
+                          if isinstance(reqs[i], dict) else None)
+                j = i + 1
+                while (j < len(reqs) and isinstance(reqs[j], dict)
+                       and reqs[j].get("method") == method):
+                    j += 1
+                with tracing.span("rpc.call", cat="rpc", method=str(method),
+                                  n=j - i):
+                    out.extend(self._handle_one(r) for r in reqs[i:j])
+                i = j
+            return out
 
         def _handle_one(self, req) -> bytes:
             if not isinstance(req, dict) or "method" not in req:
@@ -455,6 +490,9 @@ class WSConn:
         self._q_cap = server.ws_send_queue
         self._q_cond = threading.Condition()
         self._q_hwm = 0
+        # height of the newest NewBlock frame queued and not yet handed
+        # to the writer (0: none); read only for rpc.wsSend's `height`
+        self._block_height = 0
         self.events_sent = 0
         self.events_dropped = 0
         self._writer: Optional[threading.Thread] = None
@@ -565,13 +603,14 @@ class WSConn:
     # not deterministically everything past the cap
     ENQUEUE_CHUNK = 32
 
-    def enqueue_events(self, frames) -> int:
+    def enqueue_events(self, frames, block_height: int = 0) -> int:
         """Queue a drained batch of pre-rendered frames in chunked lock
         holds. Per-frame semantics match enqueue_event: each frame past
         capacity is counted dropped INDIVIDUALLY (a burst shedding k
         frames bumps the counters by k), the writer can drain between
         chunks, and the disconnect policy trips on the first overflow.
-        Returns the number queued."""
+        `block_height` is the NewBlock among them, for the writer's
+        span. Returns the number queued."""
         if self._closed.is_set() or not frames:
             return 0
         disconnect = False
@@ -580,6 +619,8 @@ class WSConn:
         for start in range(0, len(frames), self.ENQUEUE_CHUNK):
             chunk = frames[start:start + self.ENQUEUE_CHUNK]
             with self._q_cond:
+                if block_height:
+                    self._block_height = block_height
                 chunk_accepted = 0
                 for frame in chunk:
                     if len(self._q) >= self._q_cap:
@@ -615,15 +656,31 @@ class WSConn:
                 if self._closed.is_set() and not self._q:
                     return
                 frame = self._q.popleft()
-            try:
-                self.send_frame(frame)
-                self.events_sent += 1
-            except OSError:
-                self._closed.set()
-                with self._q_cond:
-                    self._q.clear()
-                    self._q_cond.notify_all()
-                return
+                height, self._block_height = self._block_height, 0
+            # rpc.wsSend: one span per burst — from the first frame
+            # popped until the queue is found empty — never one per frame
+            # and never across the wait above
+            frames = nbytes = 0
+            with tracing.span("rpc.wsSend", cat="rpc") as sp:
+                try:
+                    while frame is not None:
+                        self.send_frame(frame)
+                        self.events_sent += 1
+                        frames += 1
+                        nbytes += len(frame)
+                        with self._q_cond:
+                            frame = self._q.popleft() if self._q else None
+                            height = self._block_height or height
+                            self._block_height = 0
+                except OSError:
+                    self._closed.set()
+                    with self._q_cond:
+                        self._q.clear()
+                        self._q_cond.notify_all()
+                    return
+                finally:
+                    sp.set(frames=frames, bytes=nbytes,
+                           **({"height": height} if height else {}))
 
     # -- serve loop ----------------------------------------------------
 
@@ -745,4 +802,9 @@ class WSConn:
             msgs = sub.get_batch(256, timeout=0.5)
             if not msgs:
                 continue
-            self.enqueue_events(render_event_frames(msgs, qs))
+            height = 0
+            if tracing.get_tracer().enabled:
+                for m in msgs:
+                    if m.tags.get("tm.event") == "NewBlock":
+                        height = m.data["block"].header.height
+            self.enqueue_events(render_event_frames(msgs, qs), height)

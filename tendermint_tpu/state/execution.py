@@ -14,7 +14,7 @@ from typing import List, Optional
 
 from ..abci import types as abci
 from ..crypto import merkle, pubkey_from_bytes
-from ..libs import fail
+from ..libs import fail, tracing
 from ..libs.db import DB
 from ..types import serde
 from ..types.basic import BlockID
@@ -266,12 +266,11 @@ class BlockExecutor:
         fire events. Returns the new State (reference execution.go:89-152)."""
         import time as _time
 
-        from ..libs import tracing
-
         from ..abci.client import ABCIAppRestartedError
 
         _t0 = _time.monotonic()
         with tracing.span("state.applyBlock", cat="state",
+                          request=("block", block.header.height),
                           height=block.header.height,
                           txs=len(block.data.txs)):
             try:
@@ -289,96 +288,95 @@ class BlockExecutor:
 
     def _apply_block_inner(self, state: State, block_id: BlockID,
                            block: Block, _t0: float) -> State:
+        """Every stage is a child span of state.applyBlock (README
+        "Spans"); where a CommitStageProfile stage brackets the same
+        lines it is observed from the span's own two clock reads."""
         import time as _time
 
+        height = block.header.height
         # apply-time blocks are DECIDED (commit apply, replay, fast
         # sync) — proposal-only checks like the aggregate-lane clock
         # drift bound must not reject them
-        self.validate_block(state, block, decided=True)
+        with tracing.span("state.validateBlock", cat="state", height=height):
+            self.validate_block(state, block, decided=True)
 
-        from ..libs import tracing
-
-        _t_exec = _time.perf_counter()
-        with tracing.span("commit.execute", cat="state",
-                          height=block.header.height):
+        with tracing.timed("commit.execute", cat="state",
+                           height=height) as sp:
             abci_responses = self._exec_block(state, block)
-        self.stage_profile.observe(
-            "execute", _time.perf_counter() - _t_exec)
+        self.stage_profile.observe("execute", sp.seconds)
 
         fail.fail_point("ApplyBlock.SaveABCIResponses")  # execution.go:103
-        save_abci_responses(self.db, block.header.height, abci_responses)
-        # durability barrier: the app Commit below makes the app's state
-        # ahead of the chain's — recoverable ONLY through the stored
-        # responses (the app==store handshake path). If this record can
-        # vanish with an un-synced page-cache tail, that crash window is
-        # unrecoverable (found by the crash matrix:
-        # ApplyBlock.AfterCommit x state_torn), so fsync it FIRST.
-        sync = getattr(self.db, "sync", None)
-        if sync is not None:
-            sync()
+        with tracing.span("state.saveResponses", cat="state", height=height):
+            save_abci_responses(self.db, height, abci_responses)
+            # durability barrier: the app Commit below makes the app's
+            # state ahead of the chain's — recoverable ONLY through the
+            # stored responses (the app==store handshake path). If this
+            # record can vanish with an un-synced page-cache tail, that
+            # crash window is unrecoverable (found by the crash matrix:
+            # ApplyBlock.AfterCommit x state_torn), so fsync it FIRST.
+            sync = getattr(self.db, "sync", None)
+            if sync is not None:
+                sync()
         fail.fail_point("ApplyBlock.AfterSaveABCIResponses")  # execution.go:108
 
-        val_updates = _abci_validator_updates(abci_responses)
-        if val_updates:
-            self.logger.info("updates to validators: %d", len(val_updates))
-            self.metrics.validator_updates.inc(len(val_updates))
-            self.metrics.valset_changes.inc()
+        with tracing.span("state.updateState", cat="state", height=height):
+            val_updates = _abci_validator_updates(abci_responses)
+            if val_updates:
+                self.logger.info("updates to validators: %d",
+                                 len(val_updates))
+                self.metrics.validator_updates.inc(len(val_updates))
+                self.metrics.valset_changes.inc()
 
-        state = update_state(state, block_id, block.header, abci_responses)
+            state = update_state(state, block_id, block.header,
+                                 abci_responses)
 
         # lock mempool, commit app state, update mempool (execution.go:130-135)
         app_hash = self.commit(state, block)
 
         fail.fail_point("ApplyBlock.AfterCommit")  # execution.go:139
 
-        if self.evidence_pool is not None:
-            self.evidence_pool.update(block, state)
+        with tracing.span("state.saveState", cat="state", height=height):
+            if self.evidence_pool is not None:
+                self.evidence_pool.update(block, state)
 
-        state.app_hash = app_hash
-        save_state(self.db, state)
+            state.app_hash = app_hash
+            save_state(self.db, state)
 
         fail.fail_point("ApplyBlock.AfterSaveState")  # execution.go:145
 
         self.metrics.block_processing_time.observe(_time.monotonic() - _t0)
-        _t_ev = _time.perf_counter()
-        with tracing.span("commit.events", cat="state",
-                          height=block.header.height):
+        with tracing.timed("commit.events", cat="state", height=height) as sp:
             self._fire_events(block, abci_responses, val_updates)
-        self.stage_profile.observe("events", _time.perf_counter() - _t_ev)
+        self.stage_profile.observe("events", sp.seconds)
         return state
 
     def commit(self, state: State, block: Block) -> bytes:
         """App Commit under mempool lock; then mempool Update/recheck
         (reference execution.go:160-202). Returns the new app hash."""
+        height = block.header.height
         if self.mempool is not None:
             self.mempool.lock()
         try:
             if self.mempool is not None:
                 self.mempool.flush_app_conn()
-            import time as _time
-
-            _t_ac = _time.perf_counter()
-            res = self.proxy_app.commit()
-            self.stage_profile.observe(
-                "app_commit", _time.perf_counter() - _t_ac)
+            with tracing.timed("commit.appCommit", cat="state",
+                               height=height) as sp:
+                res = self.proxy_app.commit()
+            self.stage_profile.observe("app_commit", sp.seconds)
             self.logger.debug(
                 "committed state: height=%d app_hash=%s",
-                block.header.height,
+                height,
                 res.data.hex()[:16],
             )
             if self.mempool is not None:
-                from ..libs import tracing
-
-                _t0 = _time.perf_counter()
-                with tracing.span("commit.mempool_update", cat="state",
-                                  height=block.header.height):
+                with tracing.timed("commit.mempool_update", cat="state",
+                                   height=height) as sp:
                     self.mempool.update(
-                        block.header.height,
+                        height,
                         block.data.txs,
                         pre_check=_tx_pre_check(state),
                     )
-                self.stage_profile.observe(
-                    "mempool_update", _time.perf_counter() - _t0)
+                self.stage_profile.observe("mempool_update", sp.seconds)
             return res.data
         finally:
             if self.mempool is not None:

@@ -367,29 +367,39 @@ class IndexerService(BaseService):
         self._thread.start()
 
     def _ingest(self, msgs) -> None:
-        import time as _time
+        from ..libs import tracing
 
         results = [
             TxResult(height=m.data["height"], index=m.data["index"],
                      tx=m.data["tx"], result=m.data["result"])
             for m in msgs
         ]
-        _t0 = _time.perf_counter()
+        # txindex.drain: one span per block of a drain, caused by the
+        # commit.events that published the block's txs; the "index"
+        # stage is the spans' own clock reads, summed over the drain
+        seconds = 0.0
         if not self.batch:
-            for r in results:
-                self.indexer.index(r)
+            with tracing.timed("txindex.drain", cat="index",
+                               cause=msgs[0].cause, txs=len(results)) as sp:
+                for r in results:
+                    self.indexer.index(r)
+            seconds = sp.seconds
         else:
             # group consecutive same-height runs: one index_batch per
             # block even when a drain straddles several blocks
             start = 0
             for i in range(1, len(results) + 1):
                 if i == len(results) or results[i].height != results[start].height:
-                    self.indexer.index_batch(
-                        results[start].height, results[start:i])
+                    height = results[start].height
+                    with tracing.timed("txindex.drain", cat="index",
+                                       cause=msgs[start].cause,
+                                       request=("block", height),
+                                       height=height, txs=i - start) as sp:
+                        self.indexer.index_batch(height, results[start:i])
+                    seconds += sp.seconds
                     start = i
         if self.stage_profile is not None and results:
-            self.stage_profile.observe(
-                "index", _time.perf_counter() - _t0)
+            self.stage_profile.observe("index", seconds)
 
     def _run(self) -> None:
         while not self._quit.is_set():

@@ -261,14 +261,19 @@ class ValidatorSet:
         verify_commit_aggregate: ONE pairing check regardless of
         committee size.
         """
+        from ..libs import tracing
         from .block import AggregateCommit
 
-        if isinstance(commit, AggregateCommit):
-            self.verify_commit_aggregate(chain_id, block_id, height, commit)
-            return
-        bv, entries = self._prepare_commit_verify(chain_id, block_id, height, commit)
-        mask, psum_tally = self._run_batch_verify(bv, entries, block_id)
-        self._finish_commit_verify(mask, psum_tally, entries, block_id)
+        with tracing.span("valset.verifyCommit", cat="types", height=height,
+                          n=len(self.validators)):
+            if isinstance(commit, AggregateCommit):
+                self.verify_commit_aggregate(chain_id, block_id, height,
+                                             commit)
+                return
+            bv, entries = self._prepare_commit_verify(
+                chain_id, block_id, height, commit)
+            mask, psum_tally = self._run_batch_verify(bv, entries, block_id)
+            self._finish_commit_verify(mask, psum_tally, entries, block_id)
 
     def _gate_commit_aggregate(self, chain_id: str, block_id: BlockID,
                                height: int, commit):

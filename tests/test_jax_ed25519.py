@@ -107,6 +107,25 @@ def test_jax_verify_batch(batch):
     assert got == want
 
 
+def test_verify_program_is_named_for_the_profiler(batch):
+    """The device trace finds the program and its stages by name: the
+    jitted function is `ed25519_verify_packed`, not a partial, and the
+    XLA path's operations carry their stage's scope. Lowers the shape
+    test_jax_verify_batch ran, so the trace is already cached."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.crypto.jaxed25519 import verify as V
+
+    fn = V._jitted_packed(3, 80, 16, 1, donate=False)
+    assert fn.jitted.__name__ == "ed25519_verify_packed"
+    text = fn.jitted.lower(jax.ShapeDtypeStruct(
+        (V.ROWS_AUX + 80, 16), jnp.int32)).as_text(debug_info=True)
+    assert "module @jit_ed25519_verify_packed" in text
+    for scope in ("sha512", "decompress", "scalar_mul", "compare"):
+        assert f"jit(ed25519_verify_packed)/{scope}/" in text, scope
+
+
 def test_jax_verify_multidevice(batch):
     import jax
 

@@ -105,12 +105,10 @@ def test_crypto_and_step_metrics_exposition_golden():
 
     m = prometheus_metrics("tm")
     m.crypto.batch_verify_seconds.with_labels("jax").observe(0.002)
-    m.crypto.batch_size.observe(64)
+    m.crypto.batch_size.with_labels("jax").observe(64)
     m.crypto.signatures_verified.inc(63)
     m.crypto.signatures_invalid.inc(1)
     m.crypto.routing_decisions.with_labels("device").inc()
-    m.crypto.device_transfer_seconds.set(0.0004)
-    m.crypto.device_compute_seconds.set(0.0016)
     m.consensus.step_duration.with_labels("propose").observe(0.01)
 
     out = m.registry.render()
@@ -120,15 +118,12 @@ def test_crypto_and_step_metrics_exposition_golden():
         'tm_crypto_batch_verify_seconds_bucket{backend="jax",le="+Inf"} 1',
         'tm_crypto_batch_verify_seconds_count{backend="jax"} 1',
         "# TYPE tm_crypto_batch_size histogram",
-        'tm_crypto_batch_size_bucket{le="64"} 1',
-        "tm_crypto_batch_size_count 1",
+        'tm_crypto_batch_size_bucket{backend="jax",le="64"} 1',
+        'tm_crypto_batch_size_count{backend="jax"} 1',
         "# TYPE tm_crypto_signatures_verified_total counter",
         "tm_crypto_signatures_verified_total 63",
         "tm_crypto_signatures_invalid_total 1",
         'tm_crypto_batch_routing_total{route="device"} 1',
-        "# TYPE tm_crypto_device_transfer_seconds gauge",
-        "tm_crypto_device_transfer_seconds 0.0004",
-        "tm_crypto_device_compute_seconds 0.0016",
         "# TYPE tm_consensus_step_duration_seconds histogram",
         'tm_consensus_step_duration_seconds_bucket{step="propose",le="0.01"} 1',
         'tm_consensus_step_duration_seconds_count{step="propose"} 1',
@@ -146,8 +141,7 @@ def test_nop_metrics_accept_observability_calls():
 
     m = nop_metrics()
     m.crypto.batch_verify_seconds.with_labels("cpu").observe(0.1)
-    m.crypto.batch_size.observe(8)
+    m.crypto.batch_size.with_labels("cpu").observe(8)
     m.crypto.signatures_verified.inc(8)
     m.crypto.routing_decisions.with_labels("cpu").inc()
-    m.crypto.device_transfer_seconds.set(0.0)
     m.consensus.step_duration.with_labels("commit").observe(0.1)
