@@ -327,6 +327,12 @@ REQUIRED_FAMILIES = (
     "replica_tree_depth",
     "replica_parent_switches_total",
     "replica_lag_blocks",
+    # PR-27 one Merkle root per validator set, one verification per
+    # commit (types_valset_hash_total is live on any node: make_block
+    # and validate_block ask for the root; last_commit_check_total
+    # counts from the second block on, handed_down under fast sync only)
+    "types_valset_hash_total",
+    "state_last_commit_check_total",
 )
 
 # ...and of those, the hot-path families that must have RECORDED samples

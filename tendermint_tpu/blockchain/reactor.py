@@ -24,6 +24,7 @@ from typing import Optional
 
 from ..libs import tracing
 from ..p2p.base_reactor import ChannelDescriptor, Reactor
+from ..state.validation import VerifiedCommit
 from ..types import serde
 from ..types.basic import BlockID
 from ..types.block import make_part_set
@@ -89,6 +90,8 @@ class BlockchainReactor(Reactor):
         self._stop = threading.Event()
         self._pool_thread: Optional[threading.Thread] = None
         self.blocks_synced = 0
+        # what _apply_verified noted of the last commit the loop verified
+        self._verified_commit: Optional[VerifiedCommit] = None
         # height -> cause of the p2p.recvBlock that decoded it, so the
         # sync loop's fastsync.block names the download as its parent;
         # filled only while the recorder is on, emptied as blocks apply
@@ -497,7 +500,7 @@ class BlockchainReactor(Reactor):
             except Exception as e:
                 LOG.warning("invalid block %d during fast sync: %s",
                             height, e)
-                self.pool.redo_request(height)
+                self._redo(height)
                 return False
         self.pool.pop_request()
         self.store.save_block(first, first_parts, second.last_commit)
@@ -509,11 +512,32 @@ class BlockchainReactor(Reactor):
             nfirst, _ = self.pool.peek_two_blocks()
             if nfirst is not None:
                 stage(nfirst)
-        self.state = self.block_exec.apply_block(self.state, first_id, first)
+        self._apply_verified(first, first_id, second.last_commit)
+        return True
+
+    def _redo(self, height: int) -> None:
+        """Block `height`'s commit failed: ask for the block again. The
+        copy that comes back is judged from scratch, its LastCommit
+        included."""
+        self._verified_commit = None
+        self.pool.redo_request(height)
+
+    def _apply_verified(self, block, block_id, commit) -> None:
+        """Apply a block whose commit (carried by its successor) this
+        loop has just verified against self.state.validators. That
+        commit is the next block's LastCommit, which validate_block
+        would verify once more under the same set (by then
+        state.last_validators): note what was verified, keyed by the
+        set's Merkle root, and hand the executor the note of one
+        iteration earlier for this block's own LastCommit."""
+        self.block_exec.verified_last_commit = self._verified_commit
+        self._verified_commit = VerifiedCommit(
+            commit, self.state.validators.hash(), self.state.chain_id,
+            block_id, block.header.height)
+        self.state = self.block_exec.apply_block(self.state, block_id, block)
         self.blocks_synced += 1
         if self.blocks_synced % 100 == 0:
             LOG.info("fast sync at height %d", self.state.last_block_height)
-        return True
 
     # -- pipelined sync (verify k+1 on-device while k applies) ---------
 
@@ -559,7 +583,7 @@ class BlockchainReactor(Reactor):
             err = self._resolve_block_verify(spec)
         if err is not None:
             LOG.warning("invalid block %d during fast sync: %s", height, err)
-            self.pool.redo_request(height)
+            self._redo(height)
             return False
         self.pool.pop_request()
         self.store.save_block(spec.first, spec.parts, spec.second.last_commit)
@@ -576,11 +600,8 @@ class BlockchainReactor(Reactor):
                 stage = getattr(self.block_exec, "stage_next_block", None)
                 if stage is not None:
                     stage(nfirst)
-        self.state = self.block_exec.apply_block(
-            self.state, spec.block_id, spec.first)
-        self.blocks_synced += 1
-        if self.blocks_synced % 100 == 0:
-            LOG.info("fast sync at height %d", self.state.last_block_height)
+        self._apply_verified(spec.first, spec.block_id,
+                             spec.second.last_commit)
         return nxt
 
     def _begin_block_verify(self, first, second) -> "_SpeculativeVerify":

@@ -103,6 +103,11 @@ class CryptoMetrics:
     # cross-height verify scheduler (crypto/batch.py): verify_async
     # calls that were merged into another caller's dispatch
     coalesced_calls: object = NOP
+    # ValidatorSet.hash() calls, labeled result=memo|computed: a Merkle
+    # walk of the whole committee against a 32-byte read (types/
+    # validator_set.py reports through this process-wide sink like
+    # verify_commit does)
+    valset_hash: object = NOP
 
 
 @dataclass
@@ -304,6 +309,10 @@ class StateMetrics:
     # work-stealing lane pool: groups a lane stole from a sibling's
     # deque tail (nonzero = the pool is actually load-balancing)
     exec_lane_steals: object = NOP
+    # apply-time LastCommit checks, labeled result=handed_down|verified:
+    # the fast-sync loop verified this very commit one iteration earlier
+    # and handed the proof down, against a full verify_commit
+    last_commit_check: object = NOP
 
 
 @dataclass
@@ -628,6 +637,12 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             f"{ns}_exec_lane_steals_total",
             "Groups stolen from a sibling lane's deque by the "
             "persistent work-stealing pool."),
+        last_commit_check=r.counter(
+            f"{ns}_state_last_commit_check_total",
+            "Apply-time LastCommit checks, by result: handed_down (fast "
+            "sync verified this commit one block earlier) or verified "
+            "(a full verify_commit).",
+            ("result",)),
     )
     crypto = CryptoMetrics(
         batch_verify_seconds=r.histogram(
@@ -695,6 +710,11 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             f"{ns}_crypto_coalesced_calls_total",
             "verify_async calls merged into another caller's dispatch "
             "by the cross-height coalescing scheduler."),
+        valset_hash=r.counter(
+            f"{ns}_types_valset_hash_total",
+            "ValidatorSet.hash() calls, by result: memo (the remembered "
+            "root) or computed (a Merkle walk of the whole set).",
+            ("result",)),
     )
     statesync = StateSyncMetrics(
         snapshots=r.gauge(

@@ -12,4 +12,4 @@ from .store import (  # noqa: F401
 )
 from .execution import ABCIResponses, BlockExecutor, update_state  # noqa: F401
 from .txindex import IndexerService, KVTxIndexer, NullTxIndexer, TxResult  # noqa: F401
-from .validation import ErrInvalidBlock, validate_block  # noqa: F401
+from .validation import ErrInvalidBlock, VerifiedCommit, validate_block  # noqa: F401

@@ -191,6 +191,13 @@ class BlockExecutor:
         # the commit-path profiler: shared with ConsensusState (wal
         # stage) and the node's IndexerService (index stage)
         self.stage_profile = CommitStageProfile(self.metrics)
+        # a validation.VerifiedCommit for the next apply_block's
+        # LastCommit, set by a caller that verified that commit itself
+        # (fast sync, one block earlier) just before it calls
+        # apply_block, which takes it and clears it. An attribute and
+        # not a parameter of apply_block, so the call keeps its three
+        # arguments for every stand-in that replaces it.
+        self.verified_last_commit = None
         # exec-lane flight recorder: process-global (state/parallel.py);
         # the executor only hands it a metrics sink when the parallel
         # path can actually run, so a lanes=1 node never touches it
@@ -258,8 +265,10 @@ class BlockExecutor:
             rec.set_metrics(None)
 
     def validate_block(self, state: State, block: Block,
-                       decided: bool = False) -> None:
-        validate_block(state, block, self.evidence_pool, decided=decided)
+                       decided: bool = False, verified_last_commit=None):
+        return validate_block(state, block, self.evidence_pool,
+                              decided=decided,
+                              verified_last_commit=verified_last_commit)
 
     def apply_block(self, state: State, block_id: BlockID, block: Block) -> State:
         """Validate → exec against app → update state → commit app →
@@ -297,8 +306,14 @@ class BlockExecutor:
         # apply-time blocks are DECIDED (commit apply, replay, fast
         # sync) — proposal-only checks like the aggregate-lane clock
         # drift bound must not reject them
-        with tracing.span("state.validateBlock", cat="state", height=height):
-            self.validate_block(state, block, decided=True)
+        handed, self.verified_last_commit = self.verified_last_commit, None
+        with tracing.span("state.validateBlock", cat="state",
+                          height=height) as sp:
+            checked = self.validate_block(state, block, decided=True,
+                                          verified_last_commit=handed)
+            if checked is not None:
+                sp.set(last_commit=checked)
+                self.metrics.last_commit_check.with_labels(checked).inc()
 
         with tracing.timed("commit.execute", cat="state",
                            height=height) as sp:

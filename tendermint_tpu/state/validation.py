@@ -8,12 +8,30 @@ from state/validation.go:102-103).
 
 from __future__ import annotations
 
+from typing import NamedTuple, Optional
+
+from ..types.basic import BlockID
 from ..types.block import Block
 from .state import State, median_time
 
 
 class ErrInvalidBlock(Exception):
     pass
+
+
+class VerifiedCommit(NamedTuple):
+    """A commit its holder has fully verified (verify_commit returned),
+    and what it was verified as: +2/3 of the set with Merkle root
+    `valset_root` for `block_id` at `height` on `chain_id`. Fast sync
+    verifies block k's commit (carried by block k+1) before it saves k,
+    and one iteration later validate_block would verify the same object
+    under the same set again; the reactor hands this down instead."""
+
+    commit: object
+    valset_root: bytes
+    chain_id: str
+    block_id: BlockID
+    height: int
 
 
 # Aggregate-lane block-time bound: BLS certificates carry no per-vote
@@ -32,8 +50,16 @@ AGG_MAX_CLOCK_DRIFT_NS = 10_000_000_000  # 10s
 
 
 def validate_block(state: State, block: Block, evidence_pool=None,
-                   decided: bool = False) -> None:
-    """Raises ErrInvalidBlock (or ErrInvalidCommit subclasses) on failure."""
+                   decided: bool = False,
+                   verified_last_commit: Optional[VerifiedCommit] = None
+                   ) -> Optional[str]:
+    """Raises ErrInvalidBlock (or ErrInvalidCommit subclasses) on failure.
+
+    Returns how LastCommit's signatures were checked: "verified" (a full
+    verify_commit), "handed_down" (verified_last_commit is this very
+    commit object, verified under state.last_validators' root for
+    state.last_block_id at this height on this chain — anything else
+    takes the full path), or None at height 1."""
     h = block.header
     # header matches state (reference validation.go:25-98; chain/height
     # checks come before structural validation so errors are precise)
@@ -65,6 +91,7 @@ def validate_block(state: State, block: Block, evidence_pool=None,
     from ..types.block import AggregateCommit
 
     is_agg = isinstance(block.last_commit, AggregateCommit)
+    last_commit_check = None
     if h.height == 1:
         if block.last_commit is not None and (
             is_agg or block.last_commit.precommits
@@ -90,11 +117,21 @@ def validate_block(state: State, block: Block, evidence_pool=None,
             raise ErrInvalidBlock(
                 f"wrong LastCommit size {got}, expected {len(state.last_validators)}"
             )
-        # ★ batched signature verification (TPU path); AggregateCommit
-        # dispatches to the one-pairing certificate check
-        state.last_validators.verify_commit(
-            state.chain_id, state.last_block_id, h.height - 1, block.last_commit
-        )
+        rec = verified_last_commit
+        if (rec is not None and rec.commit is block.last_commit
+                and rec.chain_id == state.chain_id
+                and rec.block_id == state.last_block_id
+                and rec.height == h.height - 1
+                and rec.valset_root == state.last_validators.hash()):
+            last_commit_check = "handed_down"
+        else:
+            # ★ batched signature verification (TPU path); AggregateCommit
+            # dispatches to the one-pairing certificate check
+            state.last_validators.verify_commit(
+                state.chain_id, state.last_block_id, h.height - 1,
+                block.last_commit
+            )
+            last_commit_check = "verified"
         # median-time rule (reference validation.go:110-124): strictly
         # increasing AND exactly the weighted median of LastCommit times
         if h.time <= state.last_block_time:
@@ -133,6 +170,7 @@ def validate_block(state: State, block: Block, evidence_pool=None,
         verify_evidence(state, ev)
         if evidence_pool is not None and evidence_pool.is_committed(ev):
             raise ErrInvalidBlock(f"evidence was already committed: {ev}")
+    return last_commit_check
 
 
 def verify_evidence(state: State, evidence, load_validators=None) -> None:

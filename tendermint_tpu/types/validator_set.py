@@ -131,6 +131,9 @@ class ValidatorSet:
         vs = ValidatorSet.__new__(ValidatorSet)
         vs.validators = [v.copy() for v in self.validators]
         vs._total = self._total
+        # the root covers (address, pub_key, voting_power) only, which a
+        # copy shares: update_state copies the sets at every height
+        vs._hash_memo = getattr(self, "_hash_memo", None)
         vs.proposer = None
         if self.proposer is not None:
             for v in vs.validators:
@@ -225,9 +228,26 @@ class ValidatorSet:
         return self.proposer
 
     def hash(self) -> bytes:
-        from ..crypto import merkle
+        """Merkle root over every validator's hash_bytes (address, key,
+        power; no priority). Memoised: the catch-up loop and
+        validate_block ask it of an unchanged committee several times a
+        height, and at 500 validators one walk is milliseconds of
+        interpreter time. Computed on first use, carried by copy(),
+        dropped by update_with_changes — the one place membership and
+        powers change; the priority walks leave it alone.
+        getattr-with-default as in is_bls()."""
+        memo = getattr(self, "_hash_memo", None)
+        m = batch.get_metrics()
+        if m is not None:
+            m.valset_hash.with_labels(
+                "computed" if memo is None else "memo").inc()
+        if memo is None:
+            from ..crypto import merkle
 
-        return merkle.hash_from_byte_slices([v.hash_bytes() for v in self.validators])
+            memo = merkle.hash_from_byte_slices(
+                [v.hash_bytes() for v in self.validators])
+            self._hash_memo = memo
+        return memo
 
     def is_bls(self) -> bool:
         """True when every validator key is BLS12-381 — the aggregate
@@ -526,6 +546,7 @@ class ValidatorSet:
         self.validators = sorted(by_addr.values(), key=lambda v: v.address)
         self._total = None
         self._is_bls_cache = None
+        self._hash_memo = None
         if self.proposer is not None and self.proposer.address not in by_addr:
             self.proposer = None
         self.total_voting_power()
