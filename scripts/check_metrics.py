@@ -270,6 +270,7 @@ REQUIRED_FAMILIES = (
     "churn_validator_updates_total",
     "churn_valset_changes_total",
     "p2p_reconnect_attempts_total",
+    "p2p_throttled_seconds_total",
     # PR-11 runtime lockdep (declaration presence: samples flow only
     # under [instrumentation] lockdep = true — the chaos-under-lockdep
     # scenarios are where these families go live)

@@ -27,7 +27,7 @@ from ..p2p.base_reactor import ChannelDescriptor, Reactor
 from ..state.validation import VerifiedCommit
 from ..types import serde
 from ..types.basic import BlockID
-from ..types.block import make_part_set
+from ..types.part_set import PartSet
 
 LOG = logging.getLogger("blockchain.reactor")
 
@@ -52,6 +52,21 @@ SYNC_BATCH = 10  # blocks applied per didProcess burst
 
 def _enc(obj) -> bytes:
     return serde.pack(obj)
+
+
+# a block_response is the array ["block_response", block]: what follows
+# this head is the block's own encoding
+_BLOCK_RESPONSE_HEAD = _enc(["block_response", None])[:-1]
+
+
+def _part_set(block) -> PartSet:
+    """The part set of a block a peer sent, cut from the bytes it came
+    in. They are block.encode() because serde is deterministic
+    (tests/test_committee_scale.py); were they not (a peer that packs
+    another way), the part-set hash would not be the one the commit
+    signed and the block would be refused, as any altered block is."""
+    data = block.arrived_as
+    return PartSet.from_data(data if data is not None else block.encode())
 
 
 class _SpeculativeVerify:
@@ -271,6 +286,8 @@ class BlockchainReactor(Reactor):
                 peer.try_send(BLOCKCHAIN_CHANNEL, _enc(["no_block_response", height]))
         elif kind == "block_response":
             block = serde.block_from(obj[1])
+            if msg_bytes.startswith(_BLOCK_RESPONSE_HEAD):
+                block.arrived_as = msg_bytes[len(_BLOCK_RESPONSE_HEAD):]
             if self.tree is not None:
                 self.tree.note_delivery(peer.id)
             self.pool.add_block(peer.id, block, len(msg_bytes))
@@ -431,7 +448,7 @@ class BlockchainReactor(Reactor):
             commit = second.last_commit
             if not isinstance(commit, AggregateCommit):
                 continue
-            parts = make_part_set(first)
+            parts = _part_set(first)
             block_id = BlockID(hash=first.hash(),
                                parts_header=parts.header())
             checks.append((block_id, first.header.height, commit))
@@ -484,7 +501,7 @@ class BlockchainReactor(Reactor):
         else:
             with tracing.span("fastsync.partSet", cat="fastsync",
                               height=height):
-                first_parts = make_part_set(first)
+                first_parts = _part_set(first)
                 first_id = BlockID(hash=first.hash(),
                                    parts_header=first_parts.header())
             try:
@@ -615,7 +632,7 @@ class BlockchainReactor(Reactor):
         request = ("block", height)
         with tracing.span("fastsync.partSet", cat="fastsync",
                           request=request, height=height):
-            parts = make_part_set(first)
+            parts = _part_set(first)
             block_id = BlockID(hash=first.hash(),
                                parts_header=parts.header())
         vals = self.state.validators

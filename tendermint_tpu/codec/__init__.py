@@ -16,9 +16,14 @@ WIRE_FIXED64 = 1
 WIRE_BYTES = 2
 
 
+_ONE_BYTE = tuple(bytes([i]) for i in range(0x80))
+
+
 def uvarint(n: int) -> bytes:
-    if n < 0:
-        raise ValueError("uvarint of negative")
+    if n < 0x80:  # tags, lengths and small counts: most calls
+        if n < 0:
+            raise ValueError("uvarint of negative")
+        return _ONE_BYTE[n]
     out = bytearray()
     while True:
         b = n & 0x7F

@@ -193,12 +193,14 @@ def key_type_of(pk) -> str:
 
 
 def pubkey_to_bytes(pk: PubKey) -> bytes:
+    # the common key first: a validator set is encoded key by key, and
+    # three import statements a key cost more than the encoding
+    if isinstance(pk, PubKeyEd25519):
+        return bytes([TYPE_ED25519]) + pk.data
     from .bls import PubKeyBLS12381
     from .multisig import PubKeyMultisigThreshold
     from .secp256k1 import PubKeySecp256k1
 
-    if isinstance(pk, PubKeyEd25519):
-        return bytes([TYPE_ED25519]) + pk.data
     if isinstance(pk, PubKeySecp256k1):
         return bytes([TYPE_SECP256K1]) + pk.data
     if isinstance(pk, PubKeyMultisigThreshold):

@@ -39,14 +39,24 @@ def _split_point(n: int) -> int:
 
 def hash_from_byte_slices(items: Sequence[bytes]) -> bytes:
     """Root hash of the simple tree over items. Empty tree hashes to
-    SHA256 of the empty string, matching an unambiguous fixed value."""
-    n = len(items)
-    if n == 0:
+    SHA256 of the empty string, matching an unambiguous fixed value.
+
+    Walked level by level: adjacent nodes pair up and an odd last node
+    rises unchanged, which is the tree the largest-power-of-two split
+    rule (_split_point) defines, without its recursion and slicing
+    (tests/test_committee_scale.py holds the two equal). A commit or a
+    validator set of 10,000 is 20,000 hashes a root."""
+    if not items:
         return _sha256(b"")
-    if n == 1:
-        return leaf_hash(items[0])
-    k = _split_point(n)
-    return inner_hash(hash_from_byte_slices(items[:k]), hash_from_byte_slices(items[k:]))
+    sha = hashlib.sha256
+    level = [sha(LEAF_PREFIX + item).digest() for item in items]
+    while len(level) > 1:
+        paired = [sha(INNER_PREFIX + level[i] + level[i + 1]).digest()
+                  for i in range(0, len(level) - 1, 2)]
+        if len(level) & 1:
+            paired.append(level[-1])
+        level = paired
+    return level[0]
 
 
 def hash_from_map(m: Dict[str, bytes]) -> bytes:

@@ -32,6 +32,9 @@ class Monitor:
         # epoch `start` so credit-forfeiture can't corrupt avg_rate()
         self._limit_start = self.start
         self._limit_total = 0
+        # seconds limit() has slept in all: the limiter's own share of a
+        # slow transfer (its one caller reads it before and after)
+        self.throttled_s = 0.0
 
     def update(self, n: int) -> int:
         """Record n bytes transferred; returns n."""
@@ -91,7 +94,9 @@ class Monitor:
                     allowed = burst_cap
             if allowed >= 1:
                 return min(want, int(allowed))
+            t0 = time.monotonic()
             time.sleep(min((1 - allowed) / rate_limit, self.sample_period))
+            self.throttled_s += time.monotonic() - t0
 
     def status(self) -> dict:
         with self._lock:

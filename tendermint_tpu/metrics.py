@@ -129,6 +129,10 @@ class P2PMetrics:
     # reconnect storm hygiene (switch._schedule_reconnect): dial attempts
     # at a dropped persistent peer, pruned on removal like the rest
     reconnect_attempts: object = NOP  # (peer_id)
+    # seconds MConnection spent blocked in its flow-rate limiter, by
+    # direction (send|recv): tells "the link's cap binds" from "the
+    # host is slow" when a sync nears send_rate/recv_rate
+    throttled_seconds: object = NOP  # (direction)
     # network-fault engine (p2p/netchaos.py): faults actually injected,
     # by kind (drop|delay|throttle|disconnect), and the rules currently
     # active in the installed fault plan (0 when no controller/phase)
@@ -518,6 +522,10 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             "Dial attempts at a dropped persistent peer (reconnect "
             "loops; pruned with the peer's other series on removal).",
             ("peer_id",)),
+        throttled_seconds=r.counter(
+            f"{ns}_p2p_throttled_seconds_total",
+            "Seconds connections spent blocked by the [p2p] send_rate/"
+            "recv_rate limiter, by direction.", ("direction",)),
         chaos_injected=r.counter(
             f"{ns}_chaos_injected_total",
             "Network faults injected by the netchaos engine, by kind.",

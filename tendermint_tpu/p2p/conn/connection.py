@@ -23,12 +23,23 @@ from typing import Callable, Dict, List, Optional
 
 import msgpack
 
+from ...libs import tracing
 from ...libs.flowrate import Monitor
 
 LOG = logging.getLogger("p2p.conn")
 
 MAX_PACKET_MSG_PAYLOAD_SIZE = 1024  # connection.go:21
 NUM_BATCH_PACKET_MSGS = 10  # connection.go:23
+
+# p2p.sendThrottle / p2p.recvThrottle: a stretch in which the flow-rate
+# limiter kept putting the connection to sleep. Sleeps closer together
+# than the first are one stretch (a binding limiter sleeps a fraction of
+# a millisecond a packet), a stretch is cut and recorded at the second
+# so that a snapshot loses little of one still open, and one shorter
+# than the third gets no span (the counter still has its seconds).
+THROTTLE_JOIN_NS = 10_000_000
+THROTTLE_CUT_NS = 100_000_000
+THROTTLE_SPAN_FLOOR_NS = 1_000_000
 
 _PKT_PING = 0
 _PKT_PONG = 1
@@ -104,7 +115,11 @@ class MConnection:
         on_receive: Callable[[int, bytes], None],
         on_error: Callable[[Exception], None],
         config: Optional[MConnConfig] = None,
+        metrics=None,  # P2PMetrics
     ):
+        from ...metrics import P2PMetrics
+
+        self.metrics = metrics if metrics is not None else P2PMetrics()
         self.conn = conn
         self.config = config or MConnConfig()
         self.channels: Dict[int, _Channel] = {
@@ -114,6 +129,7 @@ class MConnection:
         self.on_error = on_error
         self.send_monitor = Monitor()
         self.recv_monitor = Monitor()
+        self._throttled: Dict[str, Optional[List[int]]] = {}
         # wall clock of the last fully received packet (any kind);
         # 0.0 until the first one lands. The peer-reachability probe
         # (consensus stall classification, monitor [PARTITIONED?] tag)
@@ -209,11 +225,36 @@ class MConnection:
         except Exception as e:
             self._error(e)
 
+    def _throttle(self, direction: str, monitor: Monitor, want: int,
+                  rate: int) -> None:
+        """monitor.limit(), with the seconds it slept counted in
+        p2p_throttled_seconds_total{direction} and its stretches of
+        sleeping recorded, when they end, as spans."""
+        t0 = time.perf_counter_ns()
+        slept = monitor.throttled_s
+        monitor.limit(want, rate)
+        slept = monitor.throttled_s - slept
+        open_ = self._throttled.get(direction)  # [start_ns, end_ns]
+        if open_ is not None and (t0 - open_[1] > THROTTLE_JOIN_NS
+                                  or t0 - open_[0] > THROTTLE_CUT_NS):
+            if open_[1] - open_[0] >= THROTTLE_SPAN_FLOOR_NS:
+                tracing.get_tracer().record(
+                    f"p2p.{direction}Throttle", open_[0], open_[1], "p2p")
+            open_ = self._throttled[direction] = None
+        if slept > 0:
+            self.metrics.throttled_seconds.with_labels(direction).inc(slept)
+            t1 = time.perf_counter_ns()
+            if open_ is None:
+                self._throttled[direction] = [t0, t1]
+            else:
+                open_[1] = t1
+
     def _send_some_packets(self) -> bool:
         """Send up to a batch of packets; True if any were sent
         (connection.go:448-486)."""
         # rate-limit on the monitor before a batch
-        self.send_monitor.limit(
+        self._throttle(
+            "send", self.send_monitor,
             NUM_BATCH_PACKET_MSGS * self.config.max_packet_msg_payload_size,
             self.config.send_rate,
         )
@@ -256,7 +297,8 @@ class MConnection:
                 body = self.conn.read_exact(length)
                 self.last_recv_time = time.monotonic()
                 self.recv_monitor.update(len(body))
-                self.recv_monitor.limit(len(body), self.config.recv_rate)
+                self._throttle("recv", self.recv_monitor, len(body),
+                               self.config.recv_rate)
                 pkt = msgpack.unpackb(body, raw=False)
                 kind = pkt[0]
                 if kind == _PKT_PING:

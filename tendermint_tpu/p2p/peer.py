@@ -42,6 +42,7 @@ class Peer:
             on_receive=lambda ch_id, msg: on_receive(ch_id, self, msg),
             on_error=lambda err: on_error(self, err),
             config=mconfig,
+            metrics=self.metrics,
         )
 
     @property

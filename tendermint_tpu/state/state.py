@@ -162,19 +162,13 @@ def _median_time(commit: Commit, validators: Optional[ValidatorSet]) -> int:
             return 0
         ts = sorted(v.timestamp for v in votes)
         return ts[len(ts) // 2]
-    pairs = []
-    total = 0
-    for i, v in enumerate(commit.precommits):
-        if v is None:
-            continue
-        _, val = validators.get_by_index(i)
-        if val is None:
-            continue
-        pairs.append((v.timestamp, val.voting_power))
-        total += val.voting_power
+    # zip stops at the shorter: a vote past the set's end has no power
+    pairs = sorted((v.timestamp, val.voting_power) for v, val
+                   in zip(commit.precommits, validators.validators)
+                   if v is not None)
     if not pairs:
         return 0
-    pairs.sort()
+    total = sum(power for _, power in pairs)
     half = total // 2
     acc = 0
     for ts, power in pairs:

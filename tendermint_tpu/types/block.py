@@ -13,7 +13,8 @@ from typing import List, Optional
 
 from .. import codec
 from ..crypto import merkle, tmhash
-from .basic import VOTE_TYPE_PRECOMMIT, BlockID, PartSetHeader, Vote
+from .basic import (VOTE_TYPE_PRECOMMIT, BlockID, PartSetHeader, Vote,
+                    votes_encode)
 
 MAX_BLOCK_SIZE_BYTES = 104857600  # reference types/params.go MaxBlockSizeBytes
 
@@ -122,9 +123,8 @@ class Commit:
                 raise ValueError("commit contains vote from wrong height/round")
 
     def hash(self) -> bytes:
-        return merkle.hash_from_byte_slices(
-            [v.encode() if v is not None else b"" for v in self.precommits]
-        )
+        """Merkle root over each precommit's encode() (b"" where absent)."""
+        return merkle.hash_from_byte_slices(votes_encode(self.precommits))
 
     def __str__(self):
         n = sum(1 for v in self.precommits if v is not None)
@@ -236,6 +236,12 @@ class Block:
     data: Data
     evidence: EvidenceData
     last_commit: Optional[Commit]
+    # Not part of the block: the bytes a block_response carried for it,
+    # kept by the blockchain reactor that decoded them so that fast sync
+    # cuts the part set from them and does not encode 10,000 precommits
+    # again (serde is deterministic: the bytes are encode()'s).
+    arrived_as: Optional[bytes] = dc_field(default=None, repr=False,
+                                           compare=False)
 
     @classmethod
     def make(

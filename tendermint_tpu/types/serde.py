@@ -125,7 +125,15 @@ def commit_from(o):
             block_id=block_id_from(o[1]), agg_height=o[2], agg_round=o[3],
             signers=BitArray.from_bytes_size(o[5], o[4]), agg_sig=o[6],
         )
-    return Commit(block_id=block_id_from(o[0]), precommits=[vote_from(v) for v in o[1]])
+    # a vote for the commit's own block id (all but a few) shares the
+    # commit's BlockID object: it is immutable, and 10,000 copies of it
+    # are most of what decoding a large commit builds
+    for_block, block_id = o[0], block_id_from(o[0])
+    return Commit(block_id=block_id, precommits=[
+        None if v is None else Vote(
+            v[0], v[1], v[2], v[3], v[4], v[5],
+            block_id if v[6] == for_block else block_id_from(v[6]), v[7])
+        for v in o[1]])
 
 
 def header_obj(h: Header):
@@ -244,18 +252,18 @@ def valset_from(o) -> ValidatorSet:
     vs = ValidatorSet.__new__(ValidatorSet)
     vs.validators = [validator_from(v) for v in o[0]]
     # __new__ skips __init__'s sort/rotation on purpose (persisted sets
-    # carry their exact order + priorities) but its duplicate-address
-    # check must still hold: statesync feeds wire bytes through here,
-    # and a repeated entry would double-count that validator's power in
-    # every tally downstream (lite aggregate trusting path included)
+    # carry their exact order + priorities) but its invariants must
+    # still hold: statesync feeds wire bytes through here. A repeated
+    # entry would double-count that validator's power in every tally
+    # downstream (lite aggregate trusting path included), and an
+    # unsorted set would hide members from get_by_address's search
     addrs = [v.address for v in vs.validators]
-    if len(set(addrs)) != len(addrs):
-        raise ValueError("duplicate validator address")
+    if any(a >= b for a, b in zip(addrs, addrs[1:])):
+        raise ValueError("duplicate validator address"
+                         if len(set(addrs)) != len(addrs)
+                         else "validators not sorted by address")
     vs._total = None
-    vs.proposer = None
-    for v in vs.validators:
-        if v.address == o[1]:
-            vs.proposer = v
+    _, vs.proposer = vs.get_by_address(o[1])
     return vs
 
 

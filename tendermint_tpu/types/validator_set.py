@@ -10,12 +10,14 @@ the whole commit. This is north-star call site #1.
 from __future__ import annotations
 
 import logging
+from bisect import bisect_left
+from operator import attrgetter
 from dataclasses import dataclass, field as dc_field
 from typing import List, Optional
 
 from .. import codec
 from ..crypto import PubKey, batch, tmhash
-from .basic import VOTE_TYPE_PRECOMMIT, BlockID
+from .basic import VOTE_TYPE_PRECOMMIT, BlockID, votes_sign_bytes
 
 LOG = logging.getLogger("types.validator_set")
 
@@ -59,7 +61,7 @@ class PendingCommitVerify:
             raise self._exc
 
 
-@dataclass
+@dataclass(slots=True)
 class Validator:
     address: bytes
     pub_key: PubKey
@@ -109,6 +111,9 @@ class Validator:
         return f"Val{{{self.address.hex()[:8]} pow:{self.voting_power} pri:{self.proposer_priority}}}"
 
 
+_address_of = attrgetter("address")
+
+
 class ValidatorSet:
     """Sorted-by-address validator set with proposer rotation
     (reference types/validator_set.go:33-117)."""
@@ -136,9 +141,7 @@ class ValidatorSet:
         vs._hash_memo = getattr(self, "_hash_memo", None)
         vs.proposer = None
         if self.proposer is not None:
-            for v in vs.validators:
-                if v.address == self.proposer.address:
-                    vs.proposer = v
+            _, vs.proposer = vs.get_by_address(self.proposer.address)
         return vs
 
     def total_voting_power(self) -> int:
@@ -150,13 +153,16 @@ class ValidatorSet:
         return self._total
 
     def has_address(self, address: bytes) -> bool:
-        return any(v.address == address for v in self.validators)
+        return self.get_by_address(address)[1] is not None
 
     def get_by_address(self, address: bytes):
-        """-> (index, Validator) or (-1, None)."""
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v
+        """-> (index, Validator) or (-1, None). The set is sorted by
+        address, so this is a binary search as the reference's is
+        (validator_set.go GetByAddress: sort.Search)."""
+        vals = self.validators
+        i = bisect_left(vals, address, key=_address_of)
+        if i < len(vals) and vals[i].address == address:
+            return i, vals[i]
         return -1, None
 
     def get_by_index(self, index: int):
@@ -436,18 +442,22 @@ class ValidatorSet:
         round_ = commit.round()
 
         bv = batch.new_batch_verifier()
-        entries = []  # (index, precommit, validator)
-        for idx, precommit in enumerate(commit.precommits):
-            if precommit is None:
-                continue
+        present = [(idx, pc) for idx, pc in enumerate(commit.precommits)
+                   if pc is not None]
+        for _, precommit in present:
             if precommit.height != height:
                 raise ErrInvalidCommit(f"invalid commit precommit height {precommit.height}")
             if precommit.round != round_:
                 raise ErrInvalidCommit(f"invalid commit precommit round {precommit.round}")
             if precommit.type != VOTE_TYPE_PRECOMMIT:
                 raise ErrInvalidCommit("invalid commit vote type")
-            _, val = self.get_by_index(idx)
-            bv.add(precommit.sign_bytes(chain_id), precommit.signature, val.pub_key.bytes())
+        # what the votes share is encoded once, not once a validator
+        msgs = votes_sign_bytes(chain_id, [pc for _, pc in present])
+        validators = self.validators
+        entries = []  # (index, precommit, validator)
+        for (idx, precommit), msg in zip(present, msgs):
+            val = validators[idx]
+            bv.add(msg, precommit.signature, val.pub_key.bytes())
             entries.append((idx, precommit, val))
         return bv, entries
 
