@@ -1,21 +1,28 @@
 """Driver kind `fastsync_from_peer`: a joiner node catches up from one
 serving peer over a real p2p connection on loopback.
 
-Set-up builds the seeded chain, starts the serving switch and the
-joiner, waits for the joiner's verifier, has the joiner's validator set
+Set-up draws the committee from the seed and signs its chain, starts
+the serving switch and the joiner, waits for the joiner's verifier, has
+the joiner's validator set
 verify the chain's first commit through the call the sync loop makes
 (which loads, or in a checkout's first run compiles, the cell's one
 kernel shape while no peer is connected), connects the peer and lets
 the joiner apply the cell's warm-up blocks. The window opens
 at the instant the joiner's block store reaches the warm-up height and
-lasts `seconds`; the rate is the heights the store gained over that
-time. The peer's advertised tip stays `lookahead` blocks ahead of the
-joiner's store, as a live chain's does, so what is downloaded ahead is
-bounded and the check after the window is short.
+lasts `seconds` on the height poller's clock, in every kind of run: the
+rate is the heights the store gained from `t_open` to `t_open + seconds`
+over `seconds`. The peer's advertised tip stays `lookahead` blocks ahead
+of the joiner's store, as a live chain's does, and stands still from
+`t_close` on, so what is downloaded ahead is bounded, the chain needs no
+block for what follows the window, and the check after it is short.
 
 After the window the peer turns dishonest: two blocks past its tip it
 serves a block whose LastCommit has one flipped signature bit, in the
-upper half of the committee. The joiner has to stop below it.
+upper half of the committee. The joiner has to stop below it. The thread
+that closes the window makes that offer, in the same breath: a joiner
+left at a tip that stands still goes on to consensus within a second.
+Only then are the closing readings taken and the profiler stopped
+(benchmark/README.md, "The window and the profiler").
 """
 
 from __future__ import annotations
@@ -42,23 +49,39 @@ def say(*parts) -> None:
 
 class HeightPoller(threading.Thread):
     """Reads the joiner's block store every 2 ms, stamps every change and
-    moves the serving peer's tip with it."""
+    moves the serving peer's tip with it. It owns the window's close: at
+    the first reading past `t_close` it freezes the advertised tip and
+    calls `at_close` (the driver's offer of the corrupted commit), so
+    whatever holds the main thread then stretches neither the window nor
+    the chain the joiner is offered, and costs no run its `correct`."""
 
-    def __init__(self, store, serving, lookahead: int, last: int):
+    def __init__(self, store, serving, lookahead: int, last: int, at_close):
         super().__init__(name="bench-height", daemon=True)
         self.store, self.serving = store, serving
         self.lookahead, self.last = lookahead, last
+        self.at_close = at_close
+        self.t_close = None
         self.frozen = False
+        self.closed = threading.Event()  # set when at_close has returned
+        self.error = None
         self.marks: list = []  # (monotonic, height) at each change
         self._halt = threading.Event()
 
     def run(self) -> None:
         seen = -1
         while not self._halt.is_set():
-            h = self.store.height()
+            h, now = self.store.height(), time.monotonic()
+            if (self.t_close is not None and now > self.t_close
+                    and not self.frozen):
+                self.frozen = True  # a height stamped past t_close moves no tip
+                try:
+                    self.at_close()
+                except Exception as e:  # the main thread raises it
+                    self.error = e
+                self.closed.set()
             if h != seen:
                 seen = h
-                self.marks.append((time.monotonic(), h))
+                self.marks.append((now, h))
                 if not self.frozen:
                     self.serving.advertise(min(self.last, h + self.lookahead))
             time.sleep(0.002)
@@ -124,13 +147,18 @@ def run(ctx) -> dict:
                 + int(traffic["chain_blocks_per_s"] * seconds + 0.999))
     rng = np.random.default_rng(seed)
 
-    chain = chainlib.build_chain(
-        seed=seed, validators=n_vals, blocks=n_blocks,
-        txs_per_block=traffic["txs_per_block"], tx_bytes=traffic["tx_bytes"],
-        key_space=traffic["key_space"], workers=ctx.workers)
+    chain = chainlib.committee(seed=seed, validators=n_vals)
+    # signed before the joiner exists: nothing of the program runs beside
+    # the twelve workers, so `kernel_ready_s` reads the node alone and
+    # whatever a later PR moves into the node's start shows in `setup_s`
+    chainlib.sign_blocks(
+        chain, blocks=n_blocks, txs_per_block=traffic["txs_per_block"],
+        tx_bytes=traffic["tx_bytes"], key_space=traffic["key_space"],
+        workers=ctx.workers)
     say(f"chain of {n_blocks} blocks x {n_vals} precommits built in "
-        f"{chain.build_s:.1f}s; block {n_blocks} is {len(chain.messages[-1])} bytes")
-
+        f"{chain.build_s:.1f}s; block {n_blocks} is "
+        f"{len(chain.messages[-1])} bytes, all "
+        f"{sum(map(len, chain.messages))}")
     home = tempfile.mkdtemp(prefix="bench_home_")
     node = poller = sw = None
     try:
@@ -151,7 +179,32 @@ def run(ctx) -> dict:
         say(f"commit of {n_vals} precommits verified in "
             f"{warm_commit_shape(node, chain):.1f}s before the peer is dialled")
 
-        poller = HeightPoller(node.block_store, serving, lookahead, n_blocks)
+        close: dict = {}  # what the poller's thread found and did at t_close
+
+        def at_close() -> None:
+            """The window's last instant, on the poller's thread: the tip
+            is frozen, and two blocks past it the corrupted commit goes on
+            offer before anything else can keep the joiner waiting."""
+            t_close = poller.t_close
+            close["tip"] = tip = serving.tip
+            close["early_drop"] = (serving.dropped.is_set()
+                                   and serving.dropped_at <= t_close)
+            close["caught_up"] = poller.height_at(t_close) >= n_blocks - 1
+            if close["early_drop"] or close["caught_up"]:
+                return
+            # one corrupted precommit, in the upper half of the committee:
+            # a verifier that stops part-way through a batch lets it pass.
+            # (One attempt only: the joiner keeps the refused block in its
+            # pool and asks no peer for it again, PERF.md Open questions.)
+            close["bad_h"] = bad_h = min(n_blocks, tip + 2)
+            msg, close["where"] = chainlib.poisoned_message(
+                chain, bad_h, rng, [(n_vals // 2, n_vals)])
+            serving.poison[bad_h] = msg
+            serving.advertise(bad_h)
+            close["offered"] = time.monotonic()
+
+        poller = HeightPoller(node.block_store, serving, lookahead, n_blocks,
+                              at_close)
         poller.start()
         addr = node.transport.listen_addr
         if sw.dial_peer(addr, expect_id=node.node_key.id) is None:
@@ -159,39 +212,40 @@ def run(ctx) -> dict:
 
         t_open = poller.wait_height(warm, traffic.get("deadline_s", 1100))
         ctx.window_opens(t_open, surf)
-        t_close = t_open + seconds
-        while time.monotonic() < t_close:
-            if serving.dropped.is_set():
-                break
-            if ctx.trace_due():
-                ctx.trace_stop()
-            time.sleep(min(0.05, max(0.0, t_close - time.monotonic())))
-        t_end = time.monotonic()
-        h_open, h_end = poller.height_at(t_open), node.block_store.height()
-        caught_up = h_end >= n_blocks - 1
-        if caught_up:  # the chain ran out: the rate is over the time it had work
-            t_end = next(t for t, h in poller.marks if h >= n_blocks - 1)
-            say(f"the joiner caught up with the {n_blocks}-block chain "
-                f"{t_end - t_open:.2f}s into a {seconds}s window")
+        poller.t_close = t_close = ctx.t_close
+        ctx.wait_until(t_close)
+        # the window is over on the poller's clock, whatever kept this
+        # thread: the height stamped last before t_close, the tip frozen,
+        # the corrupted commit on offer. The closing readings meanwhile.
         peak = ctx.window_closes()
-        early_drop = serving.dropped.is_set()
-        window_s = t_end - t_open
+        if not poller.closed.wait(10):
+            raise RuntimeError("the height poller did not close the window")
+        if poller.error is not None:
+            raise poller.error
+        h_open, h_end = poller.height_at(t_open), poller.height_at(t_close)
+        tip, window_s = close["tip"], seconds
+        early_drop, caught_up = close["early_drop"], close["caught_up"]
+        bad_h = close.get("bad_h")
+        if caught_up:  # the chain ran out: the rate is over the time it had work
+            window_s = next(t for t, h in poller.marks if h >= n_blocks - 1) - t_open
+            say(f"the joiner caught up with the {n_blocks}-block chain "
+                f"{window_s:.2f}s into a {seconds}s window")
         blocks = h_end - h_open
-        say(f"window: heights {h_open}..{h_end} in {window_s:.3f}s")
+        used = 100.0 * (h_end - warm) / (n_blocks - 1 - warm)
+        say(f"window: heights {h_open}..{h_end} in {window_s:.3f}s "
+            f"({blocks / window_s:.4f} blocks/s), tip frozen at {tip}, "
+            f"{used:.1f}% of the chain used")
+        if not caught_up and n_blocks - (tip + 2) < lookahead:
+            say(f"WARNING: {n_blocks - (tip + 2)} blocks of the chain lie past "
+                f"the corrupted commit's place: a joiner {lookahead} heights "
+                f"faster cannot be offered it and its run is not correct "
+                f"(chain_blocks_per_s, benchmark/README.md)")
 
         # --- the dishonest tail ------------------------------------------
-        poller.frozen = True
         numbers: dict = {}
         if early_drop:
             say(f"the joiner dropped the honest peer: {serving.drop_reason}")
             numbers["honest_blocks_refused"] = (1, 0)
-            # it still applies what it had downloaded: let it finish, so
-            # that the read-back below is of a store that stands still
-            quiet, h = time.monotonic(), node.block_store.height()
-            while time.monotonic() - quiet < 1.5:
-                time.sleep(0.1)
-                if node.block_store.height() != h:
-                    quiet, h = time.monotonic(), node.block_store.height()
         elif caught_up:
             # fast sync is over (the joiner went on to consensus), so the
             # corrupted commit cannot be offered: the cell needs a longer
@@ -199,22 +253,24 @@ def run(ctx) -> dict:
             numbers["bad_commit_not_offered"] = (1, 0)
         else:
             numbers["honest_blocks_refused"] = (0, 0)
-            bad_h = min(n_blocks, serving.tip + 2)
-            # one corrupted precommit, in the upper half of the committee:
-            # a verifier that stops part-way through a batch lets it pass.
-            # (One attempt only: the joiner keeps the refused block in its
-            # pool and asks no peer for it again, PERF.md Open questions.)
-            msg, where = chainlib.poisoned_message(
-                chain, bad_h, rng, [(n_vals // 2, n_vals)])
-            serving.poison[bad_h] = msg
-            serving.advertise(bad_h)
+            say(f"corrupted precommit of validator {close['where']} offered in "
+                f"block {bad_h}, {close['offered'] - t_close:.3f}s after the "
+                f"window")
+        ctx.trace_stop()  # seconds, in which the joiner walks to the tip
+        if early_drop:
+            # it still applies what it had downloaded: let it finish, so
+            # that the read-back below is of a store that stands still
+            quiet, h = time.monotonic(), node.block_store.height()
+            while time.monotonic() - quiet < 1.5:
+                time.sleep(0.1)
+                if node.block_store.height() != h:
+                    quiet, h = time.monotonic(), node.block_store.height()
+        elif bad_h is not None:
             if not serving.dropped.wait(traffic.get("deadline_s", 60)):
                 say("the joiner never dropped the dishonest peer")
             time.sleep(0.3)  # anything it still applies shows here
             final = node.block_store.height()
-            say(f"corrupted precommit of validator {where} served in block "
-                f"{bad_h}: joiner at {final}, peer dropped: "
-                f"{serving.drop_reason}")
+            say(f"joiner at {final}, peer dropped: {serving.drop_reason}")
             numbers["height_past_bad_commit"] = (final - (bad_h - 2), 0)
             numbers["stopped_short_of_bad_commit"] = ((bad_h - 2) - final, 0)
         final = node.block_store.height()
@@ -224,7 +280,9 @@ def run(ctx) -> dict:
         facts = {
             "blocks": blocks, "window_s": window_s,
             "signatures_per_block": n_vals,
-            "height_open": h_open, "height_end": h_end,
+            "height_open": h_open, "height_end": h_end, "tip_at_close": tip,
+            # how near the joiner came to the end of the chain it could use
+            "chain_used_pct": used,
         }
         return {
             "end_to_end": {"sync_blocks_per_s": blocks / window_s},

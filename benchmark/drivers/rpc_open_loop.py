@@ -200,10 +200,7 @@ def run(ctx) -> dict:
         rpc = Rpc(surf.rpc_addr)
         backlog = {}
         for name, when in (("middle", t_open + seconds / 2), ("end", t_close)):
-            while clock() < when:
-                if ctx.trace_due():
-                    ctx.trace_stop()
-                time.sleep(min(0.05, max(0.0, when - clock())))
+            ctx.wait_until(when)
             backlog[name] = int(rpc.call("num_unconfirmed_txs")["n_txs"])
         peak = ctx.window_closes()
 
@@ -222,6 +219,9 @@ def run(ctx) -> dict:
                     break
             time.sleep(0.05)
         t_drained = clock()
+        # only now: the stop loads the node for seconds, and until here
+        # the window's last txs were still waiting for their block
+        ctx.trace_stop()
         with lock:
             got = dict(arrived)
             seen_blocks = sorted(blocks)
@@ -245,7 +245,8 @@ def run(ctx) -> dict:
         say(f"window: {len(in_window)} valid txs due, {missing} missing, "
             f"{sum(th.refused for th in threads)} calls answered with an error, "
             f"backlog middle/end {backlog['middle']}/{backlog['end']}, "
-            f"generator p99 late {1e3 * percentile(late, 0.99):.1f} ms")
+            f"generator p99 late {1e3 * percentile(late, 0.99):.1f} ms, "
+            f"commit latency p50 {1e3 * percentile(lat, 0.50):.1f} ms")
 
         numbers = _check_served(rpc, surf, txs, bad, got, seen_blocks, rng,
                                 traffic["check_txs"])

@@ -26,32 +26,61 @@ VOTE_TYPE_PRECOMMIT = 2
 
 class SignerPool:
     """Worker processes that hold the committee's keys. They never
-    import JAX, so they can run beside the process that owns the chip."""
+    import JAX, so they can run beside the process that owns the chip.
+    Each has a pipe of its own that the caller's thread writes and reads
+    itself: a `multiprocessing.Pool` hands its jobs over through threads
+    of the caller's process, which wait for the interpreter lock while
+    the caller works, and the caller works while the committee signs."""
 
     def __init__(self, seeds: list, workers: int):
         self.n = len(seeds)
         self.workers = max(1, min(workers, self.n))
-        ctx = multiprocessing.get_context("spawn")
-        self.pool = ctx.Pool(self.workers, initializer=signer.init_worker,
-                             initargs=(seeds,))
         step = -(-self.n // self.workers)
         self.ranges = [(lo, min(self.n, lo + step))
                        for lo in range(0, self.n, step)]
+        ctx = multiprocessing.get_context("spawn")
+        self.conns, self.procs = [], []
+        for _ in range(self.workers):
+            ours, theirs = ctx.Pipe()
+            proc = ctx.Process(target=signer.serve, args=(theirs, seeds),
+                               daemon=True)
+            proc.start()
+            theirs.close()
+            self.conns.append(ours)
+            self.procs.append(proc)
 
-    def sign_spliced(self, prefix: bytes, suffix: bytes, stamps: list) -> list:
-        jobs = [(lo, hi, prefix, suffix, stamps[lo:hi]) for lo, hi in self.ranges]
-        blob = b"".join(self.pool.map(signer.sign_spliced, jobs, chunksize=1))
-        return [blob[i:i + 64] for i in range(0, len(blob), 64)]
+    def _start(self, kind: str, jobs: list):
+        """Sends one job to each of the first workers; returns the call
+        that waits for their signatures, in the jobs' order."""
+        conns = self.conns[:len(jobs)]
+        for conn, job in zip(conns, jobs):
+            conn.send((kind, job))
+
+        def wait() -> list:
+            blob = b"".join(conn.recv_bytes() for conn in conns)
+            return [blob[i:i + 64] for i in range(0, len(blob), 64)]
+
+        return wait
+
+    def sign_spliced(self, prefix: bytes, suffix: bytes, stamps: list):
+        """Starts the committee signing; returns the call that waits for
+        the signatures, so that the caller can work meanwhile."""
+        return self._start("spliced", [(lo, hi, prefix, suffix, stamps[lo:hi])
+                                       for lo, hi in self.ranges])
 
     def sign_messages(self, pairs: list) -> list:
-        step = -(-len(pairs) // self.workers)
-        jobs = [pairs[lo:lo + step] for lo in range(0, len(pairs), step)]
-        blob = b"".join(self.pool.map(signer.sign_messages, jobs, chunksize=1))
-        return [blob[i:i + 64] for i in range(0, len(blob), 64)]
+        step = max(1, -(-len(pairs) // self.workers))
+        return self._start("messages", [pairs[lo:lo + step] for lo in
+                                        range(0, len(pairs), step)])()
 
     def close(self) -> None:
-        self.pool.close()
-        self.pool.join()
+        for conn in self.conns:
+            conn.close()  # a worker's read ends, and the worker with it
+        for proc in self.procs:
+            proc.join(10)
+            if proc.is_alive():
+                proc.terminate()
+                proc.join()
 
 
 def flip_bit(sig: bytes, bit: int) -> bytes:
@@ -90,42 +119,58 @@ class Chain:
         self.app_hash: list = []     # [h-1] -> reference app hash AFTER h
         self.pubkeys: list = []      # validator order
         self.seeds: list = []
+        self.seed = 0
+        self.state0 = None           # the genesis State
         self.build_s = 0.0
 
     def __len__(self) -> int:
         return len(self.messages)
 
 
-def build_chain(*, seed: int, validators: int, blocks: int, txs_per_block: int,
-                tx_bytes: int, key_space: int, workers: int,
-                genesis_time_ns: int = 1_700_000_000_000_000_000) -> Chain:
-    from tendermint_tpu.abci import types as abci
+def committee(*, seed: int, validators: int,
+              genesis_time_ns: int = 1_700_000_000_000_000_000) -> Chain:
+    """The chain's keys and genesis, drawn from the seed: what a joiner
+    needs before it is started. No block yet (`sign_blocks`)."""
     from tendermint_tpu.crypto.keys import PubKeyEd25519
     from tendermint_tpu.state import state_from_genesis_doc
-    from tendermint_tpu.state.execution import ABCIResponses
-    from tendermint_tpu.types import GenesisDoc, GenesisValidator, Vote, serde
-    from tendermint_tpu.types.basic import BlockID
-    from tendermint_tpu.types.block import (Block, Commit, Data, EvidenceData,
-                                            Header, make_part_set)
+    from tendermint_tpu.types import GenesisDoc, GenesisValidator
 
-    t0 = time.monotonic()
-    rng = np.random.default_rng(seed)
     out = Chain()
-    out.chain_id = chain_id = f"bench-sync-{seed}"
+    out.chain_id = f"bench-sync-{seed}"
+    out.seed = seed
     tag = b"bench-%d-val" % seed
     seeds = [signer.seed_of(tag, i) for i in range(validators)]
     pubs = [signer.public_key(s) for s in seeds]
-    doc = GenesisDoc(
-        chain_id=chain_id, genesis_time=genesis_time_ns,
+    out.genesis = GenesisDoc(
+        chain_id=out.chain_id, genesis_time=genesis_time_ns,
         validators=[GenesisValidator(PubKeyEd25519(p), 10, f"v{i}")
                     for i, p in enumerate(pubs)])
-    genesis = state_from_genesis_doc(doc)
-    vals = genesis.validators.validators  # address-sorted
+    out.state0 = state_from_genesis_doc(out.genesis)
     by_pub = {p: i for i, p in enumerate(pubs)}
-    order = [by_pub[v.pub_key.bytes()] for v in vals]
-    out.genesis = doc
+    order = [by_pub[v.pub_key.bytes()]  # address-sorted, as the set is
+             for v in out.state0.validators.validators]
     out.seeds = [seeds[i] for i in order]
     out.pubkeys = [pubs[i] for i in order]
+    return out
+
+
+def sign_blocks(out: Chain, *, blocks: int, txs_per_block: int, tx_bytes: int,
+                key_space: int, workers: int) -> Chain:
+    """Builds and signs the committee's chain, block by block (a block's
+    hash covers the signatures under the block before it)."""
+    from tendermint_tpu.abci import types as abci
+    from tendermint_tpu.state.execution import ABCIResponses
+    from tendermint_tpu.types import Vote, serde
+    from tendermint_tpu.types.basic import BlockID
+    from tendermint_tpu.types.block import (Block, Commit, Data, EvidenceData,
+                                            Header)
+    from tendermint_tpu.types.part_set import PartSet
+
+    t0 = time.monotonic()
+    rng = np.random.default_rng(out.seed)
+    chain_id, doc, genesis = out.chain_id, out.genesis, out.state0
+    genesis_time_ns = doc.genesis_time
+    vals = genesis.validators.validators
     addresses = [v.address for v in vals]
     vals_hash = genesis.validators.hash()
     next_vals_hash = genesis.next_validators.hash()
@@ -139,6 +184,9 @@ def build_chain(*, seed: int, validators: int, blocks: int, txs_per_block: int,
     results_hash = ABCIResponses(
         [abci.ResponseDeliverTx(code=0)] * txs_per_block, None).results_hash()
 
+    # a block_response is the array ["block_response", block]: this head,
+    # then the block's own encoding, which the part set is cut from too
+    response_head = serde.pack(["block_response", None])[:-1]
     pool = SignerPool(out.seeds, workers)
     try:
         ref = KVReference()
@@ -153,8 +201,7 @@ def build_chain(*, seed: int, validators: int, blocks: int, txs_per_block: int,
             if last_commit is None:
                 when = genesis_time_ns
             else:  # the median of equal-power votes stamped base + index
-                when = sorted(v.timestamp for v in last_commit.precommits)[
-                    len(vals) // 2]
+                when = stamps[len(vals) // 2]
             total_txs += len(txs)
             block = Block(
                 header=Header(
@@ -167,29 +214,33 @@ def build_chain(*, seed: int, validators: int, blocks: int, txs_per_block: int,
                 data=Data(txs=txs), evidence=EvidenceData(evidence=[]),
                 last_commit=last_commit)
             block.fill_header()
-            parts = make_part_set(block)
-            block_id = BlockID(hash=block.hash(), parts_header=parts.header())
-            out.messages.append(
-                serde.pack(["block_response", serde.block_obj(block)]))
-            out.block_hash.append(block_id.hash)
-            out.txs.append(txs)
-
+            encoded = block.encode()  # once: the parts and the message
+            block_id = BlockID(hash=block.hash(),
+                               parts_header=PartSet.from_data(encoded).header())
             base = max(last_time, when) + 1_000_000_000
             stamps = [base + i for i in range(len(vals))]
             prefix, suffix = _splice_parts(chain_id, h, block_id)
-            sigs = pool.sign_spliced(prefix, suffix, stamps)
+            signed = pool.sign_spliced(prefix, suffix, stamps)
+            # while the workers sign: what no signature feeds
+            out.messages.append(response_head + encoded)
+            out.block_hash.append(block_id.hash)
+            out.txs.append(txs)
+            for tx in txs:
+                ref.deliver(tx)
+            app_hash = ref.commit()
+            out.app_hash.append(app_hash)
+            sigs = signed()
             votes = [Vote(addresses[i], i, h, 0, stamps[i], VOTE_TYPE_PRECOMMIT,
                           block_id, sigs[i]) for i in range(len(vals))]
-            if h == 1:  # the splice against the program's own encoding
+            if h == 1:  # the splices against the program's own encodings
                 for i in (0, len(vals) - 1):
                     want = votes[i].sign_bytes(chain_id)
                     got = prefix + struct.pack("<Q", stamps[i]) + suffix
                     if want != got:
                         raise RuntimeError("spliced sign-bytes differ")
-            for tx in txs:
-                ref.deliver(tx)
-            app_hash = ref.commit()
-            out.app_hash.append(app_hash)
+                if out.messages[0] != serde.pack(
+                        ["block_response", serde.block_obj(block)]):
+                    raise RuntimeError("spliced block_response differs")
             last_results = results_hash
             last_id, last_commit, last_time = block_id, Commit(block_id, votes), when
     finally:

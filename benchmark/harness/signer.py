@@ -46,3 +46,16 @@ def sign_messages(job) -> bytes:
     for i, msg in job:
         out += _KEYS[i].sign(msg)
     return bytes(out)
+
+
+def serve(conn, seeds: list) -> None:
+    """A worker's life: (kind, job) in, the signatures out as bytes,
+    until the other end of the pipe is closed."""
+    init_worker(seeds)
+    kinds = {"spliced": sign_spliced, "messages": sign_messages}
+    while True:
+        try:
+            kind, job = conn.recv()
+        except EOFError:
+            return
+        conn.send_bytes(kinds[kind](job))

@@ -15,9 +15,20 @@ bridge `Run.reduce_trace` uses. Params:
 - `scale`: multiplies a millisecond result (1000: microseconds).
 
 None when there is no trace, when the program's records carry no ids
-(a program older than the spans), when the ring was full at the
-snapshot (a span of the window may have been dropped), or when no span
-of the name starts inside the window.
+(a program older than the spans) or when the ring was full at the
+snapshot (a span of the window may have been dropped): the records
+cannot be trusted. Where they can and no span of the name is in the
+window, `pct_of_window` is 0.0 (the recorder was on and whole, and the
+span never opened: `fastsync.poolWait` with a pool that never starved),
+and every other `what` is None: a mean over nothing is not 0. The
+records are read when the profiler's stop returns, seconds after the
+window (run.py `trace_stop`), so a span recorded when it ends
+(`fastsync.poolWait`, `runtime.gc`, `p2p.recvThrottle`) that was open at
+the window's end is in them and counts up to that end. Only one still
+open seconds later is missed: a wait that never ends, in which the cell
+applies no block and says so end to end. A throttled stretch is cut and
+recorded every 100 ms by the program, so a limiter that sleeps through
+the whole window reads its share, not 0.
 """
 from ..harness import trace as tr
 
@@ -74,12 +85,13 @@ def read(p: dict, run) -> float | None:
         return None
     lo, hi = run.trace_window
     chosen = _selected(spans, p, lo, hi)
-    if not chosen:
-        return None
     what = p["what"]
     if what == "pct_of_window":
+        # the records are whole: no span of the name is a share of 0
         covered = tr.union([(s, e) for _, s, e in chosen], lo, hi)
         return 100.0 * sum(e - s for s, e in covered) / (hi - lo)
+    if not chosen:
+        return None  # a mean over nothing is not 0
     if what == "total_ms":
         ns = sum(e - s for _, s, e in chosen)
     elif what == "self_ms":

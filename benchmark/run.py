@@ -48,8 +48,9 @@ class Run:
         self.prom = self.crypto = ({}, {})
         self.facts: dict = {}
         self.peaks: dict = {}
+        self.t_open = self.t_close = None
         self._trace_dir = None
-        self._trace_t0 = self._sync_perf_ns = None
+        self._trace_at = self._trace_t0 = self._sync_perf_ns = None
         self._spans_raw: list = []
 
     # -- hooks the drivers call ----------------------------------------
@@ -60,20 +61,19 @@ class Run:
             self.fault(node)
 
     def window_opens(self, t_open: float, surf) -> None:
-        """The window's first instant and its first readings; with
-        --trace 1 the profiler starts here."""
+        """The window's first instant and its first readings. The window
+        is [t_open, t_open + seconds] whatever any thread does after."""
         self.setup_s = t_open - _T0
+        self.t_open, self.t_close = t_open, t_open + self.seconds
         self._surf = surf
         self._prom0, self._crypto0 = self._readings()
-        if not self.trace_on:
-            return
-        from benchmark.harness import trace as tr
-        from tendermint_tpu.libs import tracing
-
-        self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        tracing.get_tracer().clear()
-        self._sync_perf_ns = tr.start_profile(self._trace_dir)
-        self._trace_t0 = time.monotonic()
+        print(f"benchmark: window open after {self.setup_s:.1f}s of set-up; its "
+              f"first readings took {time.monotonic() - t_open:.3f}s",
+              file=sys.stderr)
+        if self.trace_on:
+            # --trace 1 traces the window's last `trace_seconds`
+            cap = self.cell.traffic.get("trace_seconds", 8)
+            self._trace_at = max(t_open, self.t_close - cap)
 
     def _readings(self) -> tuple:
         from benchmark.harness import prom
@@ -81,34 +81,63 @@ class Run:
         return (prom.scrape(self._surf.metrics_addr),
                 self._surf.debug("/debug/crypto"))
 
-    def trace_due(self) -> bool:
-        """True once the traced part of the window is over."""
-        cap = self.cell.traffic.get("trace_seconds", 8)
-        return (self._trace_t0 is not None
-                and time.monotonic() - self._trace_t0 >= cap)
+    def wait_until(self, when: float) -> None:
+        """Sleeps the driver's main thread up to the instant `when` of the
+        window, and starts the profiler when the traced part begins."""
+        while time.monotonic() < when:
+            if self._trace_at is not None and time.monotonic() >= self._trace_at:
+                self._trace_start()
+            time.sleep(min(0.05, max(0.0, when - time.monotonic())))
+
+    def _trace_start(self) -> None:
+        from benchmark.harness import trace as tr
+        from tendermint_tpu.libs import tracing
+
+        self._trace_at = None
+        t = time.monotonic()
+        self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+        tracing.get_tracer().clear()
+        self._sync_perf_ns = tr.start_profile(self._trace_dir)
+        self._trace_t0 = time.monotonic()
+        print(f"benchmark: profiler started {self._trace_t0 - self.t_open:.3f}s "
+              f"into the window, in {self._trace_t0 - t:.3f}s", file=sys.stderr)
+
+    def window_closes(self) -> int:
+        """Called at t_close: the window's last readings; nothing here
+        takes long. The traced window ends at t_close, whenever this is
+        called. Returns the device's memory peak."""
+        from benchmark.harness import device
+
+        prom1, crypto1 = self._readings()
+        self.prom, self.crypto = (self._prom0, prom1), (self._crypto0, crypto1)
+        if self._trace_t0 is not None:
+            self.traced_s = self.t_close - self._trace_t0
+        return device.memory_peak_bytes()
 
     def trace_stop(self) -> None:
+        """Stops the profiler. Writing the trace out loads the process
+        for seconds (0.12 s a traced program), so a driver calls this
+        after window_closes() and after whatever else the window's
+        answers still wait for: never inside [t_open, t_close]. The
+        recorder's spans are read when it returns, not at t_close: a
+        span is recorded when it ends, and one that was open at t_close
+        (a wait, a collection, a throttled stretch) has ended by then,
+        so a share of the window counts it (readers/span_time.py)."""
         if self._trace_t0 is None:
             return
         import jax.profiler as jp
 
         from tendermint_tpu.libs import tracing
 
-        # the traced window ends where stop_trace is called: writing the
-        # trace out takes seconds in which nothing more is recorded
-        self.traced_s = time.monotonic() - self._trace_t0
-        self._spans_raw = tracing.get_tracer().events()
+        if time.monotonic() < self.t_close:
+            raise RuntimeError("the profiler may not be stopped inside the window")
         self._trace_t0 = None
+        t = time.monotonic()
         jp.stop_trace()
-
-    def window_closes(self) -> int:
-        """The window's last readings; returns the device's memory peak."""
-        from benchmark.harness import device
-
-        self.trace_stop()
-        prom1, crypto1 = self._readings()
-        self.prom, self.crypto = (self._prom0, prom1), (self._crypto0, crypto1)
-        return device.memory_peak_bytes()
+        self._spans_raw = tracing.get_tracer().events()
+        print(f"benchmark: profiler stopped in {time.monotonic() - t:.1f}s, "
+              f"{t - self.t_close:.3f}s after the window; the recorder holds "
+              f"{len(self._spans_raw)} spans", file=sys.stderr)
 
     # -- after the window ----------------------------------------------
 
@@ -211,9 +240,11 @@ def main(argv=None, *, allow_cpu: bool = False, fault=None, cell=None) -> int:
         ok = limit is not None and value <= limit
         correct = correct and ok
         checks[name] = {"value": value, "limit": limit}
+    # the driver's own counts go into every run's line (no metric: the
+    # checker ignores the key): window_s, heights, chain_used_pct, ...
     result = {"correct": bool(correct), "attempted": out["attempted"],
               "failed": out["failed"], "metrics": metrics, "device": device,
-              **line, "checks": checks}
+              **line, "facts": run.facts, "checks": checks}
     sys.stdout.flush()
     for name, c in checks.items():
         print(f"check {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)

@@ -55,7 +55,7 @@ def state_unchanged(node) -> None:
     """A step that returns its state unchanged: apply_block applies
     nothing and hands back the state it was given."""
     _patch(node.block_exec, "apply_block",
-           lambda state, block_id, block: state)
+           lambda state, block_id, block, **kw: state)
 
 
 def answer_altered(node) -> None:

@@ -85,3 +85,36 @@ def test_every_data_file_is_named_by_the_manifest():
     assert set(os.listdir(os.path.join(manifest.HERE, "metrics"))) == used
     traffic = {w["traffic"] + ".json" for w in MAN["workloads"]}
     assert traffic <= set(os.listdir(os.path.join(manifest.HERE, "workloads")))
+
+
+def test_the_metric_of_pr_29():
+    name, cells = "p2p_recv_throttled_pct.sync10k", ["sync10k-light"]
+    entry = next(m for m in MAN["per_layer"] if m["name"] == name)
+    body = manifest.load_json("metrics", name + ".json")
+    assert body["reader"] == "span_time" and entry["workloads"] == cells
+    assert entry["moves"] == "sync_blocks_per_s" and entry["unit"] == "%"
+    assert name in {m["name"] for m in manifest.Cell(cells[0], MAN).per_layer}
+    # a share that reads 0 when the span is absent
+    assert body["params"]["what"] == "pct_of_window"
+    assert entry["layer"] == "p2p link" and entry["source"] == "program_span"
+    # how much of its chain a run used is a fact in every run's line, not
+    # a metric: it is the end-to-end rate times a constant
+    assert not [m for m in MAN["per_layer"] if m["name"].startswith("chain_used")]
+
+
+def test_chain_lengths_and_the_ceilings_they_set():
+    # chain = warm + lookahead + 4 + rate x seconds. The joiner can use
+    # (chain - 1 - warm) of it before fast sync is over; the corrupted
+    # commit, two past a tip `lookahead` ahead, fits up to rate + 2/seconds
+    ends, fits = {}, {}
+    for cell in ("sync500-light", "sync500-busy", "sync10k-light"):
+        t = manifest.Cell(cell, MAN).traffic
+        n = (t["warmup_blocks"] + t["lookahead_blocks"] + 4
+             + t["chain_blocks_per_s"] * MAN["run_seconds"])
+        ends[cell] = (n - 1 - t["warmup_blocks"]) / MAN["run_seconds"]
+        fits[cell] = (n - t["lookahead_blocks"] - 2
+                      - t["warmup_blocks"]) / MAN["run_seconds"]
+    assert ends == {"sync500-light": 82.15, "sync500-busy": 12.15,
+                    "sync10k-light": 3.65}
+    assert fits == {"sync500-light": 80.1, "sync500-busy": 11.1,
+                    "sync10k-light": 3.1}

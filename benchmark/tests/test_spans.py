@@ -129,6 +129,51 @@ def test_nothing_is_read_from_what_cannot_be_trusted(monkeypatch):
     assert span_coverage.read({}, run_of(BLOCK)) is None
 
 
+ABSENT = {"name": "p2p.recvThrottle", "what": "pct_of_window"}
+
+
+def test_a_share_of_the_window_is_zero_for_a_span_that_never_opened():
+    # the recorder was on and whole, and no span of the name is in the
+    # window: that is a share of 0, not nothing to read
+    run = run_of(BLOCK)
+    assert span_time.read(ABSENT, run) == 0.0
+    assert span_time.read({"name": "fastsync.block", "what": "pct_of_window"},
+                          run_of(BLOCK, window_ms=(400, 900))) == 0.0
+    # the same for a list of names, and with args that nothing matches
+    assert span_time.read(dict(ABSENT, name=["p2p.recvThrottle",
+                                             "p2p.sendThrottle"]), run) == 0.0
+    assert span_time.read({"name": "runtime.gc", "what": "pct_of_window",
+                           "match": {"generation": 0}}, run) == 0.0
+    # every other `what` keeps saying nothing: a mean over nothing is not 0
+    for what in ("total_ms", "self_ms", "gap_to_parent_ms"):
+        assert span_time.read(dict(ABSENT, what=what), run) is None
+
+
+@pytest.mark.parametrize("broken", ["no_trace", "no_sync", "no_records",
+                                    "no_ids", "ring_wrapped"])
+def test_a_share_is_not_zero_where_the_records_cannot_be_trusted(
+        monkeypatch, broken):
+    run = run_of(BLOCK)
+    if broken == "no_trace":
+        run.trace = None
+    elif broken == "no_sync":
+        run.trace.sync_ns = None
+    elif broken == "no_records":
+        run = run_of([])
+    elif broken == "no_ids":
+        run = run_of([types.SimpleNamespace(
+            name=r.name, start_ns=r.start_ns, dur_ns=r.dur_ns, args=r.args)
+            for r in BLOCK])
+    else:
+        from tendermint_tpu.libs import tracing
+
+        monkeypatch.setattr(tracing, "_GLOBAL",
+                            tracing.Tracer(capacity=len(BLOCK)))
+    assert span_time.read(ABSENT, run) is None
+    assert span_time.read({"name": "fastsync.block", "what": "pct_of_window"},
+                          run) is None
+
+
 def test_coverage_is_the_unattributed_idle_share():
     # the chip works 100..110 and 300..310 ms of a 1000 ms window; the
     # idle gaps are 0..100, 110..300, 310..1000 with middles 50, 205, 655

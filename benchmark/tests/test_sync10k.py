@@ -38,7 +38,7 @@ def test_the_cell_resolves_with_its_own_files():
 def test_every_sync10k_metric_loads_and_its_reader_imports():
     cell = manifest.Cell(CELL)
     mine = [m for m in cell.per_layer if m["name"].endswith(".sync10k")]
-    assert len(mine) == 20 and len(mine) == len(cell.per_layer)
+    assert len(mine) == 21 and len(mine) == len(cell.per_layer)
     moves = {m["name"]: m["moves"] for m in mine}
     assert {n for n, e in moves.items() if e == "setup_s"} == {
         "compiles_in_window.sync10k", "kernel_ready_s.sync10k"}
@@ -50,6 +50,11 @@ def test_every_sync10k_metric_loads_and_its_reader_imports():
     for kernel in ("ed25519_verify_us_per_sig", "ed25519_verify_roofline"):
         assert by[kernel + ".sync10k"]["params"]["pattern"] == "ed25519_verify"
     assert by["sync_pool_wait_pct.sync10k"]["params"]["what"] == "pct_of_window"
+    # PR 29: the link's share of the window (0 until the supply is cured)
+    assert by["p2p_recv_throttled_pct.sync10k"]["params"] == {
+        "name": "p2p.recvThrottle", "what": "pct_of_window"}
+    assert by["p2p_recv_throttled_pct.sync10k"]["layer"] == "p2p link"
+    assert cell.traffic["chain_blocks_per_s"] == 3
     # the metrics with no list before this cell keep the accepted cells
     man = manifest.manifest()
     for name in ("compiles_in_window", "kernel_ready_s"):

@@ -7,10 +7,11 @@ first, unchanged.
     python3 benchmark/tools/span_table.py --workload <cell> --seed <n> \
         --seconds <s> [--profiler 0] [--longest <k>] [--out <file.json>]
 
-The table covers the spans that started in the first `trace_seconds` of
-the cell's traffic file after the recorder was cleared at the window's
-opening (the traced window, to within the profiler's start-up), read
-from the ring after the run; it says so if the ring wrapped meanwhile.
+The table covers the spans that started in the `trace_seconds` of the
+cell's traffic file after the recorder was cleared, which is when the
+profiler started: the window's last `trace_seconds` (the traced window,
+to within the profiler's start-up), read from the ring after the run; it
+says so if the ring wrapped meanwhile.
 `--profiler 0` makes an untraced run with the recorder switched on from
 here (as tools/recorder_cost.py does) and tables everything the ring
 holds at the end: the way to catch a stall in a whole window, which
