@@ -271,6 +271,8 @@ REQUIRED_FAMILIES = (
     "churn_valset_changes_total",
     "p2p_reconnect_attempts_total",
     "p2p_throttled_seconds_total",
+    "p2p_frames_total",
+    "p2p_socket_calls_total",
     # PR-11 runtime lockdep (declaration presence: samples flow only
     # under [instrumentation] lockdep = true — the chaos-under-lockdep
     # scenarios are where these families go live)

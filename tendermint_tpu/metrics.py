@@ -133,6 +133,11 @@ class P2PMetrics:
     # direction (send|recv): tells "the link's cap binds" from "the
     # host is slow" when a sync nears send_rate/recv_rate
     throttled_seconds: object = NOP  # (direction)
+    # sealed frames a SecretConnection sent or opened, and the socket
+    # calls that carried them (send: one a sendall; recv: one a recv
+    # that returned bytes): calls per frame is what a batch saves
+    frames: object = NOP  # (direction)
+    socket_calls: object = NOP  # (direction)
     # network-fault engine (p2p/netchaos.py): faults actually injected,
     # by kind (drop|delay|throttle|disconnect), and the rules currently
     # active in the installed fault plan (0 when no controller/phase)
@@ -526,6 +531,15 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             f"{ns}_p2p_throttled_seconds_total",
             "Seconds connections spent blocked by the [p2p] send_rate/"
             "recv_rate limiter, by direction.", ("direction",)),
+        frames=r.counter(
+            f"{ns}_p2p_frames_total",
+            "Sealed frames connections sent or opened, by direction.",
+            ("direction",)),
+        socket_calls=r.counter(
+            f"{ns}_p2p_socket_calls_total",
+            "Socket calls that carried sealed frames, by direction "
+            "(send: one a sendall; recv: one a recv that returned "
+            "bytes).", ("direction",)),
         chaos_injected=r.counter(
             f"{ns}_chaos_injected_total",
             "Network faults injected by the netchaos engine, by kind.",

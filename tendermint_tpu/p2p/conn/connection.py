@@ -4,8 +4,10 @@ Reference parity: p2p/conn/connection.go.  One MConnection per peer:
 byte-ID'd channels with priorities and bounded send queues; messages are
 packetized (≤1024B payload, :21), the send loop picks the channel with
 the least recently_sent/priority ratio (:464-486) and sends batches of
-10 packets (:23, :448-462); both directions are flow-rate limited
-(:370,504); ping/pong liveness with a pong timeout (:38-40).
+10 packets (:23, :448-462), each batch in one conn.write (the
+reference's bufio writer, flushed after sendSomePacketMsgs); both
+directions are flow-rate limited (:370,504); ping/pong liveness with a
+pong timeout (:38-40).
 
 on_receive(ch_id, msg_bytes) fires when a packet with EOF completes a
 message; on_error(err) fires once on connection failure.
@@ -44,6 +46,12 @@ THROTTLE_SPAN_FLOOR_NS = 1_000_000
 _PKT_PING = 0
 _PKT_PONG = 1
 _PKT_MSG = 2
+
+
+def _packet(obj) -> bytes:
+    """One length-prefixed packet as it goes on the connection."""
+    body = msgpack.packb(obj, use_bin_type=True)
+    return struct.pack("<I", len(body)) + body
 
 
 @dataclass
@@ -130,6 +138,15 @@ class MConnection:
         self.send_monitor = Monitor()
         self.recv_monitor = Monitor()
         self._throttled: Dict[str, Optional[List[int]]] = {}
+        # per direction: [a count the conn keeps, the counter it feeds,
+        # the count last published]
+        frames, calls = self.metrics.frames, self.metrics.socket_calls
+        self._link = {
+            "send": [["frames_sent", frames.with_labels("send"), 0],
+                     ["send_calls", calls.with_labels("send"), 0]],
+            "recv": [["frames_recv", frames.with_labels("recv"), 0],
+                     ["recv_calls", calls.with_labels("recv"), 0]],
+        }
         # wall clock of the last fully received packet (any kind);
         # 0.0 until the first one lands. The peer-reachability probe
         # (consensus stall classification, monitor [PARTITIONED?] tag)
@@ -206,17 +223,32 @@ class MConnection:
         ch = self.channels.get(ch_id)
         return ch is not None and not ch.send_queue.full()
 
-    def _write_packet(self, obj) -> None:
-        body = msgpack.packb(obj, use_bin_type=True)
+    def _write_packets(self, packets: bytes) -> None:
+        """One conn.write of whole packets under the write lock: the
+        send and ping threads never tear one another's packets."""
         with self._wlock:
-            self.conn.write(struct.pack("<I", len(body)) + body)
+            self.conn.write(packets)
+            self._publish_link("send")
+
+    def _publish_link(self, direction: str) -> None:
+        """What the conn's frame and socket-call counts (the plain
+        integers a SecretConnection keeps; a conn without them counts
+        nothing) gained since the last call, into p2p_frames_total and
+        p2p_socket_calls_total{direction}. Once a batch sent, once a
+        packet received; one caller at a time per direction."""
+        for entry in self._link[direction]:
+            attr, counter, seen = entry
+            now = getattr(self.conn, attr, 0)
+            if now != seen:
+                counter.inc(now - seen)
+                entry[2] = now
 
     def _send_routine(self) -> None:
         try:
             while not self._stop.is_set():
                 if self._pong_pending.is_set():
                     self._pong_pending.clear()
-                    self._write_packet([_PKT_PONG])
+                    self._write_packets(_packet([_PKT_PONG]))
                 if not self._send_some_packets():
                     # nothing pending: wait for a signal (bounded so the
                     # pong/ping path stays responsive)
@@ -250,7 +282,8 @@ class MConnection:
                 open_[1] = t1
 
     def _send_some_packets(self) -> bool:
-        """Send up to a batch of packets; True if any were sent
+        """Send up to a batch of packets, gathered without waiting and
+        written at once in one conn.write; True if any were sent
         (connection.go:448-486)."""
         # rate-limit on the monitor before a batch
         self._throttle(
@@ -258,7 +291,7 @@ class MConnection:
             NUM_BATCH_PACKET_MSGS * self.config.max_packet_msg_payload_size,
             self.config.send_rate,
         )
-        sent_any = False
+        batch = []
         for _ in range(NUM_BATCH_PACKET_MSGS):
             best, least_ratio = None, float("inf")
             for ch in self.channels.values():
@@ -273,13 +306,14 @@ class MConnection:
                 eof, chunk = best.next_packet()
             except queue.Empty:
                 continue
-            self._write_packet([_PKT_MSG, best.desc.id, eof, chunk])
+            batch.append(_packet([_PKT_MSG, best.desc.id, eof, chunk]))
             self.send_monitor.update(len(chunk))
-            sent_any = True
+        if batch:
+            self._write_packets(b"".join(batch))
         # decay recently_sent so priorities re-assert over time
         for ch in self.channels.values():
             ch.recently_sent = int(ch.recently_sent * 0.8)
-        return sent_any
+        return bool(batch)
 
     # -- receiving -----------------------------------------------------
 
@@ -295,6 +329,7 @@ class MConnection:
                 if length > max_packet:
                     raise ConnectionError(f"packet too large: {length}")
                 body = self.conn.read_exact(length)
+                self._publish_link("recv")
                 self.last_recv_time = time.monotonic()
                 self.recv_monitor.update(len(body))
                 self._throttle("recv", self.recv_monitor, len(body),
@@ -326,7 +361,7 @@ class MConnection:
         try:
             while not self._stop.wait(timeout=self.config.ping_interval):
                 self._pong_received.clear()
-                self._write_packet([_PKT_PING])
+                self._write_packets(_packet([_PKT_PING]))
                 # the recv routine sets _pong_received; an early pong
                 # ends the wait so the period stays ~ping_interval
                 if not self._pong_received.wait(timeout=self.config.pong_timeout):

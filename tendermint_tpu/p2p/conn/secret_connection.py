@@ -10,6 +10,14 @@ challenge (frames :109-140, key schedule :200-260 in the reference).
 
 Wire format is our own (this is a new framework, not a wire-compatible
 client), but the cryptographic structure and frame discipline match.
+
+The socket is called once for a batch of sealed frames, not once a
+frame (what the reference's bufio reader and writer do around its
+conn): one `write(data)` is every frame of `data`, sealed in nonce
+order, in ONE `sendall`; a read takes up to RECV_CHUNK_SIZE of
+ciphertext off the socket in one `recv` and opens frames from that
+buffer one at a time as plaintext is asked for. The sealed stream is
+frame for frame what a call a frame would carry.
 """
 
 from __future__ import annotations
@@ -43,7 +51,10 @@ DATA_LEN_SIZE = 4
 DATA_MAX_SIZE = 1024
 TOTAL_FRAME_SIZE = DATA_MAX_SIZE + DATA_LEN_SIZE  # 1028
 AEAD_TAG_SIZE = 16
+SEALED_FRAME_SIZE = TOTAL_FRAME_SIZE + AEAD_TAG_SIZE  # 1044 on the wire
 NONCE_SIZE = 12
+# the most ciphertext one recv takes off the socket (62 sealed frames)
+RECV_CHUNK_SIZE = 64 * 1024
 
 HKDF_INFO = b"TENDERMINT_TPU_SECRET_CONNECTION_KEY_AND_CHALLENGE_GEN"
 
@@ -67,9 +78,16 @@ class SecretConnection:
 
     def __init__(self, conn: socket.socket, loc_priv_key: PrivKey):
         self._conn = conn
-        self._recv_buffer = b""
+        self._sealed = bytearray()  # ciphertext received, not yet opened
+        self._recv_buffer = b""  # plaintext of the frame opened last
         self._send_nonce = 0
         self._recv_nonce = 0
+        # sealed frames and socket calls, each way: plain integers, no
+        # metric call a frame (MConnection publishes their gains)
+        self.frames_sent = 0
+        self.send_calls = 0  # one a sendall
+        self.frames_recv = 0
+        self.recv_calls = 0  # one a recv that returned bytes
 
         # 1. ephemeral X25519 exchange (every 32-byte string is a valid
         #    Curve25519 pubkey, so no validation step is needed)
@@ -126,27 +144,46 @@ class SecretConnection:
         return self._recv_aead.decrypt(nonce, sealed, None)
 
     def write(self, data: bytes) -> int:
-        """Write data as one-or-more sealed frames."""
-        n = 0
+        """Seal data as one-or-more frames and send them all in one
+        sendall: a write is never split over socket calls."""
         view = memoryview(data)
-        while len(view) > 0:
-            chunk = view[:DATA_MAX_SIZE]
+        sealed = []
+        for off in range(0, len(view), DATA_MAX_SIZE):
+            chunk = view[off : off + DATA_MAX_SIZE]
             frame = struct.pack("<I", len(chunk)) + bytes(chunk)
             frame += b"\x00" * (TOTAL_FRAME_SIZE - len(frame))
-            self._conn.sendall(self._seal(frame))
-            n += len(chunk)
-            view = view[len(chunk) :]
-        return n
+            sealed.append(self._seal(frame))
+        if sealed:
+            self._conn.sendall(b"".join(sealed))
+            self.frames_sent += len(sealed)
+            self.send_calls += 1
+        return len(view)
+
+    def _next_frame(self) -> bytes:
+        """Open the next sealed frame and return its data. Ciphertext
+        stays in self._sealed until a whole frame of it is there, so a
+        socket.timeout in mid-frame loses nothing; a frame is opened and
+        its length checked before any of its bytes is handed up."""
+        buf = self._sealed
+        while len(buf) < SEALED_FRAME_SIZE:
+            chunk = self._conn.recv(RECV_CHUNK_SIZE)
+            if not chunk:
+                raise ConnectionError("connection closed during read")
+            buf += chunk
+            self.recv_calls += 1
+        sealed = bytes(buf[:SEALED_FRAME_SIZE])
+        del buf[:SEALED_FRAME_SIZE]
+        frame = self._open(sealed)
+        self.frames_recv += 1
+        (length,) = struct.unpack_from("<I", frame)
+        if length > DATA_MAX_SIZE:
+            raise ConnectionError(f"frame length {length} > {DATA_MAX_SIZE}")
+        return frame[DATA_LEN_SIZE : DATA_LEN_SIZE + length]
 
     def read(self, n: int) -> bytes:
         """Read up to n plaintext bytes (at least 1, blocking)."""
         if not self._recv_buffer:
-            sealed = _recv_exact(self._conn, TOTAL_FRAME_SIZE + AEAD_TAG_SIZE)
-            frame = self._open(sealed)
-            (length,) = struct.unpack("<I", frame[:DATA_LEN_SIZE])
-            if length > DATA_MAX_SIZE:
-                raise ConnectionError(f"frame length {length} > {DATA_MAX_SIZE}")
-            self._recv_buffer = frame[DATA_LEN_SIZE : DATA_LEN_SIZE + length]
+            self._recv_buffer = self._next_frame()
         out, self._recv_buffer = self._recv_buffer[:n], self._recv_buffer[n:]
         return out
 

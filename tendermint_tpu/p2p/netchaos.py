@@ -17,9 +17,10 @@ cannot perturb each other (the bug the global-`random` fuzz layer had).
 
 Faults act on the SENDING side of each link: every peer connection a
 Switch creates while a controller is installed gets wrapped in a
-ChaosConn whose write path consults the controller. MConnection writes
-whole frames per write() call, so dropping a write loses messages —
-exactly a lossy/partitioned network — without ever corrupting framing.
+ChaosConn whose write path consults the controller. MConnection's
+write() is a whole number of length-prefixed packets (a batch of up to
+ten, or one ping or pong), so dropping a write loses messages — exactly
+a lossy/partitioned network — without ever corrupting framing.
 One-way rules therefore model asymmetric partitions naturally: A's
 outbound wrapper drops A->B while B's wrapper keeps delivering B->A.
 
@@ -386,9 +387,11 @@ class NetChaosController:
 class ChaosConn:
     """Wraps a SecretConnection-shaped object (write / read_exact /
     close), applying the controller's outbound decisions for one
-    (local node -> peer) link. MConnection writes whole length-prefixed
-    frames per write() call, so a dropped write is a lost message,
-    never torn framing."""
+    (local node -> peer) link. One decision — drop, delay, throttle —
+    a write(), and MConnection's write() is a whole number of
+    length-prefixed packets (its batch of up to ten, or a ping or pong):
+    a dropped write is a lost message, never torn framing, and a delay
+    rule costs once a batch."""
 
     def __init__(self, conn, controller: NetChaosController,
                  src_id: str, dst_id: str):

@@ -2,9 +2,13 @@
 p2p/conn/connection.go:448-486 sendSomePacketMsgs: pick the channel with
 the least recently_sent/priority ratio, batch of 10, decay after).
 
-No sockets/threads: a dummy conn + recorded _write_packet drive
-_send_some_packets directly.
+No sockets/threads: a dummy conn that decodes the packets of each
+conn.write drives _send_some_packets directly.
 """
+
+import struct
+
+import msgpack
 
 from tendermint_tpu.p2p.base_reactor import ChannelDescriptor
 from tendermint_tpu.p2p.conn.connection import (
@@ -15,8 +19,16 @@ from tendermint_tpu.p2p.conn.connection import (
 
 
 class _DummyConn:
-    def write(self, b):  # pragma: no cover - never reached
-        raise AssertionError("dummy conn must not be written")
+    def __init__(self):
+        self.sent = []  # every packet written: [type, ch, eof, chunk]
+
+    def write(self, b):
+        pos = 0
+        while pos < len(b):
+            (n,) = struct.unpack_from("<I", b, pos)
+            self.sent.append(msgpack.unpackb(b[pos + 4 : pos + 4 + n], raw=False))
+            pos += 4 + n
+        assert pos == len(b), "a write is a whole number of packets"
 
     def read_exact(self, n):  # pragma: no cover
         raise AssertionError("dummy conn must not be read")
@@ -27,10 +39,9 @@ class _DummyConn:
 
 def _mconn(descs, **cfg_kw):
     cfg = MConnConfig(send_rate=10**12, **cfg_kw)  # no rate limiting
-    sent = []
-    m = MConnection(_DummyConn(), descs, lambda ch, b: None, lambda e: None, cfg)
-    m._write_packet = lambda obj: sent.append(obj)  # [type, ch, eof, chunk]
-    return m, sent
+    conn = _DummyConn()
+    m = MConnection(conn, descs, lambda ch, b: None, lambda e: None, cfg)
+    return m, conn.sent
 
 
 def _fill(m, ch_id, nbytes):
