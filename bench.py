@@ -31,7 +31,7 @@ Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": "ms", "vs_baseline": N}
 vs_baseline > 1 means faster than the serial baseline.
 
-Device modes (default/mega/rlc, votes, fastsync, cache, statesync) need
+Device modes (default/mega, votes, fastsync, cache, statesync) need
 the chip: they exit non-zero, naming the cause, unless
 jax.default_backend() is "tpu", and every line they print carries the
 platform, device_kind and device count it ran on. The host modes pin
@@ -48,7 +48,6 @@ import subprocess
 import sys
 import time
 
-RLC_MODE = "rlc" in sys.argv[1:]
 VOTES_MODE = "votes" in sys.argv[1:]  # BASELINE.json config 3
 FASTSYNC_MODE = "fastsync" in sys.argv[1:]  # BASELINE.json config 4 (scaled)
 COMMIT4_MODE = "commit4" in sys.argv[1:]  # BASELINE.json config 1
@@ -70,7 +69,7 @@ FLEET_MODE = "fleet" in sys.argv[1:]  # replica fan-out serving (PR 20)
 PIPELINE_FLAG = "--pipeline" in sys.argv[1:]  # fastsync: 2-stage pipeline
 PARALLEL_FLAG = "--parallel" in sys.argv[1:]  # load: parallel exec lanes
 _args = [a for a in sys.argv[1:]
-         if a not in ("rlc", "votes", "fastsync", "commit4", "cache",
+         if a not in ("votes", "fastsync", "commit4", "cache",
                       "statesync", "chaos", "load", "preverify",
                       "aggverify", "mega", "chaosnet",
                       "crashrecovery", "detcheck", "proptrace",
@@ -2250,28 +2249,17 @@ def main():
         return statesync_main()
 
     from tendermint_tpu.crypto import keys
-    from tendermint_tpu.crypto.jaxed25519.verify import (
-        verify_batch,
-        verify_batch_rlc,
-    )
-
-    if RLC_MODE:
-        # aggregate mode benchmarks the fast-sync scenario: all-valid
-        # commits where the RLC group equation shares one doubling chain
-        verify_fn = lambda m, s, p: verify_batch_rlc(m, s, p)
-    else:
-        verify_fn = verify_batch
+    from tendermint_tpu.crypto.jaxed25519.verify import verify_batch
 
     # build a synthetic 10k-validator commit: distinct keys, vote-sized
     # messages (~110B canonical sign-bytes), ~1% corrupted signatures
-    # (all-valid in rlc mode — its fast path is the valid-heavy batch)
     sks = [keys.PrivKeyEd25519.generate() for _ in range(min(n, 2000))]
     msgs, sigs, pks, want = [], [], [], []
     for i in range(n):
         sk = sks[i % len(sks)]
         msg = secrets.token_bytes(110)
         sig = sk.sign(msg)
-        if not RLC_MODE and i % 100 == 37:
+        if i % 100 == 37:
             sig = bytes([sig[0] ^ 1]) + sig[1:]
             want.append(False)
         else:
@@ -2288,61 +2276,25 @@ def main():
     serial_ms = (time.perf_counter() - t0) / sub * n * 1000
 
     # batch path: one warmup (compile; persistent cache warms later runs),
-    # then timed runs. The chunked dispatch (TM_TPU_VERIFY_CHUNKS) can
-    # hide transfer behind compute: sweep chunk counts (seeded with any user-set value) and
-    # report the best COMPLETE verify. Sweeping only makes sense where
-    # verify_batch actually chunks: single device, n >= chunk_min.
-    import jax as _jax
+    # then timed runs of the COMPLETE verify
+    got = verify_batch(msgs, sigs, pks)
+    assert got == want, "batch verify mask mismatch vs expected"
+    times = []
+    for _ in range(7):
+        t0 = time.perf_counter()
+        verify_batch(msgs, sigs, pks)
+        times.append((time.perf_counter() - t0) * 1000)
+    batch_ms = min(times)
 
-    prev_chunks = os.environ.get("TM_TPU_VERIFY_CHUNKS")
-    try:
-        chunk_min = int(os.environ.get("TM_TPU_VERIFY_CHUNK_MIN", "2048"))
-    except ValueError:
-        chunk_min = 2048  # same fallback verify_batch uses
-    can_chunk = (not RLC_MODE
-                 and len(_jax.devices()) == 1 and n >= chunk_min)
-    sweep = [1]
-    if can_chunk:
-        sweep = [1, 2, 4]
-        if (prev_chunks and prev_chunks.isdigit() and int(prev_chunks) >= 2
-                and int(prev_chunks) not in sweep):
-            sweep.append(int(prev_chunks))
-    batch_ms, best_chunks = float("inf"), 1
-    for ck in sweep:
-        os.environ["TM_TPU_VERIFY_CHUNKS"] = str(ck)
-        got = verify_fn(msgs, sigs, pks)
-        assert got == want, "batch verify mask mismatch vs expected"
-        times = []
-        for _ in range(7):
-            t0 = time.perf_counter()
-            verify_fn(msgs, sigs, pks)
-            times.append((time.perf_counter() - t0) * 1000)
-        if min(times) < batch_ms:
-            batch_ms, best_chunks = min(times), ck
-    if prev_chunks is None:
-        os.environ.pop("TM_TPU_VERIFY_CHUNKS", None)
-    else:
-        os.environ["TM_TPU_VERIFY_CHUNKS"] = prev_chunks
-
-    mode = "_rlc" if RLC_MODE else ""
-    out = {
-        "metric": f"verify_commit_{n}_sigs{mode}_wall_ms",
+    _emit({
+        "metric": f"verify_commit_{n}_sigs_wall_ms",
         "value": round(batch_ms, 3),
         "unit": "ms",
         "vs_baseline": round(serial_ms / batch_ms, 2),
-    }
-    if RLC_MODE:
-        out["note"] = (
-            "experimental: dispatch-bound, slower than the per-item "
-            "kernel at this scale (PROFILE.md); not used on consensus paths"
-        )
-    if not RLC_MODE:
         # device_ms = slope over back-to-back dispatches on resident data
         # (host clock; the profiler-trace number is ROADMAP S0's)
-        if can_chunk:
-            out["chunks"] = best_chunks
-        out["device_ms"] = round(_device_ms(msgs, sigs, pks), 1)
-    _emit(out)
+        "device_ms": round(_device_ms(msgs, sigs, pks), 1),
+    })
 
 
 def _device_ms(msgs, sigs, pks, k: int = 6) -> float:

@@ -516,8 +516,8 @@ def _check_sharded(triples, want) -> dict:
 
     n = len(msgs)
     buf, nb, mrows, bpad = _pack(triples, ndev)
-    # the variant verify_batch dispatches (already compiled above)
-    fn = jv._jitted_packed(nb, mrows, bpad, ndev, donate=jv._donate_default())
+    # the program verify_batch dispatches (already compiled above)
+    fn = jv._jitted_packed(nb, mrows, bpad, ndev)
     out = fn(jv._put(buf, ndev))
     out.block_until_ready()
     spans = len(out.sharding.device_set)

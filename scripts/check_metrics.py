@@ -246,12 +246,10 @@ REQUIRED_FAMILIES = (
     "consensus_agg_gossip_merges_total",
     "agg_commit_size_bytes",
     # PR-8 compile-once kernels (declaration presence: a cpu-backend
-    # node never compiles and a fully warm node never misses; the
-    # coalescer records nothing with the window at its default 0)
+    # node never compiles and a fully warm node never misses)
     "crypto_compile_seconds",
     "crypto_compile_cache_hits_total",
     "crypto_compile_cache_misses_total",
-    "crypto_coalesced_calls_total",
     # PR-9 RPC fan-out serving (declaration presence: a node with
     # caching off or no websocket subscribers legitimately records no
     # samples; rpc_ws_dropped_total only fires under slow clients)

@@ -282,20 +282,11 @@ class CryptoConfig:
     priv_validator.json keeps its key. bls12381 opts the chain into the
     aggregate-signature fast lane (O(1) commit certificates) — every
     genesis validator must use it, with proofs of possession in the
-    genesis doc (MIGRATION.md).
-
-    coalesce_window_ms > 0 turns on the cross-height verify scheduler:
-    verify_async calls arriving within the window are merged into one
-    device dispatch (up to coalesce_max_batch signatures), so pipelined
-    fast sync + live votes + statesync bisection share kernel launches.
-    0 (default) = every call dispatches immediately, pre-PR-8
-    behavior."""
+    genesis doc (MIGRATION.md)."""
 
     async_dispatch: bool = True
     sig_cache_size: int = 65536
     key_type: str = "ed25519"
-    coalesce_window_ms: float = 0.0
-    coalesce_max_batch: int = 8192
 
 
 @dataclass

@@ -105,9 +105,7 @@ def async_enabled() -> bool:
 
 
 def configure(async_dispatch: Optional[bool] = None,
-              sig_cache_size: Optional[int] = None,
-              coalesce_window_ms: Optional[float] = None,
-              coalesce_max_batch: Optional[int] = None) -> None:
+              sig_cache_size: Optional[int] = None) -> None:
     """Apply the [crypto] config section (config.CryptoConfig)."""
     if async_dispatch is not None:
         set_async_enabled(async_dispatch)
@@ -118,8 +116,6 @@ def configure(async_dispatch: Optional[bool] = None,
             set_sig_cache(SigCache(sig_cache_size))
         else:
             set_sig_cache(None)
-    if coalesce_window_ms is not None or coalesce_max_batch is not None:
-        set_coalesce(coalesce_window_ms, coalesce_max_batch)
 
 
 # --- async dispatch ----------------------------------------------------
@@ -283,196 +279,11 @@ def shutdown_dispatchers(timeout: float = 10.0) -> None:
     verify_async() issued afterwards lazily spawns a fresh dispatcher,
     so concurrent nodes in one process stay correct (at worst a thread
     respawn)."""
-    with _coalescers_lock:
-        cs = list(_coalescers.values())
-        _coalescers.clear()
-    for c in cs:
-        c.stop(timeout)
     with _dispatchers_lock:
         ds = list(_dispatchers.values())
         _dispatchers.clear()
     for d in ds:
         d.stop(timeout)
-
-
-# --- cross-height verify scheduler (coalescing verify_async) -----------
-#
-# With many verification streams in flight at once — pipelined fast
-# sync, live votes, statesync bisection — each caller's verify_async
-# issues its own (often half-full) device dispatch, and every dispatch
-# pays the fixed kernel-launch cost. When [crypto] coalesce_window_ms
-# is > 0, verify_async calls for the same backend arriving within that
-# window are merged into ONE backend dispatch (up to coalesce_max_batch
-# signatures); each caller's future still resolves with exactly its own
-# slice of the merged mask, in add order, so verdicts are identical to
-# sequential dispatch (property-tested). Defaults keep the scheduler
-# off: 0ms window = the plain per-call dispatcher path, untouched.
-
-_coalesce_window_s = 0.0
-_coalesce_max = 8192
-_coalescers: dict = {}  # (backend, class, instance key) -> _Coalescer
-_coalescers_lock = threading.Lock()
-
-
-def set_coalesce(window_ms: Optional[float] = None,
-                 max_batch: Optional[int] = None) -> None:
-    """Configure the verify_async coalescing scheduler. window_ms <= 0
-    disables it (every call dispatches immediately, the pre-PR-8
-    behavior). Takes effect for subsequent verify_async calls; already
-    pending entries flush under the window they were submitted with."""
-    global _coalesce_window_s, _coalesce_max
-    if window_ms is not None:
-        _coalesce_window_s = max(0.0, float(window_ms) / 1e3)
-    if max_batch is not None:
-        _coalesce_max = max(1, int(max_batch))
-
-
-def coalesce_window_ms() -> float:
-    return _coalesce_window_s * 1e3
-
-
-def coalesce_status() -> dict:
-    """Bundle for /debug/crypto: scheduler config + live pending size."""
-    with _coalescers_lock:
-        pending = sum(c.pending_items() for c in _coalescers.values())
-    return {
-        "window_ms": _coalesce_window_s * 1e3,
-        "max_batch": _coalesce_max,
-        "pending_items": pending,
-    }
-
-
-class _Coalescer:
-    """One daemon thread merging verify_async calls for one (backend,
-    verifier class) pair. Entries are (verifier, items, future); at
-    flush the first entry's verifier runs verify() over the merged item
-    list (the same _items-swap trick BatchVerifier.verify uses for the
-    sigcache miss subset), and each future resolves with its own slice.
-    A backend exception fans out to every future in the merged dispatch
-    — it still surfaces at result(), never in this thread."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self._cv = threading.Condition()
-        self._pending: list = []  # (verifier, items, future, metrics)
-        self._count = 0
-        self._deadline: Optional[float] = None
-        self._stopping = False
-        self._thread = threading.Thread(
-            target=self._run, name=f"crypto-coalesce-{name}", daemon=True
-        )
-        self._thread.start()
-
-    def pending_items(self) -> int:
-        return self._count
-
-    def submit(self, verifier: "BatchVerifier") -> Optional[VerifyFuture]:
-        """Queue this verifier's items for the next merged dispatch.
-        Returns None when stopping — the caller falls back to the plain
-        dispatcher path (its future then resolves there)."""
-        with self._cv:
-            if self._stopping:
-                return None
-            fut = VerifyFuture()
-            m = _metrics
-            _inflight_add(1)
-            if m is not None:
-                m.inflight_batches.add(1)
-            if not self._pending:
-                self._deadline = time.perf_counter() + _coalesce_window_s
-            self._pending.append((verifier, list(verifier._items), fut, m))
-            self._count += len(verifier._items)
-            self._cv.notify()
-            return fut
-
-    def _take(self) -> list:
-        """Pop the next merged group (caller holds the lock): entries in
-        submission order until max_batch is covered; anything past the
-        cap stays pending with an immediate deadline, so an oversize
-        burst drains as back-to-back full dispatches."""
-        taken, total = [], 0
-        while self._pending:
-            n = len(self._pending[0][1])
-            if taken and total + n > _coalesce_max:
-                break
-            taken.append(self._pending.pop(0))
-            total += n
-        self._count -= total
-        self._deadline = time.perf_counter() if self._pending else None
-        return taken
-
-    def _run(self) -> None:
-        while True:
-            with self._cv:
-                while not self._pending and not self._stopping:
-                    self._cv.wait()
-                if not self._pending:
-                    return  # stopping with nothing queued
-                # sit out the rest of the window unless the cap is hit
-                # or stop() needs the queue drained
-                while (self._count < _coalesce_max and not self._stopping):
-                    now = time.perf_counter()
-                    if self._deadline is None or now >= self._deadline:
-                        break
-                    self._cv.wait(self._deadline - now)
-                entries = self._take()
-            self._execute(entries)
-
-    @staticmethod
-    def _execute(entries: list) -> None:
-        host = entries[0][0]
-        merged = [t for _, items, _, _ in entries for t in items]
-        mask = None
-        exc: Optional[BaseException] = None
-        _dispatched.fut = entries[0][2]  # the oldest call's cause and wait
-        try:
-            saved = host._items
-            host._items = merged
-            try:
-                mask = host.verify()
-            finally:
-                host._items = saved
-        except BaseException as e:  # noqa: BLE001 - surfaces at result()
-            exc = e
-        finally:
-            _dispatched.fut = None
-        if len(entries) > 1:
-            m0 = entries[0][3]
-            if m0 is not None:
-                m0.coalesced_calls.inc(len(entries) - 1)
-        off = 0
-        for _, items, fut, m in entries:
-            try:
-                if exc is not None:
-                    fut._set_exception(exc)
-                else:
-                    fut._set_result(mask[off:off + len(items)])
-            finally:
-                off += len(items)
-                _inflight_add(-1)
-                if m is not None:
-                    m.inflight_batches.add(-1)
-
-    def stop(self, timeout: float = 10.0) -> None:
-        with self._cv:
-            if not self._stopping:
-                self._stopping = True
-                self._cv.notify_all()
-        self._thread.join(timeout)
-
-    def alive(self) -> bool:
-        return self._thread.is_alive()
-
-
-def _coalescer(verifier: "BatchVerifier") -> _Coalescer:
-    cls = type(verifier)
-    key = (verifier.BACKEND, cls, verifier._coalesce_key())
-    with _coalescers_lock:
-        c = _coalescers.get(key)
-        if c is None or not c.alive():
-            c = _Coalescer(f"{verifier.BACKEND}-{cls.__name__}")
-            _coalescers[key] = c
-        return c
 
 
 class BatchVerifier:
@@ -499,14 +310,6 @@ class BatchVerifier:
 
     def _verify(self) -> List[bool]:
         raise NotImplementedError
-
-    def _coalesce_key(self) -> tuple:
-        """Extra (hashable) key material for the coalescing scheduler:
-        only verifiers with equal (BACKEND, class, this key) merge into
-        one dispatch. Subclasses carrying per-instance dispatch policy
-        must include it here, so a merged batch never runs under
-        another caller's configuration."""
-        return ()
 
     def verify(self) -> List[bool]:
         """Returns one validity flag per added triple, in add order.
@@ -593,17 +396,7 @@ class BatchVerifier:
         """Dispatch verify() of the CURRENT items on this backend's
         dedicated dispatch thread. The caller must not add() to this
         verifier while the future is in flight; result() returns the
-        per-item mask (add order) or re-raises the backend error.
-
-        With [crypto] coalesce_window_ms > 0, calls landing within the
-        window are merged into one backend dispatch (same class AND
-        same per-instance _coalesce_key only, so backend semantics are
-        exact); the future still resolves with this call's own mask
-        slice."""
-        if _coalesce_window_s > 0 and self._items:
-            fut = _coalescer(self).submit(self)
-            if fut is not None:
-                return fut
+        per-item mask (add order) or re-raises the backend error."""
         return _dispatcher(self.BACKEND).submit(self.verify)
 
     def verify_all(self) -> bool:
@@ -654,12 +447,6 @@ class AdaptiveBatchVerifier(BatchVerifier):
         if min_device_batch is None:
             min_device_batch = effective_batch_min()
         self._min = min_device_batch
-
-    def _coalesce_key(self) -> tuple:
-        # routing policy is per-instance: two nodes in one process may
-        # configure different factories/thresholds, and a merged batch
-        # runs entirely on the FIRST caller's instance
-        return (self._device_factory, self._min)
 
     def verify(self) -> List[bool]:
         # overrides verify() (not _verify) on purpose: the inner

@@ -217,11 +217,11 @@ def _small_base_table_np():
     return out
 
 
-def _windows_msb_first(s_limbs, bdim, nbits: int = 256):
-    """(nbits//4, B) int32 4-bit windows, most-significant first."""
-    bits = scalar_bits(s_limbs, nbits)  # (nbits, B) LSB-first
+def _windows_msb_first(s_limbs, bdim):
+    """(64, B) int32 4-bit windows, most-significant first."""
+    bits = scalar_bits(s_limbs, 256)  # (256, B) LSB-first
     weights = jnp.asarray([1, 2, 4, 8], dtype=jnp.int32)[None, :, None]
-    w = jnp.sum(bits.reshape(nbits // 4, 4, bdim) * weights, axis=1)
+    w = jnp.sum(bits.reshape(64, 4, bdim) * weights, axis=1)
     return w[::-1]
 
 
@@ -278,87 +278,6 @@ def straus_mul_sub(s_limbs, k_limbs, neg_a):
         return add_niels(acc, (entry[:20], entry[20:40], entry[40:]))
 
     return jax.lax.fori_loop(0, 64, body, identity_p3_like(s_limbs))
-
-
-# --- grouped multi-scalar multiplication (aggregate/RLC verification) ------
-
-
-def add_points(p, q):
-    """Full P3 + P3 addition (complete)."""
-    return add_cached(p, to_cached(q))
-
-
-def build_p3_table(p):
-    """[j]p for j = 1..15 in P3 form (14 point ops) — the per-item window
-    table of the grouped MSM."""
-    p_cached = to_cached(p)
-    mults = [p]
-    for j in range(2, 16):
-        if j % 2 == 0:
-            mults.append(double(mults[j // 2 - 1]))
-        else:
-            mults.append(add_cached(mults[j - 2], p_cached))
-    return mults
-
-
-def _select_p3(table, win_row):
-    """Per-item table row select by 4-bit digit; digit 0 -> identity."""
-    sel = [jnp.zeros_like(table[0][0]) for _ in range(4)]
-    for j in range(15):
-        m = (win_row == j + 1).astype(jnp.int32)[None, :]
-        for c in range(4):
-            sel[c] = sel[c] + table[j][c] * m
-    m0 = (win_row == 0).astype(jnp.int32)
-    sel[1] = sel[1].at[0].add(m0)  # identity = (0, 1, 1, 0)
-    sel[2] = sel[2].at[0].add(m0)
-    return tuple(sel)
-
-
-def _group_tree_reduce(p, group: int):
-    """Sum contiguous groups of `group` lanes (power of two) down to one
-    point per group via pairwise adds — (20, B) -> (20, B//group)."""
-    while group > 1:
-        a = tuple(c[:, 0::2] for c in p)
-        b = tuple(c[:, 1::2] for c in p)
-        p = add_points(a, b)
-        group //= 2
-    return p
-
-
-def msm_groups(r_pts, z_win, a_pts, zk_win, group: int):
-    """Per-group Σ_j ([z_j]R_j + [zk_j]A_j) with ONE doubling chain shared
-    by the whole group — the core of aggregate (random-linear-combination)
-    batch verification. z_win: (nz, B) 4-bit windows MSB-first (the short
-    per-item randomizers); zk_win: (64, B) (253-bit). Returns a P3 batch
-    of width B//group. The doubling work drops by the group factor vs
-    per-item chains; window adds tree-reduce within each contiguous lane
-    group."""
-    bdim = r_pts[0].shape[-1]
-    assert bdim % group == 0 and (group & (group - 1)) == 0
-    nz = z_win.shape[0]
-    assert nz <= 64
-    table_r = build_p3_table(r_pts)
-    table_a = build_p3_table(a_pts)
-    acc0 = identity_p3(bdim // group)
-
-    def step(acc, w, with_r):
-        acc = double(double(double(double(acc))))
-        sel_a = _select_p3(table_a, zk_win[w])
-        acc = add_points(acc, _group_tree_reduce(sel_a, group))
-        if with_r:
-            sel_r = _select_p3(table_r, z_win[w - (64 - nz)])
-            acc = add_points(acc, _group_tree_reduce(sel_r, group))
-        return acc
-
-    # zk has 64 windows; the short z joins for the last nz of them
-    acc = jax.lax.fori_loop(0, 64 - nz, lambda w, a: step(a, w, False), acc0)
-    return jax.lax.fori_loop(64 - nz, 64, lambda w, a: step(a, w, True), acc)
-
-
-def is_identity(p):
-    """(B,) bool: p == neutral element (X/Z == 0 and Y/Z == 1)."""
-    X, Y, Z, _ = p
-    return field.is_zero_frozen(field.freeze(X)) & field.eq_mod_p(Y, Z)
 
 
 def var_base_mul(p, s_limbs):

@@ -109,32 +109,6 @@ def reduce_512(k40):
     return k
 
 
-def mul_mod_l(a, b):
-    """(20, B) x (20, B) canonical-ish scalars (< 2^253) -> (20, B)
-    canonical product mod L. Schoolbook conv (coefficients < 20*8191^2
-    < 2^31), exact carry into 40 limbs, then the reduce_512 fold chain —
-    used by the aggregate (random-linear-combination) batch verifier."""
-    bdim = a.shape[-1]
-    terms = []
-    pad = [(0, 0)] * (a.ndim - 1)
-    for i in range(20):
-        terms.append(jnp.pad(a[i] * b, [(i, 19 - i)] + pad))
-    c = terms[0]
-    for t in terms[1:]:
-        c = c + t  # (39, B)
-    c40 = jnp.pad(c, [(0, 1)] + pad)
-    return reduce_512(_seq_carry_exact(c40, 40))
-
-
-def sum_mod_l_groups(v, group: int):
-    """(20, B) canonical scalars -> (20, B//group) per-group sums mod L.
-    Limb sums stay exact in int32 for group <= 2^17."""
-    bdim = v.shape[-1]
-    g = v.reshape(20, bdim // group, group).sum(axis=2)  # limbs < 8191*group
-    g40 = jnp.pad(_seq_carry_exact(g, 24), [(0, 16), (0, 0)])
-    return reduce_512(g40)
-
-
 def scalar_bits(s20, nbits: int = 256):
     """(20, B) canonical limbs -> (nbits, B) int32 bits, little-endian."""
     shifts = jnp.arange(BITS, dtype=jnp.int32)[None, :, None]

@@ -18,9 +18,8 @@ all-gather of masks.
 from __future__ import annotations
 
 import os
-import threading
 import time
-from functools import lru_cache, partial
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -95,18 +94,6 @@ def _limbs_from_bytes(bts):
     return jnp.stack(rows, axis=0)
 
 
-@lru_cache(maxsize=32)
-def _jitted(nb: int, bpad: int, ndev: int):
-    if ndev > 1:
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-        mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("dp",))
-        last = lambda n: NamedSharding(mesh, P(*([None] * (n - 1) + ["dp"])))
-        in_sh = (last(4), last(1), last(2), last(1), last(2), last(1), last(2))
-        return jax.jit(_verify_core, in_shardings=in_sh, out_shardings=last(1))
-    return jax.jit(_verify_core)
-
-
 ROWS_AUX = 25  # mlen row + 16 sig rows + 8 pk rows
 
 
@@ -169,14 +156,7 @@ def _pallas_flags(force_pallas=None) -> tuple:
     force_pallas=True additionally enables INTERPRET mode on non-TPU
     backends so a CPU mesh exercises the exact pallas-in-shard_map code
     path (dryrun_multichip does this); it is far too slow for general
-    CPU testing, hence opt-in. TM_TPU_FORCE_PALLAS=0/1 fills in the
-    DEFAULT only — an explicit force_pallas argument always wins, so a
-    caller that claims to validate the pallas path cannot be silently
-    rerouted by the environment."""
-    if force_pallas is None:
-        env = os.environ.get("TM_TPU_FORCE_PALLAS")
-        if env in ("0", "1"):
-            force_pallas = env == "1"
+    CPU testing, hence opt-in."""
     if force_pallas is None:
         return on_tpu(), False
     if not force_pallas:
@@ -210,36 +190,16 @@ def _put(buf, ndev: int):
     return jax.device_put(buf, NamedSharding(_dp_mesh(ndev), spec))
 
 
-def _donate_default() -> bool:
-    """Whether verify_batch donates the packed h2d buffer to the kernel
-    (steady-state verification then reuses device memory instead of
-    allocating per batch). Default: on for accelerators, off for the
-    CPU backend (XLA CPU can rarely alias the buffer and warns instead).
-    TM_TPU_DONATE=0/1 forces either way. Donated kernels are a separate
-    compile key: introspection/profiling callers that re-dispatch on a
-    resident device array keep the undonated variant (donate=False, the
-    _jitted_packed default)."""
-    env = os.environ.get("TM_TPU_DONATE")
-    if env in ("0", "1"):
-        return env == "1"
-    return jax.default_backend() != "cpu"
-
-
 def _jitted_packed(nb: int, mrows: int, bpad: int, ndev: int,
-                   force_pallas=None, donate: bool = False):
-    # resolve env/backend flags BEFORE the cache so flipping
-    # TM_TPU_FORCE_PALLAS between calls can't return a stale kernel path
+                   force_pallas=None):
+    # the flags are resolved before the cache: they are part of its key
     use_pallas, interp = _pallas_flags(force_pallas)
-    return _jitted_packed_impl(nb, mrows, bpad, ndev, use_pallas, interp,
-                               donate)
+    return _jitted_packed_impl(nb, mrows, bpad, ndev, use_pallas, interp)
 
 
 @lru_cache(maxsize=32)
 def _jitted_packed_impl(nb: int, mrows: int, bpad: int, ndev: int,
-                        use_pallas: bool, interp: bool,
-                        donate: bool = False):
-    donate_kw = {"donate_argnums": (0,)} if donate else {}
-
+                        use_pallas: bool, interp: bool):
     def body(buf):
         return _verify_packed_core(buf, nb=nb, mrows=mrows,
                                    use_pallas=use_pallas,
@@ -260,14 +220,13 @@ def _jitted_packed_impl(nb: int, mrows: int, bpad: int, ndev: int,
     def ed25519_verify_packed(buf):
         return body(buf)
 
-    fn = jax.jit(ed25519_verify_packed, **donate_kw)
+    fn = jax.jit(ed25519_verify_packed)
     if interp:
         # pallas interpret mode is a CPU-mesh dryrun path; its artifacts
         # are worthless cross-process and its lowering is the slow part
         return fn
     return kernel_cache.aot_wrap(
-        "ed25519_packed",
-        (nb, mrows, bpad, ndev, use_pallas, donate), fn)
+        "ed25519_packed", (nb, mrows, bpad, ndev, use_pallas), fn)
 
 
 @lru_cache(maxsize=1)
@@ -285,33 +244,11 @@ def _pack_le_rows(arr: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(packed.T).view(np.int32)
 
 
-# per-thread packed-buffer rings for the chunked dispatch: one ring per
-# (chunks, shape), so concurrent verify_batch callers (dispatch threads
-# + direct callers) never share host memory. Reuse is ACROSS calls only
-# — within a call every chunk packs its own slot, because device_put is
-# async and the host array must stay unmodified until the copy lands.
-_host_bufs = threading.local()
-
-
-def _host_buf_ring(chunks: int, shape) -> list:
-    key = (chunks, shape)
-    pool = getattr(_host_bufs, "pool", None)
-    if pool is None or pool[0] != key:
-        pool = (key, [np.zeros(shape, dtype=np.int32)
-                      for _ in range(chunks)])
-        _host_bufs.pool = pool
-    return pool[1]
-
-
-def pack_buffer(msgs, sig_arr: np.ndarray, pk_arr: np.ndarray, ndev: int = 1,
-                dims=None, out: np.ndarray | None = None):
+def pack_buffer(msgs, sig_arr: np.ndarray, pk_arr: np.ndarray, ndev: int = 1):
     """Build the single packed h2d buffer (see _verify_packed_core layout).
     Returns (buf (ROWS_AUX+mrows, bpad) int32, nb, mrows, bpad). The ONLY
-    place the layout is produced — bench/profiling code reuses it.
-    `dims=(nb, mrows, bpad)` forces the padded shape (chunked dispatch:
-    every chunk must share ONE jit key regardless of its own maxima).
-    `out` reuses a caller-held buffer of exactly that shape instead of
-    allocating (the chunked path ping-pongs two buffers)."""
+    place the layout and its shape are produced — verify_batch and the
+    profiling code both take them from here."""
     n = len(msgs)
     lens = np.fromiter((len(m) for m in msgs), dtype=np.int64, count=n)
     maxlen = int(lens.max()) if n else 0
@@ -325,17 +262,11 @@ def pack_buffer(msgs, sig_arr: np.ndarray, pk_arr: np.ndarray, ndev: int = 1,
     if ndev > 1:
         bpad = max(bpad, ndev)
         bpad = (bpad + ndev - 1) // ndev * ndev
-    if dims is not None:
-        nb, mrows, bpad = dims
 
     msg_mat = np.zeros((n, mrows * 4), dtype=np.uint8)
     pack.fill_msg_bytes(msg_mat, [bytes(m) for m in msgs], lens)
 
-    if out is not None and out.shape == (ROWS_AUX + mrows, bpad):
-        buf = out
-        buf.fill(0)
-    else:
-        buf = np.zeros((ROWS_AUX + mrows, bpad), dtype=np.int32)
+    buf = np.zeros((ROWS_AUX + mrows, bpad), dtype=np.int32)
     buf[0, :n] = lens
     buf[1:17, :n] = _pack_le_rows(sig_arr)
     buf[17:25, :n] = _pack_le_rows(pk_arr)
@@ -377,230 +308,30 @@ def _pack_well_formed(msgs, sigs, pks):
 def verify_batch(msgs, sigs, pks, devices: int | None = None):
     """Lists of (msg bytes, 64-byte sig, 32-byte pubkey) -> list[bool].
 
-    TM_TPU_VERIFY_CHUNKS=k (default 1) splits large batches into k
-    equal chunks dispatched back-to-back: chunk i+1's host->device
-    transfer overlaps chunk i's kernel, hiding min(transfer, compute)
-    per extra chunk on direct-attached TPU. All chunks share one jit
-    key (same padded shape), and chunking composes with multi-device
-    meshes (each chunk's bpad stays a multiple of ndev, so every chunk
-    shards cleanly). Only batches >= 2048 split — below that the extra
-    dispatch overhead outweighs the overlap. On accelerators the host
-    side packs into a per-thread RING of `chunks` buffers — distinct
-    per chunk within one call (device_put is async and PJRT only
-    requires the host buffer stay unmodified until the copy completes,
-    so a buffer is never repacked under an in-flight transfer) and
-    reused across back-to-back calls (this function returns only after
-    every mask materializes, which bounds every transfer) — and the
-    device buffer is DONATED to the kernel, so steady-state
-    verification reuses both host and device memory instead of
-    allocating per batch."""
+    The host side of a device batch as five spans under the jax
+    backend's crypto.batchVerify (README "Spans"): together they are
+    the batch's whole wall. device_put and the dispatch are async, so
+    verify.h2d and verify.launch are submissions and verify.wait is
+    the blocking read-back of the masks. verify.pack also holds the
+    length checks and the kernel lookup."""
     n = len(msgs)
     if n == 0:
         return []
     ndev = devices if devices is not None else len(jax.devices())
-    try:
-        chunks = int(os.environ.get("TM_TPU_VERIFY_CHUNKS", "1"))
-        chunk_min = int(os.environ.get("TM_TPU_VERIFY_CHUNK_MIN", "2048"))
-    except ValueError:
-        # a malformed env var must never take down verification
-        chunks, chunk_min = 1, 2048
-    if chunks < 2 or n < chunk_min:
-        chunks = 1
-    per = (n + chunks - 1) // chunks
-
-    # The host side of a device batch as five spans under the jax
-    # backend's crypto.batchVerify (README "Spans"): together they are
-    # the batch's whole wall. device_put and the dispatch are async, so
-    # verify.h2d and verify.launch are submissions and verify.wait is
-    # the blocking read-back of the masks. The first verify.pack also
-    # holds the length checks and the kernel lookup.
-    def pack_chunk(idx):
-        lo = idx * per
-        hi = min(lo + per, n)
-        buf, _, _, _ = pack_buffer(
-            msgs[lo:hi], sig_arr[lo:hi], pk_arr[lo:hi], ndev,
-            dims=(nb, mrows, bpad),
-            out=bufs[idx] if reuse_host else None)
-        return buf, hi - lo
-
-    with tracing.span("verify.pack", cat="crypto", n=n, chunk=0) as sp:
+    with tracing.span("verify.pack", cat="crypto", n=n) as sp:
         sig_arr, pk_arr, ok_host = _pack_well_formed(msgs, sigs, pks)
-        # one jit key for every chunk, derived from GLOBAL maxima: a chunk
-        # with its own (nb, mrows, bpad) would trigger a fresh multi-second
-        # compile inside the live path, which warmup() exists to prevent
-        maxlen = max((len(m) for m in msgs), default=0)
-        nb = (64 + maxlen + 17 + 127) // 128
-        mrows = max(16, ((maxlen + 3) // 4 + 15) // 16 * 16)
-        bpad = _bucket(per)
-        if ndev > 1:
-            bpad = max(bpad, ndev)
-            bpad = (bpad + ndev - 1) // ndev * ndev
-        fn = _jitted_packed(nb, mrows, bpad, ndev, donate=_donate_default())
-
-        # host-buffer reuse only where device_put copies out of the host
-        # array (accelerators); the CPU backend can alias numpy memory, and
-        # an aliased buffer must never be repacked under an in-flight kernel
-        reuse_host = chunks > 1 and jax.default_backend() != "cpu"
-        bufs = (_host_buf_ring(chunks, (ROWS_AUX + mrows, bpad))
-                if reuse_host else None)
+        buf, nb, mrows, bpad = pack_buffer(msgs, sig_arr, pk_arr, ndev)
+        fn = _jitted_packed(nb, mrows, bpad, ndev)
         shape = {"bucket": bpad, "nb": nb, "mrows": mrows}
         sp.set(**shape)
-        buf, cn = pack_chunk(0)
-
-    masks = []
-    for idx in range((n + per - 1) // per):
-        if idx:
-            with tracing.span("verify.pack", cat="crypto", n=n, chunk=idx,
-                              **shape):
-                buf, cn = pack_chunk(idx)
-        # device_put + dispatch are async: the NEXT chunk's pack and
-        # h2d transfer overlap this chunk's kernel (with chunks=1 this
-        # is the plain single-dispatch pipeline)
-        with tracing.span("verify.h2d", cat="crypto", n=cn, chunk=idx,
-                          **shape):
-            dev = _put(buf, ndev)
-        with tracing.span("verify.launch", cat="crypto", n=cn, chunk=idx,
-                          **shape):
-            masks.append((fn(dev), cn))
+    with tracing.span("verify.h2d", cat="crypto", n=n, **shape):
+        dev = _put(buf, ndev)
+    with tracing.span("verify.launch", cat="crypto", n=n, **shape):
+        mask = fn(dev)
     with tracing.span("verify.wait", cat="crypto", n=n, **shape):
-        host = [np.asarray(m)[:cn] for m, cn in masks]
+        host = np.asarray(mask)[:n]
     with tracing.span("verify.unpack", cat="crypto", n=n, **shape):
-        out = np.concatenate(host) & ok_host
-        return [bool(v) for v in out]
-
-
-# --- aggregate (random-linear-combination) verification --------------------
-
-
-def _rlc_core(words, nblocks, a_y, a_sign, r_y, r_sign, s_limbs, z_limbs,
-              group: int):
-    """Grouped RLC batch check: for each contiguous group g of `group`
-    items, verify Σ_i z_i·s_i · B == Σ_i [z_i]R_i + Σ_i [z_i·k_i]A_i
-    with host-supplied random z_i = 8·u_i (u_i random odd 128-bit). The
-    factor 8 makes the equation COFACTORED: every small-order (torsion)
-    component is annihilated by construction, so acceptance is
-    deterministic (never a coin-flip on torsion sums) and the prime-order
-    part is sound to 2^-128. One doubling chain per GROUP (shared by all
-    members) instead of one per signature — the fast path for valid-heavy
-    batches (fast-sync block commits, reference call site
-    blockchain/reactor.go:310). Returns (ok_pre (B,), ok_g (B//group,)).
-    Items with failed A/R decompress are excluded (their z is zeroed) and
-    reported in ok_pre."""
-    digest = sha512.sha512_batch(words, nblocks)
-    k = scalar.reduce_512(sha512.digest_to_scalar_limbs(digest))
-    a_pt, ok_a = curve.decompress(a_y, a_sign)
-    r_pt, ok_r = curve.decompress(r_y, r_sign)
-    ok_pre = ok_a & ok_r
-    z = jnp.where(ok_pre[None, :], z_limbs, 0)
-    zk = scalar.mul_mod_l(z, k)
-    zs = scalar.mul_mod_l(z, s_limbs)
-    s_g = scalar.sum_mod_l_groups(zs, group)
-    bdim = a_y.shape[-1]
-    zk_win = curve._windows_msb_first(zk, bdim)
-    z_win = curve._windows_msb_first(z, bdim, nbits=132)  # 8*u: 131 bits
-    t_g = curve.msm_groups(r_pt, z_win, a_pt, zk_win, group)
-    rhs = curve.fixed_base_mul(s_g)
-    diff = curve.add_points(t_g, curve.negate(rhs))
-    return ok_pre, curve.is_identity(diff)
-
-
-@lru_cache(maxsize=16)
-def _jitted_rlc(nb: int, bpad: int, group: int):
-    return kernel_cache.aot_wrap(
-        "ed25519_rlc", (nb, bpad, group),
-        jax.jit(partial(_rlc_core, group=group)))
-
-
-def verify_batch_rlc(msgs, sigs, pks, group: int = 64,
-                     devices: int | None = None):
-    """Aggregate (random-linear-combination) batch verification with a
-    COFACTORED group equation (z_i = 8·u_i; ZIP-215 / ed25519-dalek
-    verify_batch style). Groups whose equation holds are accepted;
-    failed groups fall back to the per-item kernel, so ordinary forgeries
-    (prime-order defects), corrupted signatures, wrong keys, malformed
-    inputs, high-S and non-canonical-R encodings all produce exactly the
-    per-item masks (non-canonical R is pre-rejected host-side because Go
-    compares encode(R') against the RAW R bytes).
-
-    KNOWN, DELIBERATE divergence from the per-item path: a signature
-    whose defect is PURE small-order torsion — R' = R + T with T in the
-    8-torsion subgroup, s computed against H(R'||A||M) — satisfies the
-    cofactored equation but fails Go's cofactorless byte compare. No
-    batch equation can match cofactorless single verification on these
-    (Chalkias et al., "Taming the many EdDSAs"); making them pass
-    deterministically (rather than with probability ~1/8 on torsion-sum
-    cancellation) is the safer, standardized choice. Because of this
-    divergence the consensus-critical paths (verify_commit and friends)
-    use ONLY the per-item kernel; this mode is for throughput-bound,
-    non-consensus batch checks."""
-    import secrets as _secrets
-
-    n = len(msgs)
-    if n == 0:
-        return []
-    sig_arr, pk_arr, ok_host = _pack_well_formed(msgs, sigs, pks)
-    # Go's verify compares encode(R') against the RAW R bytes: a
-    # non-canonical R (y >= p) can never match the canonical encode.
-    # The RLC equation tests point equality, so weed those out up front.
-    r_masked = sig_arr[:, :32].copy()
-    r_masked[:, 31] &= 0x7F
-    ok_host = ok_host & pack.lt_const_le_batch(r_masked, _ref_P())
-
-    r_y, r_sign, s_limbs, _ = pack.split_signatures(sig_arr)
-    a_y, a_sign = pack.split_pubkeys(pk_arr)
-    prefixes = np.concatenate([sig_arr[:, :32], pk_arr], axis=1)
-    words, nblocks = pack.sha512_pad_batch(prefixes, [bytes(m) for m in msgs])
-
-    bpad = _bucket(n)
-    group = min(group, bpad)
-
-    # z_i = 8·u_i with u_i random odd 128-bit: the odd u keeps z nonzero
-    # mod L, the 8 makes the group equation cofactored (see docstring)
-    u_bytes = np.frombuffer(_secrets.token_bytes(16 * n), np.uint8
-                            ).reshape(n, 16).copy()
-    u_bytes[:, 0] |= 1
-    z_bytes = np.zeros((n, 17), dtype=np.uint8)  # u << 3, little-endian
-    z_bytes[:, :16] = u_bytes << 3
-    z_bytes[:, 1:] |= u_bytes >> 5
-    z_limbs = pack.bytes_to_limbs_batch(z_bytes)
-    z_limbs[:, ~ok_host] = 0  # excluded items must not contribute
-
-    def padb(a):
-        padw = [(0, 0)] * (a.ndim - 1) + [(0, bpad - n)]
-        return np.pad(a, padw)
-
-    fn = _jitted_rlc(words.shape[0], bpad, group)
-    ok_pre, ok_g = fn(
-        jnp.asarray(padb(words)), jnp.asarray(padb(nblocks)),
-        jnp.asarray(padb(a_y)), jnp.asarray(padb(a_sign)),
-        jnp.asarray(padb(r_y)), jnp.asarray(padb(r_sign)),
-        jnp.asarray(padb(s_limbs)), jnp.asarray(padb(z_limbs)),
-    )
-    ok_pre = np.asarray(ok_pre)[:n] & ok_host
-    ok_g = np.asarray(ok_g)
-
-    out = np.zeros(n, dtype=bool)
-    retry = []
-    for i in range(n):
-        if not ok_pre[i]:
-            continue  # definitively invalid (malformed/non-canonical/decompress)
-        if ok_g[i // group]:
-            out[i] = True
-        else:
-            retry.append(i)
-    if retry:
-        sub = verify_batch([msgs[i] for i in retry], [sigs[i] for i in retry],
-                           [pks[i] for i in retry], devices=devices)
-        for i, ok in zip(retry, sub):
-            out[i] = ok
-    return [bool(v) for v in out]
-
-
-@lru_cache(maxsize=1)
-def _ref_P() -> int:
-    from . import ref
-
-    return ref.P
+        return [bool(v) for v in host & ok_host]
 
 
 def make_sharded_commit_step(mesh, force_pallas=None):
@@ -652,9 +383,7 @@ def tallied_power(lo, hi) -> int:
 
 
 def _sharded_commit_fn(ndev: int, force_pallas=None):
-    # resolve flags BEFORE the cache (same staleness fix as
-    # _jitted_packed): flipping TM_TPU_FORCE_PALLAS must not return a
-    # kernel compiled for the previous setting
+    # the flags are resolved before the cache: they are part of its key
     use_pallas, interp = _pallas_flags(force_pallas)
     return _sharded_commit_fn_impl(ndev, use_pallas, interp)
 
@@ -735,13 +464,12 @@ def warmup(buckets=(8, 16, 64), nb: int = 2, mrows: int = 32,
     attached. Returns the calibrated cutoff, or None."""
     ndev = devices if devices is not None else len(jax.devices())
     small_fn, small_shape = None, None
-    donate = _donate_default()  # warm the variant the live path runs
     for b in buckets:
         bpad = _bucket(b)
         if ndev > 1:
             bpad = max(bpad, ndev)
             bpad = (bpad + ndev - 1) // ndev * ndev
-        fn = _jitted_packed(nb, mrows, bpad, ndev, donate=donate)
+        fn = _jitted_packed(nb, mrows, bpad, ndev)
         fn(_put(np.zeros((ROWS_AUX + mrows, bpad), dtype=np.int32), ndev))
         if small_fn is None or bpad < small_shape[1]:
             small_fn, small_shape = fn, (ROWS_AUX + mrows, bpad)
@@ -773,9 +501,7 @@ def _calibrate_batch_min(fn, shape, ndev: int = 1) -> int:
     ts = []
     for _ in range(3):
         # put INSIDE the timed region (and fresh per rep): the live
-        # path pays the transfer every batch, and a donated kernel
-        # consumes its input buffer — re-dispatching a resident
-        # array is exactly what donation forbids
+        # path pays the transfer every batch
         t0 = time.perf_counter()
         d = _put(np.zeros(shape, dtype=np.int32), ndev)
         np.asarray(fn(d))

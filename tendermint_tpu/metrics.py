@@ -100,9 +100,6 @@ class CryptoMetrics:
     compile_seconds: object = NOP
     compile_cache_hits: object = NOP
     compile_cache_misses: object = NOP
-    # cross-height verify scheduler (crypto/batch.py): verify_async
-    # calls that were merged into another caller's dispatch
-    coalesced_calls: object = NOP
     # ValidatorSet.hash() calls, labeled result=memo|computed: a Merkle
     # walk of the whole committee against a 32-byte read (types/
     # validator_set.py reports through this process-wide sink like
@@ -728,10 +725,6 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             f"{ns}_crypto_compile_cache_misses_total",
             "Kernel signatures that missed the AOT artifact store and "
             "paid a fresh XLA compile."),
-        coalesced_calls=r.counter(
-            f"{ns}_crypto_coalesced_calls_total",
-            "verify_async calls merged into another caller's dispatch "
-            "by the cross-height coalescing scheduler."),
         valset_hash=r.counter(
             f"{ns}_types_valset_hash_total",
             "ValidatorSet.hash() calls, by result: memo (the remembered "

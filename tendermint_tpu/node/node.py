@@ -183,8 +183,6 @@ class Node:
         crypto_batch.configure(
             async_dispatch=config.crypto.async_dispatch,
             sig_cache_size=config.crypto.sig_cache_size,
-            coalesce_window_ms=config.crypto.coalesce_window_ms,
-            coalesce_max_batch=config.crypto.coalesce_max_batch,
         )
         self._installed_sig_cache = crypto_batch.get_sig_cache()
         # which verifier this node got: filled in by the warm-up thread
@@ -1079,13 +1077,11 @@ class Node:
         fused_kernel, warm-up outcome, the adaptive cutoff in force),
         compile-once layer state (cache dir, AOT hit/miss counters, any
         compile in progress — a node wedged compiling at boot shows up
-        here), plus the coalescing scheduler config and live async-batch
-        count."""
+        here), plus the live async-batch count."""
         from ..crypto import batch as crypto_batch
         from ..crypto import kernel_cache
 
         out = kernel_cache.status()
-        out["coalesce"] = crypto_batch.coalesce_status()
         out["inflight_batches"] = crypto_batch.inflight_count()
         out["verifier"] = dict(
             self._verifier,

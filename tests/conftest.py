@@ -28,7 +28,6 @@ import pytest
 # a short grace join before the assert so shutdown races don't flake.
 _THREAD_FAMILIES = (
     "crypto-dispatch",    # per-backend verify dispatchers
-    "crypto-coalesce",    # cross-height coalescing scheduler
     "mempool-ingest",     # batched CheckTx ingest worker
     "ws-writer",          # per-client websocket writer (PR-9 fan-out)
     "rpc-cache-inval",    # RPC response-cache invalidation drainer
@@ -83,7 +82,6 @@ def _thread_hygiene():
     from tendermint_tpu.crypto import batch as crypto_batch
     from tendermint_tpu.libs import lockdep
 
-    crypto_batch.set_coalesce(window_ms=0)
     crypto_batch.shutdown_dispatchers()
     crypto_batch.set_sig_cache(None)
     crypto_batch.set_async_enabled(True)

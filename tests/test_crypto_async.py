@@ -154,6 +154,20 @@ class TestDispatcherLifecycle:
         fut = d.submit(bv.verify)  # stopped dispatcher object directly
         assert fut.result(timeout=5) == [True]
 
+    def test_config_of_an_older_node_still_loads(self):
+        """A config.toml written by an older node may name [crypto] keys
+        that have since gone (PR 31 took two): it loads, those keys are
+        skipped, the keys beside them are read."""
+        from tendermint_tpu.config import Config
+
+        c = Config.from_toml(
+            "[crypto]\nasync_dispatch = false\na_key_that_went = 5.0\n"
+            "sig_cache_size = 7\n")
+        assert c.crypto.async_dispatch is False
+        assert c.crypto.sig_cache_size == 7
+        assert not hasattr(c.crypto, "a_key_that_went")
+        assert "a_key_that_went" not in c.to_toml()
+
     def test_node_stop_shuts_down_dispatch_threads(self, tmp_path):
         """Node.stop must leave no crypto-dispatch threads behind (the
         clean-shutdown guarantee the conftest teardown enforces for

@@ -1,5 +1,6 @@
 """JAX Ed25519 engine vs pure-python reference vs OpenSSL."""
 
+import math
 import secrets
 
 import numpy as np
@@ -117,7 +118,7 @@ def test_verify_program_is_named_for_the_profiler(batch):
 
     from tendermint_tpu.crypto.jaxed25519 import verify as V
 
-    fn = V._jitted_packed(3, 80, 16, 1, donate=False)
+    fn = V._jitted_packed(3, 80, 16, 1)
     assert fn.jitted.__name__ == "ed25519_verify_packed"
     text = fn.jitted.lower(jax.ShapeDtypeStruct(
         (V.ROWS_AUX + 80, 16), jnp.int32)).as_text(debug_info=True)
@@ -141,25 +142,147 @@ def test_jax_verify_multidevice(batch):
     assert got == want
 
 
-def test_chunked_composes_with_multidevice(batch, monkeypatch):
-    """PR 8: chunking is no longer forced off on multi-device meshes —
-    every chunk's bpad stays a multiple of ndev so each shards cleanly,
-    and the masks match the single-dispatch mesh path exactly. Same
-    padded dims as test_jax_verify_multidevice, so no extra compile."""
-    import jax
-
+def test_device_batch_records_five_spans_once(batch):
+    """One verify_batch call is five spans, once each and in order,
+    every one with the batch's n and shape: the benchmark's readers and
+    README "Spans" read them by these names and args. A malformed row
+    rides along (the host mask must stay aligned with the device's).
+    The shape is the one test_jax_verify_batch compiled."""
     from tendermint_tpu.crypto.jaxed25519.verify import verify_batch
+    from tendermint_tpu.libs import tracing
 
-    ndev = len(jax.devices())
     msgs = [m for m, _, _, _ in batch]
     sigs = [s for _, s, _, _ in batch]
     pks = [p for _, _, p, _ in batch]
-    want = verify_batch(msgs, sigs, pks, devices=ndev)
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNKS", "2")
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNK_MIN", "4")
-    got = verify_batch(msgs, sigs, pks, devices=ndev)
+    want = [e for _, _, _, e in batch]
+    sigs[1], want[1] = sigs[1][:10], False
+    tracer = tracing.get_tracer()
+    was_on = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        got = verify_batch(msgs, sigs, pks, devices=1)
+        spans = [e for e in tracer.events() if e.name.startswith("verify.")]
+    finally:
+        if not was_on:
+            tracer.disable()
     assert got == want
-    assert got == [e for _, _, _, e in batch]
+    spans.sort(key=lambda e: e.start_ns)
+    assert [e.name for e in spans] == [
+        "verify.pack", "verify.h2d", "verify.launch", "verify.wait",
+        "verify.unpack"]
+    for e in spans:
+        assert e.cat == "crypto"
+        assert e.args == {"n": len(batch), "bucket": 16, "nb": 3,
+                          "mrows": 80}, e.name
+    for a, b in zip(spans, spans[1:]):
+        assert a.end_ns <= b.start_ns
+
+
+# --- the host side of a device batch: layout, buckets, input checks --------
+
+
+def _le_words(b: bytes) -> list:
+    return [int.from_bytes(b[k:k + 4], "little", signed=True)
+            for k in range(0, len(b), 4)]
+
+
+def _pack_one_by_one(msgs, sigs, pks, ndev):
+    """The packed buffer written out item by item, as
+    verify._verify_packed_core's docstring gives the layout: what
+    pack_buffer, however it is vectorised, has to produce."""
+    n = len(msgs)
+    maxlen = max(len(m) for m in msgs)
+    # SHA-512 blocks of R || A || M, its 0x80 and its 16-byte length
+    nb = math.ceil((64 + maxlen + 1 + 16) / 128)
+    # message rows: 4 bytes a row, in steps of 16 rows, at least 16
+    mrows = max(16, math.ceil(math.ceil(maxlen / 4) / 16) * 16)
+    if n <= 8:
+        bpad = 8
+    elif n <= 512:
+        bpad = 1 << (n - 1).bit_length()  # the next power of two
+    else:
+        bpad = math.ceil(n / 512) * 512
+    bpad = math.ceil(bpad / ndev) * ndev
+    buf = np.zeros((25 + mrows, bpad), dtype=np.int32)
+    for i, (m, s, p) in enumerate(zip(msgs, sigs, pks)):
+        buf[0, i] = len(m)
+        buf[1:17, i] = _le_words(s)
+        buf[17:25, i] = _le_words(p)
+        words = _le_words(m + b"\x00" * (mrows * 4 - len(m)))
+        buf[25:, i] = words
+    return buf, (nb, mrows, bpad)
+
+
+@pytest.mark.parametrize("lens,ndev", [
+    ([0], 1),
+    ([1], 1),
+    ([63] * 3, 1),
+    ([64] * 3, 1),
+    ([65] * 3, 1),
+    ([110] * 5, 1),
+    ([128] * 9, 1),
+    ([250] * 2, 1),
+    ([0, 1, 63, 64, 65, 110, 128, 250], 1),
+    ([110, 0, 65, 128, 1, 250, 64, 63, 110], 2),
+    ([97] * 5, 3),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else f"ndev{v}")
+def test_pack_buffer_layout(lens, ndev):
+    from tendermint_tpu.crypto.jaxed25519 import verify as V
+
+    rng = np.random.default_rng(len(lens) * 1000 + sum(lens))
+    msgs = [rng.bytes(k) for k in lens]
+    sigs = [rng.bytes(64) for _ in lens]
+    pks = [rng.bytes(32) for _ in lens]
+    sig_arr = np.frombuffer(b"".join(sigs), np.uint8).reshape(-1, 64)
+    pk_arr = np.frombuffer(b"".join(pks), np.uint8).reshape(-1, 32)
+    want, want_shape = _pack_one_by_one(msgs, sigs, pks, ndev)
+    buf, nb, mrows, bpad = V.pack_buffer(msgs, sig_arr, pk_arr, ndev)
+    assert (nb, mrows, bpad) == want_shape
+    assert buf.dtype == np.int32 and buf.shape == (V.ROWS_AUX + mrows, bpad)
+    np.testing.assert_array_equal(buf, want)
+
+
+@pytest.mark.parametrize("n,bucket", [
+    (1, 8), (8, 8), (9, 16), (512, 512), (513, 1024), (10000, 10240)])
+def test_bucket_boundaries(n, bucket):
+    from tendermint_tpu.crypto.jaxed25519 import verify as V
+
+    assert V._bucket(n) == bucket
+
+
+@pytest.mark.parametrize("fault", [
+    "short signature", "long key", "S >= L", "all well formed"])
+def test_pack_well_formed_rows(fault):
+    """Malformed rows are zeroed and masked, the arrays keep their
+    shape; a non-canonical S is masked on the host and left in place."""
+    from tendermint_tpu.crypto.jaxed25519 import verify as V
+
+    items = []
+    for i in range(4):
+        sk, pk = _keypair()
+        msg = b"row-%d" % i
+        items.append([msg, sk.sign(msg), pk])
+    bad = 2
+    if fault == "short signature":
+        items[bad][1] = items[bad][1][:63]
+    elif fault == "long key":
+        items[bad][2] = items[bad][2] + b"\x00"
+    elif fault == "S >= L":
+        items[bad][1] = items[bad][1][:32] + ref.L.to_bytes(32, "little")
+    msgs, sigs, pks = (list(c) for c in zip(*items))
+    sig_arr, pk_arr, ok = V._pack_well_formed(msgs, sigs, pks)
+    assert sig_arr.shape == (4, 64) and sig_arr.dtype == np.uint8
+    assert pk_arr.shape == (4, 32) and pk_arr.dtype == np.uint8
+    assert ok.dtype == bool
+    assert ok.tolist() == [fault == "all well formed" or i != bad
+                           for i in range(4)]
+    for i in range(4):
+        if i == bad and fault in ("short signature", "long key"):
+            assert not sig_arr[i].any() and not pk_arr[i].any()
+        else:
+            assert sig_arr[i].tobytes() == sigs[i]
+            assert pk_arr[i].tobytes() == pks[i]
 
 
 @pytest.mark.slow  # pallas interpret mode: ~60s on CPU-only hosts (same
@@ -225,73 +348,6 @@ def test_pallas_verify_tail_matches_xla(batch):
         jnp.asarray(r_sign), jnp.asarray(s_limbs), k, interpret=True,
     )
     assert np.array_equal(np.asarray(want), np.asarray(got))
-
-
-@pytest.mark.slow  # fresh XLA compile: minutes on CPU-only hosts
-def test_rlc_aggregate_exact_masks():
-    """verify_batch_rlc (random-linear-combination aggregate mode) must
-    return exactly the same masks as the per-item path on an adversarial
-    mixed batch: corrupted sigs, wrong msg, bad pk, malformed, high-S,
-    non-canonical R, plus valid items — with group fallback resolving
-    failed groups per-item."""
-    from tendermint_tpu.crypto.jaxed25519 import ref as R
-    from tendermint_tpu.crypto.jaxed25519.verify import (
-        verify_batch,
-        verify_batch_rlc,
-    )
-
-    items = []
-    for i in range(12):
-        sk, pk = _keypair()
-        msg = secrets.token_bytes(60 + i)
-        items.append((msg, sk.sign(msg), pk))
-    sk, pk = _keypair()
-    msg = b"bad"
-    sig = sk.sign(msg)
-    items.append((msg, bytes([sig[0] ^ 1]) + sig[1:], pk))
-    items.append((b"other", sig, pk))
-    items.append((msg, sig, b"\x07" * 32))
-    items.append((msg, b"\x00" * 30, pk))
-    s = int.from_bytes(sig[32:], "little")
-    if s + R.L < 2**256:
-        items.append((msg, sig[:32] + (s + R.L).to_bytes(32, "little"), pk))
-    # non-canonical R: y' = y + p still < 2^255 only if y < 2^255 - p = 19
-    # — craft instead by setting R to p (y=p ≡ 0 mod p, non-canonical)
-    bad_r = (R.P).to_bytes(32, "little")
-    items.append((msg, bad_r + sig[32:], pk))
-
-    msgs = [m for m, _, _ in items]
-    sigs = [s_ for _, s_, _ in items]
-    pks = [p for _, _, p in items]
-    want = verify_batch(msgs, sigs, pks, devices=1)
-    got = verify_batch_rlc(msgs, sigs, pks, group=8, devices=1)
-    assert got == want
-    assert sum(want) == 12  # the 12 honest items
-
-
-@pytest.mark.slow  # fresh XLA compile: minutes on CPU-only hosts
-def test_rlc_all_valid_no_fallback(monkeypatch):
-    """On an all-valid batch every group passes the aggregate equation —
-    the per-item fallback must not run."""
-    from tendermint_tpu.crypto.jaxed25519 import verify as V
-
-    # 18 items lands in the same (nb=2, bpad=32, group=8) jit key as
-    # test_rlc_aggregate_exact_masks — one shared compile per session
-    items = []
-    for i in range(18):
-        sk, pk = _keypair()
-        msg = secrets.token_bytes(60 + i)
-        items.append((msg, sk.sign(msg), pk))
-    msgs = [m for m, _, _ in items]
-    sigs = [s for _, s, _ in items]
-    pks = [p for _, _, p in items]
-
-    def boom(*a, **kw):
-        raise AssertionError("fallback ran on an all-valid batch")
-
-    monkeypatch.setattr(V, "verify_batch", boom)
-    got = V.verify_batch_rlc(msgs, sigs, pks, group=8, devices=1)
-    assert got == [True] * 18
 
 
 @pytest.mark.slow  # fresh XLA compile: minutes on CPU-only hosts
@@ -402,146 +458,3 @@ def test_batch_verifier_interface(batch):
     want = [e for _, _, _, e in batch[:5]]
     assert bv.verify() == want
     assert bv.verify_all() == all(want)
-
-
-@pytest.mark.slow  # ~160s on CPU-only hosts: compiles BOTH the rlc and
-# per-item kernels to pin one documented edge-case divergence
-def test_rlc_is_cofactored_torsion_divergence_pinned():
-    """verify_batch_rlc uses the COFACTORED group equation (z = 8u).
-    This test pins the one documented divergence from the per-item
-    (Go byte-compare) path: a signature whose defect is pure 8-torsion
-    (R' = R + T, s computed against H(R'||A||M)) fails per-item verify
-    but passes the cofactored batch equation deterministically. No batch
-    equation can match cofactorless single verification on such inputs
-    (Chalkias et al.); anything with a prime-order defect must still
-    match the per-item masks exactly (checked here too)."""
-    import hashlib
-
-    from tendermint_tpu.crypto.jaxed25519 import ref as R
-    from tendermint_tpu.crypto.jaxed25519.verify import (
-        verify_batch,
-        verify_batch_rlc,
-    )
-
-    # find a small-order (torsion) point T != identity: [L]P for an
-    # arbitrary decompressable point P kills the prime-order component
-    T = None
-    for y in range(2, 200):
-        pt = R.decompress(y.to_bytes(32, "little"))
-        if pt is None:
-            continue
-        cand = R.scalar_mult(R.L, pt)
-        if not R.equal(cand, R.scalar_mult(0, pt)):  # not identity
-            T = cand
-            break
-    assert T is not None, "no torsion point found"
-    assert R.equal(R.scalar_mult(8, T), R.scalar_mult(0, T))  # order | 8
-
-    # craft the torsion-defect signature
-    a = 0x5DEB3C55C3425C44E57C46E5288AD9D655D7B26A5EA3BE1251A55D6E5BD95A77 % R.L
-    A_pt = R.scalar_mult(a, R.base_point())
-    A = R.compress(A_pt)
-    msg = b"torsion-defect"
-    r = 0x1F19E27C0C3B4A85D7F4C2E8A1B35D9F17A3C5E7091B3D5F7A9BCDEF01234567 % R.L
-    R0 = R.scalar_mult(r, R.base_point())
-    r_bytes = R.compress(R.add(R0, T))
-    k = int.from_bytes(hashlib.sha512(r_bytes + A + msg).digest(),
-                       "little") % R.L
-    s = (r + k * a) % R.L
-    sig = r_bytes + s.to_bytes(32, "little")
-
-    # sanity: defect is pure torsion — cofactorless reject
-    assert not R.verify(A, msg, sig)
-
-    # group 1 (items 0-7): the torsion sig + 7 valid — its group must
-    # PASS the cofactored equation. group 2 (items 8-15): an ordinary
-    # prime-order forgery + 7 valid — its group must FAIL and fall back.
-    items = [(msg, sig, A, "torsion")]
-    for i in range(7):
-        sk, pk = _keypair()
-        m = secrets.token_bytes(80 + i)
-        items.append((m, sk.sign(m), pk, True))
-    sk, pk = _keypair()
-    m = b"ordinary-forgery"
-    bad = sk.sign(m)
-    items.append((m, bytes([bad[0] ^ 4]) + bad[1:], pk, False))
-    for i in range(7):
-        sk, pk = _keypair()
-        m = secrets.token_bytes(90 + i)
-        items.append((m, sk.sign(m), pk, True))
-
-    msgs = [m for m, _, _, _ in items]
-    sigs = [s_ for _, s_, _, _ in items]
-    pks = [p for _, _, p, _ in items]
-
-    per_item = verify_batch(msgs, sigs, pks, devices=1)
-    assert per_item[0] is False  # Go semantics reject the torsion sig
-    assert per_item[1:8] == [True] * 7
-    assert per_item[8] is False
-    assert per_item[9:] == [True] * 7
-
-    got = verify_batch_rlc(msgs, sigs, pks, group=8, devices=1)
-    # the ONLY divergence: the torsion item is accepted (cofactored);
-    # every prime-order defect still matches per-item exactly
-    assert got[0] is True, "cofactored equation must accept pure torsion"
-    assert got[1:] == per_item[1:]
-
-
-def test_chunked_verify_matches_single_dispatch(monkeypatch):
-    """TM_TPU_VERIFY_CHUNKS pipelines transfers against kernels; the
-    masks must be identical to the single-dispatch path, including
-    chunk-boundary alignment of the host-side canonicity bits."""
-    from tendermint_tpu.crypto.jaxed25519 import verify as V
-
-    items = []
-    for i in range(24):
-        sk, pk = _keypair()
-        m = secrets.token_bytes(70 + i)
-        s = sk.sign(m)
-        if i % 6 == 1:
-            s = bytes([s[0] ^ 1]) + s[1:]
-        if i == 13:
-            s = b"\x00" * 10  # malformed: ok_host must stay aligned
-        items.append((m, s, pk))
-    msgs = [m for m, _, _ in items]
-    sigs = [s for _, s, _ in items]
-    pks = [p for _, _, p in items]
-
-    want = V.verify_batch(msgs, sigs, pks, devices=1)
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNKS", "3")
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNK_MIN", "8")
-    got = V.verify_batch(msgs, sigs, pks, devices=1)
-    assert got == want
-    assert sum(want) == 20  # invalid: i in {1,7,13,19} (13 also malformed)
-
-
-@pytest.mark.slow  # fresh XLA compile: donate=True is its own kernel key
-def test_donated_dispatch_matches_undonated(monkeypatch):
-    """PR 8 donated-buffer dispatch: with TM_TPU_DONATE=1 the packed
-    h2d buffer is donated to the kernel (steady-state device-memory
-    reuse); verdicts must be identical to the undonated path, across
-    repeat dispatches of the same shape (a donated buffer must never be
-    reused by the host after dispatch)."""
-    from tendermint_tpu.crypto.jaxed25519 import verify as V
-
-    items = []
-    for i in range(12):
-        sk, pk = _keypair()
-        m = secrets.token_bytes(80)
-        s = sk.sign(m)
-        if i % 4 == 2:
-            s = bytes([s[0] ^ 1]) + s[1:]
-        items.append((m, s, pk))
-    msgs = [m for m, _, _ in items]
-    sigs = [s for _, s, _ in items]
-    pks = [p for _, _, p in items]
-
-    monkeypatch.setenv("TM_TPU_DONATE", "0")
-    want = V.verify_batch(msgs, sigs, pks, devices=1)
-    monkeypatch.setenv("TM_TPU_DONATE", "1")
-    for _ in range(3):  # steady state: repeated donated dispatches
-        assert V.verify_batch(msgs, sigs, pks, devices=1) == want
-    # chunked + donated: ping-pong host buffers over a donated kernel
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNKS", "2")
-    monkeypatch.setenv("TM_TPU_VERIFY_CHUNK_MIN", "4")
-    assert V.verify_batch(msgs, sigs, pks, devices=1) == want

@@ -1,5 +1,4 @@
-"""Compile-once kernel layer (crypto/kernel_cache.py) and the
-cross-height coalescing verify scheduler (crypto/batch.py).
+"""Compile-once kernel layer (crypto/kernel_cache.py).
 
 The kernel-cache tests drive the AOT artifact store with TINY jitted
 kernels (millisecond compiles) so integrity properties — corrupted
@@ -8,8 +7,6 @@ corrupt an entry, cached ≡ fresh results — run in tier-1 time. The
 real verify kernels route through exactly the same aot_wrap layer
 (tests/test_jax_ed25519.py exercises them end to end, warm via the
 conftest session cache).
-
-Coalescer tests run on the cpu backend: no jax, no compile cost.
 """
 
 import os
@@ -20,9 +17,7 @@ os.environ.setdefault("TM_TPU_CRYPTO_BACKEND", "cpu")
 import numpy as np
 import pytest
 
-from tendermint_tpu.crypto import batch as crypto_batch
 from tendermint_tpu.crypto import kernel_cache
-from tendermint_tpu.crypto.keys import PrivKeyEd25519
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
@@ -270,24 +265,28 @@ class TestAOTStore:
         s = kernel_cache.stats()
         assert s["hits"] == 1 and s["compiles"] == 0
 
-    def test_donated_equals_undonated(self, cache_dir):
-        """donate_argnums is a compile-key dimension, not a semantics
-        one: the donated executable computes identical results."""
-        base = lambda x: (x * 7 + 5) % 11  # noqa: E731
-        plain = kernel_cache.aot_wrap("t_undonated", (), jax.jit(base))
-        donated = kernel_cache.aot_wrap(
-            "t_donated", (), jax.jit(base, donate_argnums=(0,)))
-        x = np.arange(32, dtype=np.int32)
-        want = np.asarray(plain(x))
-        got = np.asarray(donated(np.arange(32, dtype=np.int32)))
-        np.testing.assert_array_equal(want, got)
-
     def test_status_bundle_shape(self, cache_dir):
         fn = _tiny_kernel()
         fn(np.arange(4, dtype=np.int32))
         st = kernel_cache.status()
         assert st["enabled"] and st["dir"] == cache_dir
         assert st["compiles"] == 1 and st["compiling"] == {}
+
+    def test_wrapper_cache_weakly_held(self, cache_dir):
+        """An aot_wrap dropped by its caller (lru_cache eviction) must
+        free its executables — the registry holds them weakly."""
+        import gc
+
+        fn = _tiny_kernel()
+        fn(np.arange(4, dtype=np.int32))
+        live_before = sum(1 for r in kernel_cache._wrapper_caches
+                          if r() is not None)
+        del fn
+        gc.collect()
+        kernel_cache.clear_memory()  # also prunes dead refs
+        live_after = sum(1 for r in kernel_cache._wrapper_caches
+                         if r() is not None)
+        assert live_after < live_before
 
 
 class TestCacheRule:
@@ -331,222 +330,10 @@ class TestCacheRule:
             assert ".jax_cache/" in f.read().split()
 
 
-def _triple(i=0, valid=True):
-    sk = PrivKeyEd25519.gen_from_secret(b"coal-%d" % i)
-    msg = b"cmsg-%d" % i
-    sig = sk.sign(msg)
-    if not valid:
-        sig = bytes([sig[0] ^ 1]) + sig[1:]
-    return (msg, sig, sk.pub_key().bytes())
-
-
-@pytest.fixture
-def coalesce_window():
-    crypto_batch.set_coalesce(window_ms=25, max_batch=8192)
-    yield
-    crypto_batch.set_coalesce(window_ms=0, max_batch=8192)
-    crypto_batch.shutdown_dispatchers()
-
-
-class TestCoalescer:
-    def test_coalesced_equals_sequential(self, coalesce_window):
-        """Property: merged dispatch returns exactly the per-caller
-        masks sequential dispatch would — mixed validity, mixed sizes,
-        add order preserved."""
-        batches = [
-            [_triple(10 * k + j, valid=((j + k) % 3 != 0))
-             for j in range(k + 1)]
-            for k in range(6)
-        ]
-        wants = [crypto_batch.batch_verify(b, backend="cpu")
-                 for b in batches]
-        futs = []
-        for b in batches:
-            bv = crypto_batch.CPUBatchVerifier()
-            for t in b:
-                bv.add(*t)
-            futs.append(bv.verify_async())
-        got = [f.result(timeout=30) for f in futs]
-        assert got == wants
-
-    def test_callers_actually_merged(self, coalesce_window):
-        """Submissions inside one window produce ONE backend dispatch
-        (observed via a counting subclass), not one per caller."""
-        calls = []
-
-        class Counting(crypto_batch.CPUBatchVerifier):
-            def _verify(self):
-                calls.append(len(self._items))
-                return super()._verify()
-
-        futs = []
-        for k in range(4):
-            bv = Counting()
-            for t in [_triple(100 + 10 * k + j) for j in range(3)]:
-                bv.add(*t)
-            futs.append(bv.verify_async())
-        for f in futs:
-            assert f.result(timeout=30) == [True] * 3
-        assert sum(calls) == 12
-        assert len(calls) < 4, f"expected merged dispatches, got {calls}"
-
-    def test_distinct_instance_keys_do_not_merge(self, coalesce_window):
-        """A merged batch runs entirely on the FIRST caller's verifier
-        instance, so verifiers carrying different per-instance dispatch
-        policy (_coalesce_key — e.g. AdaptiveBatchVerifier's
-        factory/threshold) must never share a dispatch."""
-        calls = []
-
-        class Keyed(crypto_batch.CPUBatchVerifier):
-            def __init__(self, key):
-                super().__init__()
-                self._key = key
-
-            def _coalesce_key(self):
-                return (self._key,)
-
-            def _verify(self):
-                calls.append((self._key, len(self._items)))
-                return super()._verify()
-
-        futs = []
-        for k in range(4):
-            bv = Keyed(k % 2)
-            for t in [_triple(400 + 10 * k + j) for j in range(2)]:
-                bv.add(*t)
-            futs.append(bv.verify_async())
-        for f in futs:
-            assert f.result(timeout=30) == [True, True]
-        # every dispatch carries exactly one policy key, and each key's
-        # four items were verified under ITS instances — a cross-key
-        # merge would count one key's items under the other's policy
-        for key in (0, 1):
-            assert sum(n for k, n in calls if k == key) == 4, calls
-
-    def test_exception_fans_out_and_thread_survives(self, coalesce_window):
-        class Exploding(crypto_batch.CPUBatchVerifier):
-            def _verify(self):
-                raise RuntimeError("backend boom")
-
-        futs = []
-        for k in range(3):
-            bv = Exploding()
-            bv.add(*_triple(200 + k))
-            futs.append(bv.verify_async())
-        for f in futs:
-            with pytest.raises(RuntimeError, match="backend boom"):
-                f.result(timeout=30)
-        # the scheduler thread survives and serves later batches
-        bv = crypto_batch.CPUBatchVerifier()
-        bv.add(*_triple(250))
-        assert bv.verify_async().result(timeout=30) == [True]
-        assert crypto_batch.inflight_count() == 0
-
-    def test_max_batch_splits_oversize_groups(self):
-        crypto_batch.set_coalesce(window_ms=25, max_batch=4)
-        try:
-            futs = []
-            for k in range(3):
-                bv = crypto_batch.CPUBatchVerifier()
-                for t in [_triple(300 + 10 * k + j) for j in range(3)]:
-                    bv.add(*t)
-                futs.append(bv.verify_async())
-            assert all(f.result(timeout=30) == [True] * 3 for f in futs)
-        finally:
-            crypto_batch.set_coalesce(window_ms=0, max_batch=8192)
-            crypto_batch.shutdown_dispatchers()
-
-    def test_window_off_means_no_scheduler(self):
-        crypto_batch.set_coalesce(window_ms=0)
-        bv = crypto_batch.CPUBatchVerifier()
-        bv.add(*_triple(400))
-        assert bv.verify_async().result(timeout=30) == [True]
-        assert not [t for t in threading.enumerate()
-                    if t.name.startswith("crypto-coalesce")]
-
-    def test_empty_verifier_skips_coalescer(self, coalesce_window):
-        bv = crypto_batch.CPUBatchVerifier()
-        assert bv.verify_async().result(timeout=30) == []
-
-    def test_shutdown_resolves_pending(self):
-        """stop() drains: futures submitted right before shutdown still
-        resolve (the invariant the dispatcher path already guarantees)."""
-        crypto_batch.set_coalesce(window_ms=500, max_batch=8192)
-        try:
-            bv = crypto_batch.CPUBatchVerifier()
-            bv.add(*_triple(500))
-            fut = bv.verify_async()  # parked in the 500ms window
-            crypto_batch.shutdown_dispatchers()
-            assert fut.result(timeout=10) == [True]
-        finally:
-            crypto_batch.set_coalesce(window_ms=0)
-
-    def test_coalesced_calls_metric(self, coalesce_window):
-        from tendermint_tpu.metrics import prometheus_metrics
-
-        ms = prometheus_metrics("tm")
-        crypto_batch.set_metrics(ms.crypto)
-        try:
-            futs = []
-            for k in range(3):
-                bv = crypto_batch.CPUBatchVerifier()
-                bv.add(*_triple(600 + k))
-                futs.append(bv.verify_async())
-            for f in futs:
-                f.result(timeout=30)
-            body = ms.registry.render()
-            assert "tm_crypto_coalesced_calls_total" in body
-        finally:
-            crypto_batch.set_metrics(None)
-
-    def test_config_plumbs_coalesce_knobs(self):
-        crypto_batch.configure(coalesce_window_ms=7.5,
-                               coalesce_max_batch=123)
-        try:
-            st = crypto_batch.coalesce_status()
-            assert st["window_ms"] == 7.5 and st["max_batch"] == 123
-        finally:
-            crypto_batch.set_coalesce(window_ms=0, max_batch=8192)
-
-
-class TestHostBufRing:
-    def test_ring_distinct_within_reused_across(self):
-        """The chunked dispatch's host ring: every chunk of one call
-        gets its OWN buffer (no repack under an in-flight async
-        transfer), and back-to-back calls with the same (chunks, shape)
-        reuse the same memory; a shape change swaps the pool."""
-        from tendermint_tpu.crypto.jaxed25519 import verify as V
-
-        a = V._host_buf_ring(3, (57, 64))
-        assert len(a) == 3
-        assert len({id(b) for b in a}) == 3  # distinct per chunk
-        assert all(b.shape == (57, 64) and b.dtype == np.int32 for b in a)
-        b = V._host_buf_ring(3, (57, 64))
-        assert [id(x) for x in a] == [id(x) for x in b]  # cross-call reuse
-        c = V._host_buf_ring(2, (57, 128))
-        assert len(c) == 2 and c[0].shape == (57, 128)
-
-    def test_wrapper_cache_weakly_held(self, cache_dir):
-        """An aot_wrap dropped by its caller (lru_cache eviction) must
-        free its executables — the registry holds them weakly."""
-        import gc
-
-        fn = _tiny_kernel()
-        fn(np.arange(4, dtype=np.int32))
-        live_before = sum(1 for r in kernel_cache._wrapper_caches
-                          if r() is not None)
-        del fn
-        gc.collect()
-        kernel_cache.clear_memory()  # also prunes dead refs
-        live_after = sum(1 for r in kernel_cache._wrapper_caches
-                         if r() is not None)
-        assert live_after < live_before
-
-
 class TestObservability:
     def test_node_crypto_status_bundle(self, cache_dir):
         """The /debug/crypto provider bundle: kernel-cache state +
-        coalescer config + inflight count, JSON-serializable."""
+        inflight count, JSON-serializable."""
         import json
 
         from tendermint_tpu.node.node import Node
@@ -557,7 +344,7 @@ class TestObservability:
         out = Node._crypto_status(_Stub)
         json.dumps(out)
         assert out["dir"] == cache_dir and out["enabled"]
-        assert "compiling" in out and "coalesce" in out
+        assert "compiling" in out
         assert out["kernels"] == [] and out["inflight_batches"] == 0
         assert out["verifier"]["backend"] == "cpu"
         assert out["verifier"]["batch_cutoff"] >= 1
