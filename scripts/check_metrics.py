@@ -334,6 +334,8 @@ REQUIRED_FAMILIES = (
     # counts from the second block on, handed_down under fast sync only)
     "types_valset_hash_total",
     "state_last_commit_check_total",
+    # PR-32 the frozen heap (0 until the verify warm-up has ended)
+    "runtime_gc_frozen_objects",
 )
 
 # ...and of those, the hot-path families that must have RECORDED samples

@@ -33,7 +33,9 @@ always-available spans (README "Spans" lists the names):
   spans (queue waits, `runtime.gc`) exist in the recorder only.
 - Names the process's stalls: while enabled, a `gc.callbacks` hook
   records `runtime.gc` for every full collection and any other that
-  takes over a millisecond.
+  takes over a millisecond. What those collections walk is kept small
+  by `hold_frozen_heap()` (below the recorder), recorded as
+  `runtime.gcFreeze`.
 
 Export is Chrome trace event format ("X" complete events, µs units),
 loadable in chrome://tracing or https://ui.perfetto.dev, served from
@@ -532,6 +534,82 @@ _GLOBAL = Tracer()
 def get_tracer() -> Tracer:
     """The process-global tracer (disabled until a Node enables it)."""
     return _GLOBAL
+
+
+# --- the frozen heap -----------------------------------------------------
+#
+# What a process holds once it has started — modules, jax, the loaded
+# kernels — lives as long as the process, and a full collection walks
+# all of it every time (65-94 ms a collection on a joiner catching up,
+# PERF.md section 5). gc.freeze() moves it to the permanent generation,
+# which no collection walks. The switch is the interpreter's, not a
+# node's, and several nodes run in one process (tests, scenario tools),
+# so holds are counted here: the last release unfreezes.
+
+_heap_lock = threading.Lock()
+_heap_holds = 0
+
+
+def hold_frozen_heap() -> int:
+    """Collects once (so no garbage cycle is frozen in), then freezes
+    everything alive; returns gc.get_freeze_count(). Every hold collects
+    and freezes again, so what a later node built joins the permanent
+    generation too. Thresholds stay the interpreter's and no collection
+    is skipped: what is allocated afterwards is collected as before.
+    One `runtime.gcFreeze` span a hold. A cycle made of frozen objects
+    that dies later is kept until the last release_frozen_heap(); a
+    process that never releases keeps its start-up cycles until exit,
+    as any long-running node does."""
+    global _heap_holds
+    t0 = time.perf_counter_ns()
+    with _heap_lock:
+        collected = gc.collect()
+        gc.freeze()
+        frozen = gc.get_freeze_count()
+        _heap_holds += 1
+    _GLOBAL.record("runtime.gcFreeze", t0, time.perf_counter_ns(), "runtime",
+                   frozen=frozen, collected=collected)
+    return frozen
+
+
+def release_frozen_heap() -> None:
+    """Gives back one hold_frozen_heap(); the last one unfreezes (the
+    permanent generation rejoins the oldest, and is collected again)."""
+    global _heap_holds
+    with _heap_lock:
+        _heap_holds -= 1
+        if _heap_holds == 0:
+            gc.unfreeze()
+
+
+class FrozenHeap:
+    """One owner's hold on the frozen heap, for one start of it: take()
+    when the start-up is over, on whatever thread ends it; drop() when
+    the owner stops, which gives back only what take() took. After
+    drop() a take() that comes late takes nothing. `publish(n)` is
+    called under the hold's own lock with the objects frozen (0 at
+    drop()), so what the owner shows never lags the hold."""
+
+    def __init__(self, publish):
+        self._lock = threading.Lock()
+        self._publish = publish
+        self._held = False
+        self._dropped = False
+
+    def take(self) -> None:
+        with self._lock:
+            if self._held or self._dropped:
+                return
+            self._held = True
+            self._publish(hold_frozen_heap())
+
+    def drop(self) -> None:
+        with self._lock:
+            self._dropped = True
+            if self._held:
+                self._held = False
+                release_frozen_heap()
+                self._publish(0)
 
 
 def span(name: str, cat: str = "", **args):

@@ -413,6 +413,16 @@ class ReplicaMetrics:
 
 
 @dataclass
+class RuntimeMetrics:
+    """The interpreter under the node (ours; libs/tracing.py "the
+    frozen heap")."""
+
+    # objects in the collector's permanent generation after this node's
+    # gc.freeze() at the end of its start; 0 before it and after stop()
+    gc_frozen_objects: object = NOP
+
+
+@dataclass
 class NodeMetrics:
     consensus: ConsensusMetrics = field(default_factory=ConsensusMetrics)
     p2p: P2PMetrics = field(default_factory=P2PMetrics)
@@ -429,6 +439,7 @@ class NodeMetrics:
     incident: IncidentMetrics = field(default_factory=IncidentMetrics)
     handel: HandelMetrics = field(default_factory=HandelMetrics)
     replica: ReplicaMetrics = field(default_factory=ReplicaMetrics)
+    runtime: RuntimeMetrics = field(default_factory=RuntimeMetrics)
     registry: Optional[Registry] = None
 
 
@@ -875,8 +886,16 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             "Tip age: best fleet tip this replica can see minus its "
             "own store height."),
     )
+    runtime = RuntimeMetrics(
+        gc_frozen_objects=r.gauge(
+            f"{ns}_runtime_gc_frozen_objects",
+            "Objects the node's start left in the collector's permanent "
+            "generation (gc.freeze), which no collection walks; 0 until "
+            "the verify warm-up has ended and after stop()."),
+    )
     return NodeMetrics(consensus=cons, p2p=p2p, abci=abci_m, mempool=mem,
                        state=state, crypto=crypto, statesync=statesync,
                        rpc=rpc, lockdep=lockdep, recovery=recovery,
                        determinism=determinism, incident=incident,
-                       handel=handel, replica=replica, registry=r)
+                       handel=handel, replica=replica, runtime=runtime,
+                       registry=r)

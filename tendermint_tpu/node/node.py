@@ -190,8 +190,13 @@ class Node:
         self._verifier = {
             "backend": crypto_batch.default_backend_name(),
             "platform": None, "device_kind": None, "device_count": 0,
-            "fused_kernel": None, "warmup": "pending",
+            "fused_kernel": None, "warmup": "pending", "gc_frozen": 0,
         }
+        # this start's hold on the frozen heap (libs/tracing.FrozenHeap):
+        # taken by the warm-up thread once the services are up, dropped
+        # by stop() or by a start() that fails
+        self._heap: Optional[tracing.FrozenHeap] = None
+        self._services_up = threading.Event()
         self._enabled_tracing = False
         if config.instrumentation.tracing:
             tracer = tracing.get_tracer()
@@ -724,6 +729,25 @@ class Node:
     def start(self) -> None:
         self._running = True
         self._stopped.clear()
+        from ..libs import tracing
+
+        # set when the services below are up, or have failed: the
+        # warm-up thread freezes the heap no earlier
+        self._services_up = up = threading.Event()
+        self._heap = tracing.FrozenHeap(self._publish_frozen)
+        try:
+            self._start_services()
+        except BaseException:
+            self._heap.drop()
+            raise
+        finally:
+            up.set()
+
+    def _publish_frozen(self, frozen: int) -> None:
+        self._verifier["gc_frozen"] = frozen
+        self.metrics.runtime.gc_frozen_objects.set(frozen)
+
+    def _start_services(self) -> None:
         # dirty-boot marker: exists for exactly the running lifetime of
         # the node; a boot that finds one knows the previous run never
         # reached its clean stop() (see the incident block in __init__)
@@ -899,17 +923,28 @@ class Node:
         live vote path; a warm-up that fails — backend init, a shape the
         compiler refuses — is an ERROR here, not a surprise in the first
         live batch. The host OpenSSL backend ("cpu") never touches jax.
-        TM_TPU_WARMUP=0 skips the compile, not the report."""
+        TM_TPU_WARMUP=0 skips the compile, not the report. Whatever the
+        outcome, the heap as the start left it — the services start()
+        built, jax, the kernels loaded here — is then collected once and
+        frozen (libs/tracing "the frozen heap"), before `warmup` says
+        so: whoever waits for the warm-up's end starts on a frozen
+        heap."""
         from ..crypto import batch as crypto_batch
 
         info = self._verifier
+        up, heap = self._services_up, self._heap
 
         def _go():
+            outcome = _warm()
+            up.wait()
+            heap.take()
+            info["warmup"] = outcome
+
+        def _warm() -> str:
             if info["backend"] == "cpu":
-                info["warmup"] = "disabled"
                 LOG.info("crypto verifier: backend=cpu (host OpenSSL); "
                          "no device initialised")
-                return
+                return "disabled"
             try:
                 import jax
 
@@ -928,21 +963,20 @@ class Node:
                     info["platform"], info["device_kind"],
                     info["device_count"], info["fused_kernel"])
                 if os.environ.get("TM_TPU_WARMUP", "1") == "0":
-                    info["warmup"] = "disabled"
-                    return
+                    return "disabled"
                 env = os.environ.get("TM_TPU_WARMUP_BUCKETS")
                 buckets = (tuple(int(x) for x in env.split(",") if x)
                            if env else (8, 16, 64))
                 jv.warmup(buckets=buckets)
-                info["warmup"] = "ok"
                 LOG.info("verify warm-up ok: buckets=%s, adaptive batch "
                          "cutoff %d", list(buckets),
                          crypto_batch.effective_batch_min())
+                return "ok"
             except Exception as e:  # noqa: BLE001 - thread boundary
-                info["warmup"] = f"error: {type(e).__name__}: {e}"
                 LOG.exception("verify warm-up FAILED with the %s backend: "
                               "the first live batch will meet the same "
                               "error", info["backend"])
+                return f"error: {type(e).__name__}: {e}"
 
         t = threading.Thread(target=_go, name="verify-warmup", daemon=True)
         t.start()
@@ -1146,6 +1180,8 @@ class Node:
         if (self._installed_sig_cache is not None
                 and crypto_batch.get_sig_cache() is self._installed_sig_cache):
             crypto_batch.set_sig_cache(None)
+        if self._heap is not None:
+            self._heap.drop()
         if self._enabled_tracing:
             from ..libs import tracing
 
