@@ -192,6 +192,7 @@ REQUIRED_FAMILIES = (
     "consensus_step_duration_seconds",
     "crypto_batch_verify_seconds",
     "crypto_batch_size",
+    "crypto_batch_lanes_per_device",
     "crypto_signatures_verified_total",
     # PR-2 async/cache families (declaration only: a node that commits
     # blocks without duplicate gossip may legitimately record no hits)

@@ -70,6 +70,27 @@ def get_metrics():
     return _metrics
 
 
+# the largest number of chips one device batch has been cut over in
+# this process (/debug/crypto `verifier.devices_used`, beside the
+# `device_count` the backend sees)
+_devices_used = 0
+
+
+def note_device_batch(lanes: int, ndev: int) -> None:
+    """One device batch of `lanes` padded lanes shared by `ndev` chips,
+    reported by the device backend once it has chosen both."""
+    global _devices_used
+    if ndev > _devices_used:
+        _devices_used = ndev
+    m = _metrics
+    if m is not None:
+        m.batch_lanes_per_device.with_labels(str(ndev)).observe(lanes // ndev)
+
+
+def devices_used() -> int:
+    return _devices_used
+
+
 # --- process-wide [crypto] configuration (sig cache + async flag) ------
 #
 # Like the metrics sink above, these are process-global so every call
@@ -298,6 +319,9 @@ class BatchVerifier:
     # how the batch got to this verifier: "direct", or the adaptive
     # router's decision ("device" | "cpu") on the verifier it built
     _route = "direct"
+    # chips the last _verify() cut its batch over: a device backend
+    # sets it, and crypto.batchVerify then carries it as `ndev`
+    ndev = None
 
     def __init__(self):
         self._items: List[Triple] = []
@@ -382,6 +406,8 @@ class BatchVerifier:
                               int(fut._t_submit * 1e9), sp.start_ns,
                               "crypto", backend=self.BACKEND, n=n)
             mask = self._verify()
+            if self.ndev is not None:
+                sp.set(ndev=self.ndev)
         if m is not None:
             m.batch_verify_seconds.with_labels(self.BACKEND).observe(sp.seconds)
             m.batch_size.with_labels(self.BACKEND).observe(n)

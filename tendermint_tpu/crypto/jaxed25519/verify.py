@@ -9,10 +9,10 @@ Replaces the reference's serial verify loop (types/validator_set.go:345-371
 
 Per-item validity masks come back — mixed valid/invalid batches are
 first-class (no all-or-nothing batch equation). With more than one device
-visible the batch shards across a 1-D "dp" mesh via shard_map; signatures
-are the batch dimension, so the commit of a 10k-validator set simply
-spreads over the pod with no cross-device traffic except the final
-all-gather of masks.
+visible a batch of 512 lanes or more (batch_devices) shards across a 1-D
+"dp" mesh via shard_map; signatures are the batch dimension, so the commit
+of a 10k-validator set simply spreads over the host's chips with no
+cross-device traffic: each chip's part of the mask is read back.
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ import numpy as np
 
 from ...libs import tracing
 from .. import kernel_cache
-from ..batch import BatchVerifier
+from ..batch import BatchVerifier, get_sig_cache, note_device_batch
 from . import curve, pack, pallas_kernels, scalar, sha512
 
 # compile-once layer (crypto/kernel_cache): persistent XLA compilation
@@ -226,7 +226,7 @@ def _jitted_packed_impl(nb: int, mrows: int, bpad: int, ndev: int,
         # are worthless cross-process and its lowering is the slow part
         return fn
     return kernel_cache.aot_wrap(
-        "ed25519_packed", (nb, mrows, bpad, ndev, use_pallas), fn)
+        "ed25519_packed", (nb, mrows, bpad, ndev, use_pallas), fn, ndev=ndev)
 
 
 @lru_cache(maxsize=1)
@@ -258,10 +258,7 @@ def pack_buffer(msgs, sig_arr: np.ndarray, pk_arr: np.ndarray, ndev: int = 1):
     # warmup() pre-builds — a fresh mrows key would stall the live path
     mrows = max(16, ((maxlen + 3) // 4 + 15) // 16 * 16)
 
-    bpad = _bucket(n)
-    if ndev > 1:
-        bpad = max(bpad, ndev)
-        bpad = (bpad + ndev - 1) // ndev * ndev
+    bpad = _padded_bucket(n, ndev)
 
     msg_mat = np.zeros((n, mrows * 4), dtype=np.uint8)
     pack.fill_msg_bytes(msg_mat, [bytes(m) for m in msgs], lens)
@@ -280,6 +277,31 @@ def _bucket(n: int) -> int:
     if n <= 512:
         return 1 << (n - 1).bit_length()
     return (n + 511) // 512 * 512
+
+
+# A batch under this many lanes stays on one chip. One chip against
+# four of a v5e host, the call's wall by bucket (benchmark/tools/
+# chips_table.py, PERF.md §6, PR 33): 2.63 against 3.18 ms at 8 lanes and
+# 2.66 against 3.28 at 64 (four shard puts and four launches for 0.84 ms
+# of kernel), then 4.76 against 3.78 at 512, 12.6 against 7.1 at 2,048,
+# 53.2 against 23.7 at 10,240.
+MULTI_CHIP_MIN_LANES = 512
+
+
+def batch_devices(n: int) -> int:
+    """How many of the visible chips a batch of n signatures is cut
+    over: all of them from MULTI_CHIP_MIN_LANES lanes up, else one. The
+    one rule for verify_batch, the funnel's span and warmup(); on a
+    one-chip host it is 1 whatever n."""
+    ndev = len(jax.devices())
+    return ndev if ndev > 1 and _bucket(n) >= MULTI_CHIP_MIN_LANES else 1
+
+
+def _padded_bucket(n: int, ndev: int) -> int:
+    """The bucket of n lanes, padded to a multiple of the chips that
+    share it (every chip gets the same number of lanes)."""
+    bpad = max(_bucket(n), ndev)
+    return (bpad + ndev - 1) // ndev * ndev
 
 
 def _pack_well_formed(msgs, sigs, pks):
@@ -307,6 +329,7 @@ def _pack_well_formed(msgs, sigs, pks):
 
 def verify_batch(msgs, sigs, pks, devices: int | None = None):
     """Lists of (msg bytes, 64-byte sig, 32-byte pubkey) -> list[bool].
+    `devices` chips share the batch; left out, batch_devices(n) decides.
 
     The host side of a device batch as five spans under the jax
     backend's crypto.batchVerify (README "Spans"): together they are
@@ -317,13 +340,14 @@ def verify_batch(msgs, sigs, pks, devices: int | None = None):
     n = len(msgs)
     if n == 0:
         return []
-    ndev = devices if devices is not None else len(jax.devices())
+    ndev = devices if devices is not None else batch_devices(n)
     with tracing.span("verify.pack", cat="crypto", n=n) as sp:
         sig_arr, pk_arr, ok_host = _pack_well_formed(msgs, sigs, pks)
         buf, nb, mrows, bpad = pack_buffer(msgs, sig_arr, pk_arr, ndev)
         fn = _jitted_packed(nb, mrows, bpad, ndev)
-        shape = {"bucket": bpad, "nb": nb, "mrows": mrows}
+        shape = {"bucket": bpad, "nb": nb, "mrows": mrows, "ndev": ndev}
         sp.set(**shape)
+    note_device_batch(bpad, ndev)
     with tracing.span("verify.h2d", cat="crypto", n=n, **shape):
         dev = _put(buf, ndev)
     with tracing.span("verify.launch", cat="crypto", n=n, **shape):
@@ -396,7 +420,7 @@ def _sharded_commit_fn_impl(ndev: int, use_pallas: bool, interp: bool):
     if interp:
         return step  # CPU-mesh dryrun: artifacts are worthless cross-run
     return kernel_cache.aot_wrap(
-        "ed25519_commit_step", (ndev, use_pallas), step)
+        "ed25519_commit_step", (ndev, use_pallas), step, ndev=ndev)
 
 
 def sharded_commit_verify(msgs, sigs, pks, powers, for_block,
@@ -422,8 +446,7 @@ def sharded_commit_verify(msgs, sigs, pks, powers, for_block,
     prefixes = np.concatenate([sig_arr[:, :32], pk_arr], axis=1)
     words, nblocks = pack.sha512_pad_batch(prefixes, [bytes(m) for m in msgs])
 
-    bpad = max(_bucket(n), ndev)
-    bpad = (bpad + ndev - 1) // ndev * ndev
+    bpad = _padded_bucket(n, ndev)
 
     def padb(a, fill=0):  # pad batch-last axis to bpad
         padw = [(0, 0)] * (a.ndim - 1) + [(0, bpad - n)]
@@ -462,30 +485,32 @@ def warmup(buckets=(8, 16, 64), nb: int = 2, mrows: int = 32,
     (crypto.batch.set_calibrated_batch_min) — the device is then only
     chosen where it wins on the latency of the hardware actually
     attached. Returns the calibrated cutoff, or None."""
-    ndev = devices if devices is not None else len(jax.devices())
-    small_fn, small_shape = None, None
+    # the psum commit step is reached only round the funnel's cache
+    # (ValidatorSet._run_batch_verify: a synchronous verify_commit with
+    # no sig cache installed), and then over every chip; a node that
+    # cannot take that way does not compile it (109.5 s cold at 10,240
+    # lanes on four chips, PERF.md)
+    all_dev = devices if devices is not None else len(jax.devices())
+    commit_step = all_dev > 1 and get_sig_cache() is None
+    small = None  # (bucket, fn, shape, ndev) of the smallest shape
     for b in buckets:
-        bpad = _bucket(b)
-        if ndev > 1:
-            bpad = max(bpad, ndev)
-            bpad = (bpad + ndev - 1) // ndev * ndev
+        ndev = devices if devices is not None else batch_devices(b)
+        bpad = _padded_bucket(b, ndev)
         fn = _jitted_packed(nb, mrows, bpad, ndev)
         fn(_put(np.zeros((ROWS_AUX + mrows, bpad), dtype=np.int32), ndev))
-        if small_fn is None or bpad < small_shape[1]:
-            small_fn, small_shape = fn, (ROWS_AUX + mrows, bpad)
-        if ndev > 1:
-            # the multi-device commit path routes through the shard_map
-            # psum step (sharded_commit_verify) — compile it too, or the
-            # first live verify_commit pays the compile
-            step = _sharded_commit_fn(ndev)
+        if small is None or bpad < small[0]:
+            small = (bpad, fn, (ROWS_AUX + mrows, bpad), ndev)
+        if commit_step:
+            bpad = _padded_bucket(b, all_dev)
+            step = _sharded_commit_fn(all_dev)
             z20 = np.zeros((20, bpad), np.int32)
             zrow = np.zeros((bpad,), np.int32)
-            step(*(_put(a, ndev) for a in (
+            step(*(_put(a, all_dev) for a in (
                 np.zeros((nb, 16, 2, bpad), np.uint32), zrow + 1, z20, zrow,
                 z20, zrow, z20, zrow, zrow)))
-    if (calibrate and small_fn is not None
+    if (calibrate and small is not None
             and os.environ.get("TM_TPU_CALIBRATE", "1") != "0"):
-        return _calibrate_batch_min(small_fn, small_shape, ndev)
+        return _calibrate_batch_min(*small[1:])
     return None
 
 
@@ -542,4 +567,5 @@ class JAXBatchVerifier(BatchVerifier):
         msgs = [m for m, _, _ in self._items]
         sigs = [s for _, s, _ in self._items]
         pks = [p for _, _, p in self._items]
-        return verify_batch(msgs, sigs, pks)
+        self.ndev = batch_devices(len(msgs))
+        return verify_batch(msgs, sigs, pks, devices=self.ndev)

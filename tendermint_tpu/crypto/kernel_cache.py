@@ -84,6 +84,23 @@ _ready_log: list = []
 _wrapper_caches: list = []
 
 
+# Set on the compiling thread when jax served a compile from its own
+# persistent cache (its monitoring event; jax calls listeners on the
+# thread that compiles). An XLA:CPU executable loaded that way
+# serializes without its kernels' functions: the artifact loads in the
+# next process and fails at its first run ("Function ... not found"),
+# so such an executable is used and not stored. TPU executables
+# serialize whole either way.
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_from_jax_cache = threading.local()
+_listening = False  # the listener is registered once a process
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if event == _JAX_CACHE_HIT:
+        _from_jax_cache.seen = True
+
+
 class _WrapperCache(dict):
     """A dict that supports weak references (plain dicts don't)."""
 
@@ -108,7 +125,7 @@ def ensure_configured() -> Optional[str]:
     live at DEFAULT_CACHE_DIR and jax is pointed there. Safe before or
     after backend init. An uncreatable directory turns the store off
     (WARNING) — kernels then compile per process."""
-    global _dir, _unusable
+    global _dir, _unusable, _listening
     with _lock:
         if _dir is not None or _unusable:
             return _dir
@@ -124,7 +141,11 @@ def ensure_configured() -> Optional[str]:
         _dir = resolved
         _prune_tempfiles(resolved)
         import jax
+        import jax.monitoring
 
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
         if not env:
             jax.config.update("jax_compilation_cache_dir", resolved)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
@@ -328,6 +349,7 @@ def _timed_compile(kernel: str, jitted, args):
         _compile_seq += 1
         token = _compile_seq
         _compiling[token] = (kernel, t0)
+    _from_jax_cache.seen = False
     try:
         compiled = jitted.lower(*args).compile()
     finally:
@@ -341,11 +363,14 @@ def _timed_compile(kernel: str, jitted, args):
     return compiled
 
 
-def load_or_compile(kernel: str, static_key: tuple, jitted, args):
+def load_or_compile(kernel: str, static_key: tuple, jitted, args,
+                    ndev: int = 1):
     """One kernel instance: AOT-load from disk if a matching artifact
     exists, else lower+compile from `args` (concrete arrays or
     jax.ShapeDtypeStruct) and write the artifact back. A compile error
-    propagates: it is the kernel's, not the cache's."""
+    propagates: it is the kernel's, not the cache's. `ndev`, the chips
+    the program runs across, is carried by the kernel.load and
+    kernel.compile spans."""
     ensure_configured()
     m = _metrics()
     key = _full_key(kernel, static_key, args)
@@ -354,7 +379,7 @@ def load_or_compile(kernel: str, static_key: tuple, jitted, args):
     t0 = time.perf_counter()
     if path is not None:
         with tracing.span("kernel.load", cat="crypto", kernel=kernel,
-                          key=span_key) as sp:
+                          key=span_key, ndev=ndev) as sp:
             compiled = _try_load(kernel, key, path)
             sp.set(hit=compiled is not None)
         if compiled is not None:
@@ -367,12 +392,18 @@ def load_or_compile(kernel: str, static_key: tuple, jitted, args):
         if m is not None:
             m.compile_cache_misses.inc()
     with tracing.span("kernel.compile", cat="crypto", kernel=kernel,
-                      key=span_key):
+                      key=span_key, ndev=ndev):
         compiled = _timed_compile(kernel, jitted, args)
     _note_ready(kernel, static_key, args, t0, "compiled")
-    if path is not None:
+    if path is not None and not (_from_jax_cache.seen and _on_cpu()):
         _try_store(kernel, key, path, compiled)
     return compiled
+
+
+def _on_cpu() -> bool:
+    import jax
+
+    return jax.devices()[0].platform == "cpu"
 
 
 def _note_ready(kernel: str, static_key: tuple, args, t0: float,
@@ -386,7 +417,8 @@ def _note_ready(kernel: str, static_key: tuple, args, t0: float,
         _ready_log.append(rec)
 
 
-def aot_wrap(kernel: str, static_key: tuple, jitted) -> Callable:
+def aot_wrap(kernel: str, static_key: tuple, jitted,
+             ndev: int = 1) -> Callable:
     """Wrap a jitted function with the compile-once layer: the first
     call for each argument-shape signature loads the stored executable
     (or compiles and stores it); later calls dispatch the executable
@@ -404,7 +436,8 @@ def aot_wrap(kernel: str, static_key: tuple, jitted) -> Callable:
             with lock:
                 fn = cache.get(k)
                 if fn is None:
-                    fn = load_or_compile(kernel, static_key, jitted, args)
+                    fn = load_or_compile(kernel, static_key, jitted, args,
+                                         ndev)
                     cache[k] = fn
         return fn(*args)
 

@@ -74,6 +74,9 @@ class CryptoMetrics:
     batch_verify_seconds: object = NOP
     # signatures per verify() call, labeled like batch_verify_seconds
     batch_size: object = NOP
+    # padded lanes each chip got of one device batch, labeled by the
+    # number of chips that shared it (crypto/jaxed25519 verify_batch)
+    batch_lanes_per_device: object = NOP
     signatures_verified: object = NOP
     signatures_invalid: object = NOP
     # adaptive router choices, labeled route=cpu|device
@@ -687,6 +690,12 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             ("backend",),
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024,
                      4096)),
+        batch_lanes_per_device=r.histogram(
+            f"{ns}_crypto_batch_lanes_per_device",
+            "Padded lanes a chip of one device batch: the bucket over "
+            "the chips that shared it, by their number.",
+            ("ndev",),
+            buckets=(2, 8, 32, 128, 512, 2048, 8192)),
         signatures_verified=r.counter(
             f"{ns}_crypto_signatures_verified_total",
             "Signatures that verified valid."),

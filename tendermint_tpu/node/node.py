@@ -1107,8 +1107,9 @@ class Node:
 
     def _crypto_status(self) -> dict:
         """The /debug/crypto bundle: which verifier the node resolved at
-        start-up (backend, platform, device_kind, device_count,
-        fused_kernel, warm-up outcome, the adaptive cutoff in force),
+        start-up (backend, platform, device_kind, device_count, the most
+        chips a device batch has been cut over, fused_kernel, warm-up
+        outcome, the adaptive cutoff in force),
         compile-once layer state (cache dir, AOT hit/miss counters, any
         compile in progress — a node wedged compiling at boot shows up
         here), plus the live async-batch count."""
@@ -1119,6 +1120,7 @@ class Node:
         out["inflight_batches"] = crypto_batch.inflight_count()
         out["verifier"] = dict(
             self._verifier,
+            devices_used=crypto_batch.devices_used(),
             batch_cutoff=crypto_batch.effective_batch_min())
         return out
 

@@ -174,7 +174,7 @@ def test_device_batch_records_five_spans_once(batch):
     for e in spans:
         assert e.cat == "crypto"
         assert e.args == {"n": len(batch), "bucket": 16, "nb": 3,
-                          "mrows": 80}, e.name
+                          "mrows": 80, "ndev": 1}, e.name
     for a, b in zip(spans, spans[1:]):
         assert a.end_ns <= b.start_ns
 

@@ -76,6 +76,38 @@ class TestAOTStore:
         assert s["hits"] == 1
         np.testing.assert_array_equal(fresh, warm)
 
+    def test_a_compile_jax_served_from_its_own_cache_is_not_stored(self, cache_dir):
+        """On the CPU an executable that jax loaded from its persistent
+        cache serializes without its kernels' functions: stored, it
+        would load in the next process and fail at its first run. It is
+        used and not stored; a compile jax really made is stored."""
+        import jax.monitoring
+
+        class _ServedFromJaxCache:
+            """A jitted function whose compile jax reports as a hit."""
+
+            def __init__(self, jitted):
+                self.jitted = jitted
+
+            def lower(self, *args):
+                return self
+
+            def compile(self):
+                jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+                return self.jitted.lower(np.arange(8, dtype=np.int32)).compile()
+
+        x = np.arange(8, dtype=np.int32)
+        jitted = jax.jit(lambda x: x * 5 + 1)
+        served = kernel_cache.aot_wrap("test_served_from_cache", (5,),
+                                       _ServedFromJaxCache(jitted))
+        np.testing.assert_array_equal(np.asarray(served(x)), x * 5 + 1)
+        assert kernel_cache.stats()["compiles"] == 1
+        assert _artifacts(cache_dir) == []
+        # the flag is the compile's own: the next one, made, is stored
+        made = kernel_cache.aot_wrap("test_really_compiled", (5,), jitted)
+        np.testing.assert_array_equal(np.asarray(made(x)), x * 5 + 1)
+        assert len(_artifacts(cache_dir)) == 1
+
     def test_crashed_writer_tempfiles_pruned(self, cache_dir):
         """Resolving the store GCs day-old crashed-writer tempfiles in
         aot/; live artifacts survive and still warm-load."""
