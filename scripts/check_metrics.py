@@ -337,6 +337,11 @@ REQUIRED_FAMILIES = (
     "state_last_commit_check_total",
     # PR-32 the frozen heap (0 until the verify warm-up has ended)
     "runtime_gc_frozen_objects",
+    # PR-35 a height is encoded once on its way to disk (live on any
+    # node: every save_block counts a height, a seen commit and a
+    # next_validators packed; in fast sync nothing else is packed)
+    "store_encodings_total",
+    "store_heights_saved_total",
 )
 
 # ...and of those, the hot-path families that must have RECORDED samples

@@ -23,6 +23,7 @@ import struct
 import threading
 from typing import Optional
 
+from ..crypto import batch as crypto_batch
 from ..libs import tracing
 from ..libs.db import DB
 from ..types import serde
@@ -108,6 +109,9 @@ class BlockStore:
             for i in range(part_set.total()):
                 part = part_set.get_part(i)
                 self._db.set(_part_key(height, i), serde.pack(serde.part_obj(part)))
+            # each commit is packed once: in fast sync this LastCommit
+            # is the object saved as SC:height-1 a call ago, and brings
+            # its bytes (serde.encode_commit)
             if block.last_commit is not None:
                 self._db.set(
                     _commit_key(height - 1), serde.encode_commit(block.last_commit)
@@ -117,6 +121,9 @@ class BlockStore:
             if self._base == 0:
                 self._base = height
             self._persist_meta_locked()
+            m = crypto_batch.get_metrics()
+            if m is not None:
+                m.store_heights_saved.inc()
 
     def seed_anchor(self, height: int, commit: Commit) -> None:
         """State-sync bootstrap (no reference equivalent; upstream v0.34

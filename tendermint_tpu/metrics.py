@@ -108,6 +108,13 @@ class CryptoMetrics:
     # validator_set.py reports through this process-wide sink like
     # verify_commit does)
     valset_hash: object = NOP
+    # what a height costs on its way to disk (types/serde.py,
+    # blockchain/store.py, through the same sink): a commit or a
+    # validator set packed for a save, labeled kind=commit|valset (one
+    # kept from an earlier save counts nothing), beside the heights
+    # the block store saved
+    store_encodings: object = NOP
+    store_heights_saved: object = NOP
 
 
 @dataclass
@@ -750,6 +757,15 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             "ValidatorSet.hash() calls, by result: memo (the remembered "
             "root) or computed (a Merkle walk of the whole set).",
             ("result",)),
+        store_encodings=r.counter(
+            f"{ns}_store_encodings_total",
+            "Commits and validator sets packed for a save, by kind "
+            "(commit, valset); one that kept the bytes of an earlier "
+            "save is not counted.",
+            ("kind",)),
+        store_heights_saved=r.counter(
+            f"{ns}_store_heights_saved_total",
+            "Heights the block store saved (save_block calls)."),
     )
     statesync = StateSyncMetrics(
         snapshots=r.gauge(

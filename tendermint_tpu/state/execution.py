@@ -794,7 +794,14 @@ def update_state(
     state: State, block_id: BlockID, header, abci_responses: ABCIResponses
 ) -> State:
     """Pure state transition (reference execution.go updateState:411-472).
-    Note: app_hash is filled AFTER Commit by the caller."""
+    Note: app_hash is filled AFTER Commit by the caller.
+
+    Only next_validators is copied, because only it is written to. The
+    other two sets move down a slot as the objects they are (with the
+    bytes they were saved as, serde.encode_valset): a State's sets are
+    read-only to everyone who did not build them, and whoever rotates
+    one copies it first (consensus/state.py update_to_state,
+    enter_new_round)."""
     n_val_set = state.next_validators.copy()
 
     last_height_vals_changed = state.last_height_validators_changed
@@ -826,8 +833,8 @@ def update_state(
         last_block_id=block_id,
         last_block_time=header.time,
         next_validators=n_val_set,
-        validators=state.next_validators.copy(),
-        last_validators=state.validators.copy(),
+        validators=state.next_validators,
+        last_validators=state.validators,
         last_height_validators_changed=last_height_vals_changed,
         consensus_params=params,
         last_height_consensus_params_changed=last_height_params_changed,

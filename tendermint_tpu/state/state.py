@@ -103,15 +103,21 @@ class State:
     # --- serde --------------------------------------------------------------
 
     def to_obj(self):
+        return self._obj(serde.valset_obj)
+
+    def _obj(self, valset):
+        next_vals, vals, last_vals = (
+            None if vs is None else valset(vs) for vs in
+            (self.next_validators, self.validators, self.last_validators))
         return [
             self.chain_id,
             self.last_block_height,
             self.last_block_total_tx,
             serde.block_id_obj(self.last_block_id),
             self.last_block_time,
-            serde.valset_obj(self.next_validators) if self.next_validators is not None else None,
-            serde.valset_obj(self.validators) if self.validators is not None else None,
-            serde.valset_obj(self.last_validators) if self.last_validators is not None else None,
+            next_vals,
+            vals,
+            last_vals,
             self.last_height_validators_changed,
             [
                 self.consensus_params.block_size.max_bytes,
@@ -146,7 +152,11 @@ class State:
         )
 
     def to_bytes(self) -> bytes:
-        return serde.pack(self.to_obj())
+        """serde.pack(self.to_obj()), byte for byte, with each validator
+        set's part taken from the bytes the set keeps: a height packs
+        next_validators, the other two were packed a height and two
+        before (update_state hands them down a slot, unwritten)."""
+        return serde.pack_list(self._obj(serde.encode_valset))
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "State":

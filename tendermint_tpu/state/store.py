@@ -72,10 +72,11 @@ def save_validators_info(db: DB, height: int, last_changed: int, val_set: Option
     if last_changed > height:
         raise ValueError("last_height_changed cannot be greater than height")
     if height == last_changed and val_set is not None:
-        obj = [last_changed, serde.valset_obj(val_set)]
+        # the set's own bytes: State.to_bytes() packs them anyway
+        obj = [last_changed, serde.encode_valset(val_set)]
     else:
         obj = [last_changed, None]  # pointer record
-    db.set(_vals_key(height), serde.pack(obj))
+    db.set(_vals_key(height), serde.pack_list(obj))
 
 
 def load_validators(db: DB, height: int) -> ValidatorSet:

@@ -84,6 +84,13 @@ class Commit:
 
     block_id: BlockID
     precommits: List[Optional[Vote]]
+    # Not part of the commit: the bytes the block store saved it as
+    # (serde.encode_commit), so that the LastCommit fast sync saves as
+    # SC:h is not packed again as C:h a height later. Nothing writes to
+    # block_id or precommits once a commit is built; whoever does works
+    # on a commit it has just decoded, before any store has seen it.
+    saved_as: Optional[bytes] = dc_field(default=None, repr=False,
+                                         compare=False)
 
     def height(self) -> int:
         for v in self.precommits:

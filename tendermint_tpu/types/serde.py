@@ -13,7 +13,7 @@ from typing import Optional
 
 import msgpack
 
-from ..crypto import merkle, pubkey_from_bytes, pubkey_to_bytes
+from ..crypto import batch, merkle, pubkey_from_bytes, pubkey_to_bytes
 from .basic import BlockID, PartSetHeader, Proposal, Vote
 from .block import Block, Commit, Data, EvidenceData, Header
 from .part_set import Part
@@ -26,6 +26,25 @@ def pack(obj) -> bytes:
 
 def unpack(data: bytes):
     return msgpack.unpackb(data, raw=False, strict_map_key=False)
+
+
+class Packed(bytes):
+    """Bytes that are msgpack already: pack_list splices them in where
+    the object they were packed from would stand."""
+
+
+def pack_list(items) -> bytes:
+    """pack(list(items)) with every Packed item standing for its object:
+    an array header and its elements' own packings, one after another,
+    are the bytes msgpack gives the nested list."""
+    return b"".join([msgpack.Packer().pack_array_header(len(items))] + [
+        i if isinstance(i, Packed) else pack(i) for i in items])
+
+
+def _count_encoding(kind: str) -> None:
+    m = batch.get_metrics()
+    if m is not None:
+        m.store_encodings.with_labels(kind).inc()
 
 
 # --- to_obj / from_obj -----------------------------------------------------
@@ -219,7 +238,15 @@ def decode_vote(data: bytes) -> Vote:
 
 
 def encode_commit(c: Commit) -> bytes:
-    return pack(commit_obj(c))
+    """pack(commit_obj(c)) for the block store, kept on a Commit: fast
+    sync saves block h+1's LastCommit as SC:h and, one height later, the
+    same object as C:h."""
+    if not isinstance(c, Commit):  # an AggregateCommit: a bitmap and 96 bytes
+        return pack(commit_obj(c))
+    if c.saved_as is None:
+        c.saved_as = pack(commit_obj(c))
+        _count_encoding("commit")
+    return c.saved_as
 
 
 def decode_commit(data: bytes) -> Commit:
@@ -246,6 +273,16 @@ def validator_from(o) -> Validator:
 def valset_obj(vs: ValidatorSet):
     prop = vs.proposer.address if vs.proposer else b""
     return [[validator_obj(v) for v in vs.validators], prop]
+
+
+def encode_valset(vs: ValidatorSet) -> Packed:
+    """pack(valset_obj(vs)) for the state store, kept on the set until
+    something writes to it (ValidatorSet._packed_memo)."""
+    memo = vs._packed_memo
+    if memo is None:
+        memo = vs._packed_memo = Packed(pack(valset_obj(vs)))
+        _count_encoding("valset")
+    return memo
 
 
 def valset_from(o) -> ValidatorSet:

@@ -116,7 +116,18 @@ _address_of = attrgetter("address")
 
 class ValidatorSet:
     """Sorted-by-address validator set with proposer rotation
-    (reference types/validator_set.go:33-117)."""
+    (reference types/validator_set.go:33-117).
+
+    A set keeps the bytes it was last saved as (`_packed_memo`, filled
+    by serde.encode_valset): a height saves three sets of which two are
+    the sets of the height before, unchanged. Everything here that
+    writes a priority, the proposer or the membership drops them, and
+    nothing outside writes to a member of a set it did not build."""
+
+    # class-level defaults keep instances built via __new__ (copy,
+    # serde) safe, as getattr-with-default does for the other memos
+    _packed_memo: Optional[bytes] = None
+    _proposer: Optional[Validator] = None
 
     def __init__(self, validators: List[Validator]):
         vals = sorted((v.copy() for v in validators), key=lambda v: v.address)
@@ -125,23 +136,34 @@ class ValidatorSet:
             raise ValueError("duplicate validator address")
         self.validators = vals
         self._total: Optional[int] = None
-        self.proposer: Optional[Validator] = None
+        self.proposer = None
         if vals:
             self.increment_proposer_priority(1)
 
     def __len__(self):
         return len(self.validators)
 
+    @property
+    def proposer(self) -> Optional[Validator]:
+        return self._proposer
+
+    @proposer.setter
+    def proposer(self, val: Optional[Validator]) -> None:
+        self._proposer = val
+        self._packed_memo = None
+
     def copy(self) -> "ValidatorSet":
         vs = ValidatorSet.__new__(ValidatorSet)
         vs.validators = [v.copy() for v in self.validators]
         vs._total = self._total
         # the root covers (address, pub_key, voting_power) only, which a
-        # copy shares: update_state copies the sets at every height
+        # copy shares: update_state copies next_validators every height
         vs._hash_memo = getattr(self, "_hash_memo", None)
-        vs.proposer = None
         if self.proposer is not None:
             _, vs.proposer = vs.get_by_address(self.proposer.address)
+        # priorities and proposer are the original's, so the saved bytes
+        # are too (set last: the proposer's setter drops them)
+        vs._packed_memo = self._packed_memo
         return vs
 
     def total_voting_power(self) -> int:
@@ -192,6 +214,7 @@ class ValidatorSet:
         total = self.total_voting_power()
         self._rescale_priorities(2 * total)
         self._shift_by_avg_priority()
+        self._packed_memo = None
         for _ in range(times):
             mx = None
             for v in self.validators:
@@ -211,6 +234,7 @@ class ValidatorSet:
         types/validator_set.go:547-585 RescalePriorities)."""
         if diff_max <= 0:
             return
+        self._packed_memo = None
         prios = [v.proposer_priority for v in self.validators]
         dist = max(prios) - min(prios)
         if dist > diff_max:
@@ -223,6 +247,7 @@ class ValidatorSet:
         shiftByAvgProposerPriority). The reference computes the average
         with big.Int.Div — Euclidean division, which for a positive
         divisor equals Python's floor `//` (NOT Go's truncating `/`)."""
+        self._packed_memo = None
         n = len(self.validators)
         avg = sum(v.proposer_priority for v in self.validators) // n
         for v in self.validators:
@@ -557,6 +582,7 @@ class ValidatorSet:
         self._total = None
         self._is_bls_cache = None
         self._hash_memo = None
+        self._packed_memo = None
         if self.proposer is not None and self.proposer.address not in by_addr:
             self.proposer = None
         self.total_voting_power()
