@@ -342,6 +342,12 @@ REQUIRED_FAMILIES = (
     # next_validators packed; in fast sync nothing else is packed)
     "store_encodings_total",
     "store_heights_saved_total",
+    # PR-36 the block pool by peer slot, and what a refused commit made
+    # it ask again (declaration presence: a node that never fast-syncs
+    # sends no request, and an honest catch-up redoes nothing)
+    "blockchain_pool_requests_total",
+    "blockchain_pool_blocks_received_total",
+    "blockchain_redo_heights_total",
 )
 
 # ...and of those, the hot-path families that must have RECORDED samples

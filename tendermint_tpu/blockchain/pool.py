@@ -3,8 +3,16 @@
 Reference parity: blockchain/pool.go.  Per-height requesters ask peers
 for blocks (bounded in-flight window), time out slow peers, and hand
 blocks to the reactor in strict height order via peek_two_blocks /
-pop_request (:62-105,328).  Peer send-rate accounting marks laggards for
-removal (:129 minRecvRate).
+pop_request (:62-105,328).  A refused commit drops both blocks of the
+pair and everything their peers delivered (redo_request).  The
+reference's receive-rate floor (:129 minRecvRate) is not implemented:
+a slow peer is removed by PEER_TIMEOUT alone.
+
+Counters, through the process-wide sink (crypto/batch.get_metrics()):
+pool_requests{slot} a request sent, pool_blocks_received{slot} a block
+taken, redo_heights a delivered block dropped and asked again. A
+peer's slot is the lowest number no live peer of this pool holds when
+the pool first hears of it: bounded by the peer limit, never an id.
 """
 
 from __future__ import annotations
@@ -13,7 +21,9 @@ import logging
 import random
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
+
+from ..crypto import batch as crypto_batch
 
 LOG = logging.getLogger("blockchain.pool")
 
@@ -21,14 +31,14 @@ REQUEST_INTERVAL = 0.01  # pool.go:36 requestIntervalMS
 MAX_TOTAL_REQUESTERS = 600  # pool.go:37
 MAX_PENDING_REQUESTS = 600  # pool.go:38
 MAX_PENDING_REQUESTS_PER_PEER = 20  # pool.go:39
-MIN_RECV_RATE = 7680  # pool.go:44: 7680 B/s
 PEER_TIMEOUT = 15.0  # pool.go:41
 
 
 class _PoolPeer:
-    def __init__(self, peer_id: str, height: int):
+    def __init__(self, peer_id: str, height: int, slot: str):
         self.id = peer_id
         self.height = height
+        self.slot = slot  # the label of this peer's counters
         self.num_pending = 0
         self.timeout_at: Optional[float] = None
         self.did_timeout = False
@@ -133,6 +143,9 @@ class BlockPool:
             if peer.num_pending == 1:
                 peer.touch()
             req.peer_id = peer.id
+        m = crypto_batch.get_metrics()
+        if m is not None:
+            m.pool_requests.with_labels(peer.slot).inc()
         self._request_fn(peer.id, height)
 
     def _check_peer_timeouts(self) -> None:
@@ -152,14 +165,20 @@ class BlockPool:
         with self._lock:
             p = self._peers.get(peer_id)
             if p is None:
-                p = _PoolPeer(peer_id, height)
+                taken = {q.slot for q in self._peers.values()}
+                slot = next(s for s in map(str, range(len(taken) + 1))
+                            if s not in taken)
+                p = _PoolPeer(peer_id, height, slot)
                 self._peers[peer_id] = p
             else:
                 p.height = max(p.height, height)
             self._max_peer_height = max(self._max_peer_height, height)
 
     def remove_peer(self, peer_id: str) -> None:
-        """pool.go:243-266: re-dispatch its outstanding requests."""
+        """pool.go:243-266: re-dispatch its outstanding requests. The
+        blocks it delivered stay (a peer that timed out or hung up sent
+        nothing wrong; one whose block is refused goes through
+        redo_request, which drops them)."""
         redo: List[int] = []
         with self._lock:
             self._peers.pop(peer_id, None)
@@ -174,7 +193,6 @@ class BlockPool:
 
     def add_block(self, peer_id: str, block, block_size: int) -> None:
         """pool.go:291-324."""
-        redispatch = False
         with self._lock:
             req = self._requesters.get(block.header.height)
             if req is None or req.peer_id != peer_id or req.block is not None:
@@ -183,29 +201,53 @@ class BlockPool:
             req.block = block
             self._num_received += 1
             p = self._peers.get(peer_id)
-            if p is not None:
-                p.num_pending = max(0, p.num_pending - 1)
-                if p.num_pending == 0:
-                    p.disarm()
-                else:
-                    p.touch()
-        if redispatch:
-            self._dispatch(block.header.height)
-
-    def redo_request(self, height: int) -> None:
-        """pool.go:268-277: the block at `height` failed validation —
-        drop it and its peer, then re-request."""
-        with self._lock:
-            req = self._requesters.get(height)
-            if req is None:
+            if p is None:
                 return
-            bad_peer = req.peer_id
-            req.block = None
-            req.peer_id = None
-        if bad_peer:
-            self._error_fn(bad_peer, f"bad block at height {height}")
-            self.remove_peer(bad_peer)
-        self._dispatch(height)
+            p.num_pending = max(0, p.num_pending - 1)
+            if p.num_pending == 0:
+                p.disarm()
+            else:
+                p.touch()
+        m = crypto_batch.get_metrics()
+        if m is not None:
+            m.pool_blocks_received.with_labels(p.slot).inc()
+
+    def redo_request(self, height: int) -> Tuple[int, List[str]]:
+        """The commit for block `height`, carried by block height+1,
+        was refused (reactor.go:318-330: RedoRequest(first),
+        RedoRequest(second), both peers stopped; pool.go removePeer
+        redoes every requester of a removed peer). Either block may be
+        the altered one, so both are dropped, the peers that delivered
+        them are reported and removed, and every other block those
+        peers delivered that is still in the pool goes with them: all
+        are asked again of the peers that remain. Returns (blocks
+        dropped, peers reported)."""
+        dropped = 0
+        redo: List[int] = []
+        with self._lock:
+            bad: List[str] = []
+            for h in (height, height + 1):
+                req = self._requesters.get(h)
+                if (req is not None and req.block is not None
+                        and req.peer_id and req.peer_id not in bad):
+                    bad.append(req.peer_id)
+            for req in self._requesters.values():
+                if req.peer_id in bad:
+                    if req.block is not None:
+                        dropped += 1
+                        req.block = None
+                    req.peer_id = None
+                    redo.append(req.height)
+            for peer_id in bad:
+                self._peers.pop(peer_id, None)
+        m = crypto_batch.get_metrics()
+        if m is not None and dropped:
+            m.redo_heights.inc(dropped)
+        for peer_id in bad:
+            self._error_fn(peer_id, f"bad block at height {height}")
+        for h in redo:
+            self._dispatch(h)
+        return dropped, bad
 
     # -- ordered hand-off ----------------------------------------------
 

@@ -300,7 +300,8 @@ class BlockchainReactor(Reactor):
                 self._recv_cause[height] = tracer.record(
                     "p2p.recvBlock", t_recv, time.perf_counter_ns(), "p2p",
                     request=("block", height), height=height,
-                    txs=len(block.data.txs), bytes=len(msg_bytes))
+                    txs=len(block.data.txs), bytes=len(msg_bytes),
+                    peer=peer.id[:8])
         elif kind == "no_block_response":
             LOG.debug("peer %s has no block at %d", peer.id[:8], obj[1])
         elif kind == "status_request":
@@ -533,11 +534,20 @@ class BlockchainReactor(Reactor):
         return True
 
     def _redo(self, height: int) -> None:
-        """Block `height`'s commit failed: ask for the block again. The
-        copy that comes back is judged from scratch, its LastCommit
-        included."""
-        self._verified_commit = None
-        self.pool.redo_request(height)
+        """Block `height`'s commit, carried by block height+1, was
+        refused: either may be the altered one, so the pool drops both
+        with whatever else their peers delivered and asks again
+        (reactor.go:318-330). Nothing this loop holds of a dropped
+        block outlives it: the caller lets go of its speculative
+        verify, the note of the last verified commit goes here (the
+        copies that come back are judged from scratch, LastCommit
+        included), and the executor settles a staged block by its hash
+        when the next one is applied."""
+        with tracing.span("fastsync.redo", cat="fastsync",
+                          height=height) as sp:
+            self._verified_commit = None
+            dropped, peers = self.pool.redo_request(height)
+            sp.set(dropped=dropped, peers=len(peers))
 
     def _apply_verified(self, block, block_id, commit) -> None:
         """Apply a block whose commit (carried by its successor) this

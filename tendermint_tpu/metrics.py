@@ -115,6 +115,13 @@ class CryptoMetrics:
     # the block store saved
     store_encodings: object = NOP
     store_heights_saved: object = NOP
+    # fast sync's block pool (blockchain/pool.py, through the same
+    # sink): requests sent and blocks taken, labeled by the peer's slot
+    # in the pool (a small number, never an id), and the delivered
+    # blocks a refused commit made the pool drop and ask again
+    pool_requests: object = NOP
+    pool_blocks_received: object = NOP
+    redo_heights: object = NOP
 
 
 @dataclass
@@ -766,6 +773,20 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
         store_heights_saved=r.counter(
             f"{ns}_store_heights_saved_total",
             "Heights the block store saved (save_block calls)."),
+        pool_requests=r.counter(
+            f"{ns}_blockchain_pool_requests_total",
+            "Block requests fast sync's pool sent, by the peer's slot "
+            "in the pool.",
+            ("slot",)),
+        pool_blocks_received=r.counter(
+            f"{ns}_blockchain_pool_blocks_received_total",
+            "Blocks fast sync's pool took from the peer it had asked, "
+            "by the peer's slot in the pool.",
+            ("slot",)),
+        redo_heights=r.counter(
+            f"{ns}_blockchain_redo_heights_total",
+            "Delivered blocks the pool dropped and asked again after a "
+            "refused commit."),
     )
     statesync = StateSyncMetrics(
         snapshots=r.gauge(

@@ -124,6 +124,7 @@ class MConnection:
         on_error: Callable[[Exception], None],
         config: Optional[MConnConfig] = None,
         metrics=None,  # P2PMetrics
+        peer_id: str = "",  # whose connection: names the throttle spans
     ):
         from ...metrics import P2PMetrics
 
@@ -138,6 +139,7 @@ class MConnection:
         self.send_monitor = Monitor()
         self.recv_monitor = Monitor()
         self._throttled: Dict[str, Optional[List[int]]] = {}
+        self._span_peer = peer_id[:8]
         # per direction: [a count the conn keeps, the counter it feeds,
         # the count last published]
         frames, calls = self.metrics.frames, self.metrics.socket_calls
@@ -271,7 +273,8 @@ class MConnection:
                                   or t0 - open_[0] > THROTTLE_CUT_NS):
             if open_[1] - open_[0] >= THROTTLE_SPAN_FLOOR_NS:
                 tracing.get_tracer().record(
-                    f"p2p.{direction}Throttle", open_[0], open_[1], "p2p")
+                    f"p2p.{direction}Throttle", open_[0], open_[1], "p2p",
+                    peer=self._span_peer)
             open_ = self._throttled[direction] = None
         if slept > 0:
             self.metrics.throttled_seconds.with_labels(direction).inc(slept)

@@ -43,6 +43,7 @@ class Peer:
             on_error=lambda err: on_error(self, err),
             config=mconfig,
             metrics=self.metrics,
+            peer_id=node_info.id,
         )
 
     @property

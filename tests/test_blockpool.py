@@ -12,9 +12,13 @@ import time
 os.environ.setdefault("TM_TPU_CRYPTO_BACKEND", "cpu")
 
 import pytest
+from test_save_once import _counted
 
-from tendermint_tpu.blockchain.pool import BlockPool
+from tendermint_tpu.blockchain import pool as pool_mod
+from tendermint_tpu.blockchain.pool import BlockPool, _Requester
+from tendermint_tpu.crypto import batch as crypto_batch
 from tendermint_tpu.libs.bit_array import BitArray
+from tendermint_tpu.metrics import prometheus_metrics
 
 
 class _FakeBlock:
@@ -142,6 +146,125 @@ class TestBlockPool:
             assert h.pool.max_peer_height() == 9
         finally:
             h.pool.stop()
+
+
+# --- a refused commit (pool.go RedoRequest x 2 + removePeer) ---------------
+
+
+def _planned_pool(plan: dict, monkeypatch) -> PoolHarness:
+    """A pool, not started, whose requester for each height of `plan`
+    went to the peer the plan names (the draw is scripted); the peers
+    are heard of in sorted order, so "a" holds slot 0."""
+    h = PoolHarness(start=1)
+    for peer in sorted(set(plan.values())):
+        h.pool.set_peer_height(peer, max(plan))
+    for height, peer in sorted(plan.items()):
+        monkeypatch.setattr(
+            pool_mod.random, "choice",
+            lambda among, peer=peer: next(p for p in among if p.id == peer))
+        h.pool._requesters[height] = _Requester(height)
+        h.pool._dispatch(height)
+    monkeypatch.undo()
+    assert h.requests == [(plan[k], k) for k in sorted(plan)]
+    return h
+
+
+REFUSED = {
+    # plan, delivered, peer that timed out first, -> reported, dropped
+    "the pair came from one peer": (
+        {1: "a", 2: "a", 3: "b", 4: "a", 5: "b"}, {1, 2, 3, 4, 5}, None,
+        ["a"], {1, 2, 4}),
+    "the pair came from two peers": (
+        {1: "a", 2: "b", 3: "c", 4: "a", 5: "b", 6: "c"}, {1, 2, 3, 4, 5, 6},
+        None, ["a", "b"], {1, 2, 4, 5}),
+    "a reported peer's open requests move too": (
+        {1: "a", 2: "a", 3: "a", 4: "b"}, {1, 2, 4}, None, ["a"], {1, 2}),
+    "a peer that only timed out keeps what it delivered": (
+        {1: "a", 2: "a", 3: "t", 4: "t", 5: "b"}, {1, 2, 3, 5}, "t",
+        ["a"], {1, 2}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_a_refused_height_drops_the_pair_and_what_its_peers_delivered(
+        case, monkeypatch):
+    plan, delivered, slow, reported, dropped = REFUSED[case]
+    m = prometheus_metrics("t_bp")
+    crypto_batch.set_metrics(m.crypto)
+    try:
+        h = _planned_pool(plan, monkeypatch)
+        pool = h.pool
+        # whatever is asked again goes to the last of those that remain
+        monkeypatch.setattr(pool_mod.random, "choice",
+                            lambda among: max(among, key=lambda p: p.id))
+        for height in sorted(delivered):
+            pool.add_block(plan[height], _FakeBlock(height), 100)
+        pool.add_block("stranger", _FakeBlock(max(plan)), 100)  # not counted
+        if slow is not None:
+            pool._peers[slow].timeout_at = time.monotonic() - 1
+            pool._check_peer_timeouts()
+            assert h.errors == [(slow, "block request timed out")]
+            assert slow not in pool._peers
+        asked_before = len(h.requests)
+        assert pool.redo_request(1) == (len(dropped), reported)
+
+        assert h.errors[-len(reported):] == [
+            (p, "bad block at height 1") for p in reported]
+        gone = set(reported) | ({slow} if slow else set())
+        assert set(pool._peers) == set(plan.values()) - gone
+        kept = delivered - dropped
+        for height, req in pool._requesters.items():
+            if height in kept:  # the delivered blocks of everyone else
+                assert req.block.header.height == height
+                assert req.peer_id == plan[height]
+            else:  # asked again, of a peer that remains
+                assert req.block is None and req.peer_id in pool._peers
+        again = h.requests[asked_before:]
+        open_of_reported = {k for k, p in plan.items()
+                            if p in reported and k not in delivered}
+        assert {k for _, k in again} == dropped | open_of_reported
+        assert all(p in pool._peers for p, _ in again)
+        # the pool hands on what the survivors send for the dropped pair
+        for height in (1, 2):
+            pool.add_block(pool._requesters[height].peer_id,
+                           _FakeBlock(height), 100)
+        first, second = pool.peek_two_blocks()
+        assert (first.header.height, second.header.height) == (1, 2)
+
+        requests = _counted(m, "t_bp_blockchain_pool_requests_total")
+        received = _counted(m, "t_bp_blockchain_pool_blocks_received_total")
+        slots = {'{slot="%d"}' % i for i in range(len(set(plan.values())))}
+        assert set(requests) <= slots and set(received) <= slots
+        assert sum(requests.values()) == len(h.requests)
+        assert sum(received.values()) == len(delivered) + 2
+        assert _counted(m, "t_bp_blockchain_redo_heights_total") == {
+            "": float(len(dropped))}
+        by_slot = {'{slot="%d"}' % i: p
+                   for i, p in enumerate(sorted(set(plan.values())))}
+        before_redo = [p for p, _ in h.requests[:asked_before]]
+        for label, peer in by_slot.items():
+            if peer in pool._peers:
+                continue  # a survivor's slot also counts what was asked again
+            assert requests.get(label, 0.0) == before_redo.count(peer)
+    finally:
+        crypto_batch.set_metrics(None)
+
+
+def test_a_slot_is_the_lowest_one_free_so_the_labels_stay_few():
+    h = PoolHarness(start=1)
+    pool = h.pool
+    for peer in ("a", "b", "c"):
+        pool.set_peer_height(peer, 3)
+    assert [pool._peers[p].slot for p in "abc"] == ["0", "1", "2"]
+    pool.remove_peer("b")
+    pool.set_peer_height("d", 3)
+    pool.set_peer_height("a", 9)  # a peer heard of again keeps its slot
+    assert {p.id: p.slot for p in pool._peers.values()} == {
+        "a": "0", "c": "2", "d": "1"}
+    for i in range(50):  # churn: one peer at a time comes and goes
+        pool.set_peer_height(f"x{i}", 3)
+        assert pool._peers[f"x{i}"].slot == "3"
+        pool.remove_peer(f"x{i}")
 
 
 class TestHeightVoteSet:

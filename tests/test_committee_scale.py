@@ -495,7 +495,10 @@ def test_fast_sync_off_the_wire_equals_the_reference(chain, loop):
     assert reactor._try_sync_batch() is True
 
     assert store.height() == 4 == reactor.state.last_block_height
-    assert reactor.pool._requesters[5].block is None  # 5 is asked for again
+    # 5, 6 and everything else their peer delivered is asked for again
+    assert all(reactor.pool._requesters[h].block is None
+               and reactor.pool._requesters[h].peer_id is None
+               for h in range(5, len(blocks) + 1))
     ref = KVReference()
     for h in range(1, 5):
         for tx in txs_at[h]:
@@ -508,11 +511,10 @@ def test_fast_sync_off_the_wire_equals_the_reference(chain, loop):
         assert blocks[h + 1].header.app_hash == ref.commit()
     assert reactor.state.app_hash == ref.commit()
 
-    # the honest copies come back: the rest of the chain applies (6 is
-    # put back by hand: the reactor asks again for 5 only, PERF.md §7)
-    reactor.pool._requesters[6].block = None
-    for h in (5, 6):
-        reactor.pool._requesters[h].peer_id = "p1"
+    # the honest copies come back from another peer: the rest applies
+    peer.id = "p2"
+    for h in range(5, len(blocks) + 1):
+        reactor.pool._requesters[h].peer_id = "p2"
         reactor.receive(BLOCKCHAIN_CHANNEL, peer, _wire(blocks[h]))
     assert reactor._try_sync_batch() is True
     assert store.height() == 7

@@ -346,10 +346,11 @@ def test_corrupted_commit_refused_and_redone_height_fully_verified(loop):
     assert store.height() == 2 and reactor.state.last_block_height == 2
     assert checked == {1: None, 2: "handed_down"}
     assert reactor.pool.height == 3
-    assert reactor.pool._requesters[3].block is None
+    # 3, 4 and everything else their peer delivered is asked for again
+    assert all(reactor.pool._requesters[h].block is None for h in range(3, 8))
 
-    # the very same copy of 3 comes back, and an honest 4 behind it
-    for h in (3, 4):
+    # the very same copy of 3 comes back, and the honest rest behind it
+    for h in range(3, 8):
         reactor.pool._requesters[h].block = honest[h]
         reactor.pool._requesters[h].peer_id = "p2"
     assert reactor._try_sync_batch() is True
