@@ -348,6 +348,9 @@ REQUIRED_FAMILIES = (
     "blockchain_pool_requests_total",
     "blockchain_pool_blocks_received_total",
     "blockchain_redo_heights_total",
+    # PR-37 a batch meets the verified-signature cache once: a digest a
+    # triple looked up (live wherever the cache is on and a batch ran)
+    "crypto_sig_cache_key_hashes_total",
 )
 
 # ...and of those, the hot-path families that must have RECORDED samples

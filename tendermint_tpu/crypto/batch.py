@@ -26,7 +26,9 @@ Two cross-cutting layers sit in front of every backend:
   via set_sig_cache / configure): verify() consults it first and only
   the cache-miss subset reaches the backend; the per-item mask is
   re-interleaved in add order. Duplicate triples within one batch are
-  dispatched once.
+  dispatched once. A batch meets the cache once: one digest a triple,
+  one locked pass a shard in (the adaptive router's look is that pass,
+  handed to the leaf) and one out.
 - Async dispatch: verify_async() runs the exact verify() pipeline on a
   dedicated per-backend dispatch thread and returns a VerifyFuture, so
   callers overlap verification with other work (fast-sync applies block
@@ -307,6 +309,31 @@ def shutdown_dispatchers(timeout: float = 10.0) -> None:
         d.stop(timeout)
 
 
+def _look_up(cache, items):
+    """The one meeting of a batch with the cache on its way in: a key a
+    triple, built here and nowhere else, and one counted get_many().
+    Returns (keys, verdicts, miss_idx): verdicts holds None where the
+    backend has to answer, miss_idx the position of every such triple's
+    first occurrence in the batch."""
+    keys = cache.keys(items)
+    verdicts = cache.get_many(keys)
+    seen = set()
+    miss_idx = []
+    for i, v in enumerate(verdicts):
+        if v is None and keys[i] not in seen:
+            seen.add(keys[i])
+            miss_idx.append(i)
+    m = _metrics
+    if m is not None:
+        m.sig_cache_key_hashes.inc(len(keys))
+        hits = len(keys) - verdicts.count(None)
+        if hits:
+            m.sig_cache_hits.inc(hits)
+        if miss_idx:
+            m.sig_cache_misses.inc(len(miss_idx))
+    return keys, verdicts, miss_idx
+
+
 class BatchVerifier:
     """Accumulate (msg, sig, pubkey) triples, then verify all at once.
 
@@ -322,6 +349,13 @@ class BatchVerifier:
     # chips the last _verify() cut its batch over: a device backend
     # sets it, and crypto.batchVerify then carries it as `ndev`
     ndev = None
+    # what the adaptive router hands the verifier it built, beside the
+    # batch itself: the (keys, verdicts) of its look at the cache, which
+    # verify() then does not make again, and that every pubkey of the
+    # batch is 32 bytes long. A verifier that overrides verify()
+    # wholesale never reads either and verifies the whole of _items.
+    _looked = None
+    _ed25519_only = False
 
     def __init__(self):
         self._items: List[Triple] = []
@@ -338,53 +372,48 @@ class BatchVerifier:
     def verify(self) -> List[bool]:
         """Returns one validity flag per added triple, in add order.
 
-        Consults the process-wide verified-signature cache first: cached
-        triples never reach the backend, duplicate triples within the
-        batch are dispatched once, and only the cache-miss subset runs
-        _verify(); the mask is re-interleaved in add order."""
+        The batch meets the process-wide verified-signature cache once:
+        one key a triple and one locked look a shard (the adaptive
+        router's own, when it built this verifier: its look and the
+        counted look are one), then one locked pass to store what the
+        backend answered. Cached triples never reach the backend,
+        duplicate triples within the batch are dispatched once, and
+        only the cache-miss subset runs _verify(); the mask is
+        re-interleaved in add order."""
         cache = _sig_cache
+        looked, self._looked = self._looked, None
         if cache is None or not self._items:
             return self._verify_instrumented()
         items = self._items
-        keys = [cache.key(msg, sig, pk) for msg, sig, pk in items]
-        verdicts: List[Optional[bool]] = [None] * len(items)
-        miss_pos: dict = {}  # key -> index into miss_idx (in-batch dedup)
-        miss_idx: List[int] = []
-        hits = 0
-        for i, k in enumerate(keys):
-            if k in miss_pos:
-                continue  # duplicate of an in-batch miss: filled below
-            v = cache.get(k)
-            if v is None:
-                miss_pos[k] = len(miss_idx)
-                miss_idx.append(i)
-            else:
-                verdicts[i] = v
-                hits += 1
-        m = _metrics
-        if m is not None:
-            if hits:
-                m.sig_cache_hits.inc(hits)
-            if miss_idx:
-                m.sig_cache_misses.inc(len(miss_idx))
-        if miss_idx:
+        all_keys, verdicts, miss_idx = looked or _look_up(cache, items)
+        if not miss_idx:
+            return verdicts
+        all_missed = len(miss_idx) == len(items)
+        if all_missed:
+            keys = all_keys  # the miss subset is the list: nothing copied
+        else:
             # _verify() reads self._items; narrow it to the miss subset
             # for the dispatch (single-caller contract, like add/verify)
             self._items = [items[i] for i in miss_idx]
-            try:
-                submask = self._verify_instrumented(cache_hits=hits)
-            finally:
-                self._items = items
-            for pos, i in enumerate(miss_idx):
-                ok = bool(submask[pos])
-                verdicts[i] = ok
-                cache.put(keys[i], ok)
-        for i, k in enumerate(keys):
-            if verdicts[i] is None:  # in-batch duplicate of a miss
-                verdicts[i] = verdicts[miss_idx[miss_pos[k]]]
-        return verdicts
+            keys = [all_keys[i] for i in miss_idx]
+        try:
+            submask = self._verify_instrumented(
+                cache_hits=len(items) - verdicts.count(None),
+                key_hashes=len(all_keys))
+        finally:
+            self._items = items
+        answered = list(map(bool, submask))
+        cache.put_many(keys, answered)
+        if all_missed:
+            return answered
+        # hits keep their verdict; a miss, and every in-batch duplicate
+        # of it, takes what the backend said of its first occurrence
+        first = dict(zip(keys, answered))
+        return [first[k] if v is None else v
+                for k, v in zip(all_keys, verdicts)]
 
-    def _verify_instrumented(self, cache_hits: int = 0) -> List[bool]:
+    def _verify_instrumented(self, cache_hits: int = 0,
+                             key_hashes: Optional[int] = None) -> List[bool]:
         """_verify() wrapped with latency/size/validity telemetry: the
         histogram and the crypto.batchVerify span share two clock reads."""
         m = _metrics
@@ -405,13 +434,15 @@ class BatchVerifier:
                 tracer.record("crypto.dispatchWait",
                               int(fut._t_submit * 1e9), sp.start_ns,
                               "crypto", backend=self.BACKEND, n=n)
+            if key_hashes is not None:  # the batch went by the cache
+                sp.set(key_hashes=key_hashes)
             mask = self._verify()
             if self.ndev is not None:
                 sp.set(ndev=self.ndev)
         if m is not None:
             m.batch_verify_seconds.with_labels(self.BACKEND).observe(sp.seconds)
             m.batch_size.with_labels(self.BACKEND).observe(n)
-            ok = sum(1 for b in mask if b)
+            ok = sum(mask)
             if ok:
                 m.signatures_verified.inc(ok)
             if n - ok:
@@ -478,23 +509,25 @@ class AdaptiveBatchVerifier(BatchVerifier):
         # overrides verify() (not _verify) on purpose: the inner
         # verifier's own verify() records the latency/size telemetry
         # under its leaf backend label — a template here would double
-        # count every batch. Adaptive only adds the routing decision.
-        n = len(self._items)
-        if any(len(pk) != 32 for _, _, pk in self._items):
+        # count every batch. Adaptive only adds the routing decision,
+        # and hands the leaf its batch whole: the list, not n add()s.
+        items = self._items
+        n = len(items)
+        if any(len(pk) != 32 for _, _, pk in items):
             # non-Ed25519 triples (BLS fast lane): the jax kernel is
             # Ed25519-specific — route straight to the CPU dispatcher
             inner = CPUBatchVerifier()
-            for msg, sig, pk in self._items:
-                inner.add(msg, sig, pk)
+            inner._items = items
             return inner.verify()
         cache = _sig_cache
+        looked = None
         if cache is not None and n:
-            # route on the CACHE-MISS count (stats-neutral peek): the
-            # leaf verifier will only dispatch the misses, so a mostly-
-            # cached batch must not pay the fixed device dispatch for a
-            # handful of stragglers
-            n = sum(1 for msg, sig, pk in self._items
-                    if cache.peek(cache.key(msg, sig, pk)) is None)
+            # route on the CACHE-MISS count: the leaf verifier will only
+            # dispatch the misses, so a mostly-cached batch must not pay
+            # the fixed device dispatch for a handful of stragglers.
+            # This is the batch's one counted look; the leaf gets it
+            looked = _look_up(cache, items)
+            n = len(looked[2])
         use_device = n >= self._min
         m = _metrics
         if m is not None:
@@ -502,8 +535,9 @@ class AdaptiveBatchVerifier(BatchVerifier):
                 "device" if use_device else "cpu").inc()
         inner = self._device_factory() if use_device else CPUBatchVerifier()
         inner._route = "device" if use_device else "cpu"
-        for msg, sig, pk in self._items:
-            inner.add(msg, sig, pk)
+        inner._items = items
+        inner._looked = looked
+        inner._ed25519_only = True
         return inner.verify()
 
 

@@ -555,17 +555,16 @@ class JAXBatchVerifier(BatchVerifier):
     def _verify(self):
         if not self._items:
             return []
-        if any(len(p) != 32 for _, _, p in self._items):
+        if not self._ed25519_only and any(
+                len(p) != 32 for _, _, p in self._items):
             # non-Ed25519 triples (e.g. 48-byte BLS pubkeys): this
             # kernel is Ed25519-specific — serial host dispatch instead
+            # (the adaptive router has looked already and sends none)
             from ..batch import CPUBatchVerifier
 
             inner = CPUBatchVerifier()
-            for m, s, p in self._items:
-                inner.add(m, s, p)
+            inner._items = self._items
             return inner._verify()
-        msgs = [m for m, _, _ in self._items]
-        sigs = [s for _, s, _ in self._items]
-        pks = [p for _, _, p in self._items]
+        msgs, sigs, pks = zip(*self._items)
         self.ndev = batch_devices(len(msgs))
         return verify_batch(msgs, sigs, pks, devices=self.ndev)

@@ -27,6 +27,14 @@ Design notes:
 - Bounded: per-shard capacity = capacity // shards; least-recently-used
   entries are evicted on insert. Hit/miss counters are maintained under
   the shard locks (exact, cheap) for bench/metrics reporting.
+- Once a batch: a batch of triples meets the cache once. keys() builds
+  one digest a triple, get_many() looks the whole batch up and
+  put_many() stores its verdicts, each taking a shard's lock once a
+  batch and not once a key (a 10,000-vote commit: 10,000 digests and at
+  most 2 x shards lock takes, where a key at a time cost 20,000 and
+  30,000). BatchVerifier.verify() is their caller; the adaptive
+  router's look IS the counted look, handed on to the leaf verifier and
+  not made again. get(), peek() and put() stay for a single key.
 """
 
 from __future__ import annotations
@@ -60,8 +68,23 @@ class SigCache:
         so msg ‖ sig ‖ pk is an injective encoding."""
         return hashlib.sha256(msg + sig + pk).digest()
 
+    @staticmethod
+    def keys(items) -> List[bytes]:
+        """key() of every (msg, sig, pk) triple of a batch, in order."""
+        sha256 = hashlib.sha256
+        return [sha256(b"".join(triple)).digest() for triple in items]
+
     def _idx(self, key: bytes) -> int:
         return key[0] % len(self._shards)
+
+    def _by_shard(self, keys):
+        """(shard, positions in `keys` that fall to it) for every shard
+        the batch touches; positions keep the batch's order."""
+        n = len(self._shards)
+        groups: List[list] = [[] for _ in range(n)]
+        for i, k in enumerate(keys):
+            groups[k[0] % n].append(i)
+        return [(s, g) for s, g in enumerate(groups) if g]
 
     def get(self, key: bytes) -> Optional[bool]:
         """Cached verdict for `key`, or None on miss. A hit refreshes
@@ -86,6 +109,32 @@ class SigCache:
         with self._locks[i]:
             return self._shards[i].get(key)
 
+    def get_many(self, keys) -> List[Optional[bool]]:
+        """get() of every key, in order, under one take of each shard's
+        lock: the same verdicts, LRU refreshes and hit counts as a get()
+        a key. A key that misses counts one miss however often the
+        batch repeats it (its repeats read None too): the batch verifies
+        it once."""
+        out: List[Optional[bool]] = [None] * len(keys)
+        for s, positions in self._by_shard(keys):
+            with self._locks[s]:
+                shard = self._shards[s]
+                get, refresh = shard.get, shard.move_to_end
+                missed = set()
+                hits = 0
+                for i in positions:
+                    k = keys[i]
+                    v = get(k)
+                    if v is None:
+                        missed.add(k)
+                    else:
+                        refresh(k)
+                        out[i] = v
+                        hits += 1
+                self._hits[s] += hits
+                self._misses[s] += len(missed)
+        return out
+
     def put(self, key: bytes, verdict: bool) -> None:
         i = self._idx(key)
         with self._locks[i]:
@@ -94,6 +143,22 @@ class SigCache:
             shard.move_to_end(key)
             while len(shard) > self._per_shard_cap:
                 shard.popitem(last=False)
+
+    def put_many(self, keys, verdicts) -> None:
+        """put() of every (key, verdict) pair, in order, under one take
+        of each shard's lock: the same entries, LRU order and evictions
+        as a put() a key."""
+        cap = self._per_shard_cap
+        for s, positions in self._by_shard(keys):
+            with self._locks[s]:
+                shard = self._shards[s]
+                refresh = shard.move_to_end
+                for i in positions:
+                    k = keys[i]
+                    shard[k] = bool(verdicts[i])
+                    refresh(k)
+                    while len(shard) > cap:
+                        shard.popitem(last=False)
 
     def __len__(self) -> int:
         return sum(len(s) for s in self._shards)

@@ -85,6 +85,9 @@ class CryptoMetrics:
     # cache vs dispatched to a backend
     sig_cache_hits: object = NOP
     sig_cache_misses: object = NOP
+    # digests built to key triples for that cache: one a triple looked
+    # up, whoever looks (router or leaf verifier)
+    sig_cache_key_hashes: object = NOP
     # async dispatch (verify_async): batches submitted but not completed
     inflight_batches: object = NOP
     # wall time a caller overlapped with an in-flight async batch
@@ -725,6 +728,10 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
         sig_cache_misses=r.counter(
             f"{ns}_crypto_sig_cache_misses_total",
             "Triples that missed the cache and reached a backend."),
+        sig_cache_key_hashes=r.counter(
+            f"{ns}_crypto_sig_cache_key_hashes_total",
+            "Digests built to key triples for the verified-signature "
+            "cache: one for every triple looked up."),
         inflight_batches=r.gauge(
             f"{ns}_crypto_inflight_batches",
             "Async verify batches dispatched and not yet completed."),

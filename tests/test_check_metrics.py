@@ -145,3 +145,12 @@ def test_live_node_scrape_passes_strict_check():
              for (name, labels) in step["samples"]
              if name.endswith("_count")}
     assert {"propose", "prevote", "precommit", "commit"} <= steps
+    # PR 37: a digest for every triple looked up in the verified-
+    # signature cache, and no more (no batch here repeats a triple)
+
+    def total(family):
+        return sum(fams[f"tendermint_{family}"]["samples"].values())
+
+    assert total("crypto_sig_cache_key_hashes_total") == (
+        total("crypto_sig_cache_hits_total")
+        + total("crypto_sig_cache_misses_total")) > 0
