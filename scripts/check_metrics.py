@@ -353,6 +353,13 @@ REQUIRED_FAMILIES = (
     "crypto_sig_cache_key_hashes_total",
 )
 
+# families served under their own names, with no namespace: PR-38 the
+# process's CPU, named and meant as a Prometheus client's process
+# collector serves it (what a Go node's default registry exposes)
+PROCESS_FAMILIES = (
+    "process_cpu_seconds_total",
+)
+
 # ...and of those, the hot-path families that must have RECORDED samples
 # after blocks committed — HELP/TYPE render for registered metrics even
 # with no children, so a declaration check alone would pass with the
@@ -373,6 +380,7 @@ def check_body(body: str, namespace: str = "tendermint",
     families = parse_exposition(body)
     missing = [f"{namespace}_{f}" for f in REQUIRED_FAMILIES
                if f"{namespace}_{f}" not in families]
+    missing += [f for f in PROCESS_FAMILIES if f not in families]
     if missing:
         raise ExpositionError(f"missing metric families: {missing}")
     # help-text lint: every registered family must document itself —
@@ -431,7 +439,7 @@ def readme_metric_rows(readme_text: str) -> list:
 
 
 def check_readme_drift(readme_text: str,
-                       families=REQUIRED_FAMILIES) -> list:
+                       families=REQUIRED_FAMILIES + PROCESS_FAMILIES) -> list:
     """Both directions of REQUIRED_FAMILIES <-> README drift; returns a
     list of human-readable problems (empty = in sync)."""
     rows = readme_metric_rows(readme_text)

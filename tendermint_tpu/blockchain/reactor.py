@@ -266,7 +266,9 @@ class BlockchainReactor(Reactor):
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:174-214."""
         tracer = tracing.get_tracer()
-        t_recv = time.perf_counter_ns() if tracer.enabled else 0
+        t_recv = cpu_recv = 0
+        if tracer.enabled:
+            t_recv, cpu_recv = time.perf_counter_ns(), time.thread_time_ns()
         obj = serde.unpack(msg_bytes)
         kind = obj[0]
         if self.switch is not None and peer.is_running():
@@ -297,9 +299,11 @@ class BlockchainReactor(Reactor):
                 height = block.header.height
                 if len(self._recv_cause) > 4096:  # blocks never applied
                     self._recv_cause.clear()
+                cpu = time.thread_time_ns() - cpu_recv
                 self._recv_cause[height] = tracer.record(
                     "p2p.recvBlock", t_recv, time.perf_counter_ns(), "p2p",
-                    request=("block", height), height=height,
+                    request=("block", height), cpu_ns=cpu,
+                    cpu_start_ns=cpu_recv, height=height,
                     txs=len(block.data.txs), bytes=len(msg_bytes),
                     peer=peer.id[:8])
         elif kind == "no_block_response":

@@ -314,15 +314,25 @@ def _look_up(cache, items):
     triple, built here and nowhere else, and one counted get_many().
     Returns (keys, verdicts, miss_idx): verdicts holds None where the
     backend has to answer, miss_idx the position of every such triple's
-    first occurrence in the batch."""
-    keys = cache.keys(items)
-    verdicts = cache.get_many(keys)
-    seen = set()
-    miss_idx = []
-    for i, v in enumerate(verdicts):
-        if v is None and keys[i] not in seen:
-            seen.add(keys[i])
-            miss_idx.append(i)
+    first occurrence in the batch. The pass is one `crypto.cacheLook`
+    span: on a dispatch thread it names the span that called
+    verify_async, as crypto.batchVerify does, and lies inside
+    crypto.dispatchWait."""
+    tracer = tracing.get_tracer()
+    # read, not cleared: _verify_instrumented still takes it
+    fut = getattr(_dispatched, "fut", None) if tracer.enabled else None
+    with tracer.span("crypto.cacheLook", "crypto",
+                     cause=fut._cause if fut is not None else None,
+                     n=len(items)) as sp:
+        keys = cache.keys(items)
+        verdicts = cache.get_many(keys)
+        seen = set()
+        miss_idx = []
+        for i, v in enumerate(verdicts):
+            if v is None and keys[i] not in seen:
+                seen.add(keys[i])
+                miss_idx.append(i)
+        sp.set(misses=len(miss_idx))
     m = _metrics
     if m is not None:
         m.sig_cache_key_hashes.inc(len(keys))

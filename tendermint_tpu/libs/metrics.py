@@ -9,6 +9,7 @@ text exposition format v0.0.4, and a tiny HTTP server.
 from __future__ import annotations
 
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -219,6 +220,26 @@ class Histogram(_Metric):
             out.append(f"{self.name}_sum{lf} {sums[labels]:g}")
             out.append(f"{self.name}_count{lf} {total}")
         return out
+
+
+class ProcessCPU(_Metric):
+    """`process_cpu_seconds_total`: user + system CPU seconds of the whole
+    process, every thread, ended ones included (time.process_time()),
+    read when the registry renders: nothing between scrapes. Name and
+    meaning are a Prometheus client's process collector's (what a Go
+    node's default registry serves), so it carries no namespace."""
+
+    TYPE = "counter"
+
+    def __init__(self):
+        super().__init__("process_cpu_seconds_total",
+                         "Total user and system CPU time spent in seconds.",
+                         ())
+
+    def render(self) -> List[str]:
+        return [f"# HELP {self.name} {self.help}",
+                f"# TYPE {self.name} {self.TYPE}",
+                f"{self.name} {time.process_time()!r}"]
 
 
 class Registry:

@@ -21,9 +21,9 @@ always-available spans (README "Spans" lists the names):
   is never exported without its enclosing span) and a stuck thread's
   open span shows up instead of silently missing.
 - Lock-free on the hot path: open spans live on a per-thread stack, so
-  entering a span takes one clock read and no lock, leaving it one
-  append to the ring. The lock guards only a thread's first span,
-  enable/disable/clear and export.
+  entering a span takes two clock reads (wall, thread CPU) and no lock,
+  leaving it the same two and one append to the ring. The lock guards
+  only a thread's first span, enable/disable/clear and export.
 - Near-zero overhead when disabled: `span()` returns one shared no-op
   context manager — no allocation, no clock read, no lock.
 - One clock with the device: while enabled, each span is also entered
@@ -36,6 +36,12 @@ always-available spans (README "Spans" lists the names):
   takes over a millisecond. What those collections walk is kept small
   by `hold_frozen_heap()` (below the recorder), recorded as
   `runtime.gcFreeze`.
+- Tells "ran" from "waited to run": while enabled, a span also reads
+  its thread's CPU clock (`time.thread_time_ns`) beside each wall
+  reading, so `cpu_ns` is what the span's own thread ran, children and
+  collections inside it included, and `dur_ns - cpu_ns` the time it was
+  off the CPU: waiting for the interpreter lock, a blocking system call
+  (socket, fsync), an event or the device.
 
 Export is Chrome trace event format ("X" complete events, µs units),
 loadable in chrome://tracing or https://ui.perfetto.dev, served from
@@ -80,7 +86,9 @@ class SpanRecord:
     from another thread (0: a root). `request` is shared by everything
     done for one unit of work — ("block", height), ("post", n),
     ("drain", n), ("batch", n) — inherited from the parent unless
-    given."""
+    given. `cpu_ns` is the CPU time its own thread spent between entry
+    and exit (time.thread_time_ns; None where the span measures nothing
+    running, or crosses threads), `cpu_start_ns` that clock at entry."""
 
     name: str
     cat: str
@@ -92,6 +100,8 @@ class SpanRecord:
     span_id: int = 0
     parent_id: int = 0
     request: Optional[tuple] = None
+    cpu_ns: Optional[int] = None
+    cpu_start_ns: Optional[int] = None
 
     @property
     def end_ns(self) -> int:
@@ -143,19 +153,21 @@ class _ThreadState:
     finished ones. Only the owning thread writes it, so entering and
     leaving a span take no lock."""
 
-    __slots__ = ("stack", "ident", "name", "appended")
+    __slots__ = ("stack", "ident", "native_id", "name", "appended")
 
     def __init__(self):
         t = threading.current_thread()
         self.stack: list = []
         self.ident = t.ident or 0
+        self.native_id = threading.get_native_id()
         self.name = t.name
         self.appended = 0
 
 
 class _Span:
     __slots__ = ("_tracer", "_state", "_name", "_cat", "_args", "_start_ns",
-                 "_end_ns", "_annotation", "span_id", "parent_id", "request")
+                 "_end_ns", "_cpu0", "_annotation", "span_id", "parent_id",
+                 "request")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args,
                  cause: Optional[Cause], request: Optional[tuple]):
@@ -188,9 +200,12 @@ class _Span:
         else:
             self._annotation = None
         self._start_ns = time.perf_counter_ns()
+        # the CPU readings lie inside the wall ones: cpu_ns <= dur_ns
+        self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        cpu = time.thread_time_ns() - self._cpu0
         end = self._end_ns = time.perf_counter_ns()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
@@ -204,7 +219,7 @@ class _Span:
         self._tracer._buf.append(SpanRecord(
             self._name, self._cat, self._start_ns, end - self._start_ns,
             st.ident, st.name, self._args or None,
-            self.span_id, self.parent_id, self.request))
+            self.span_id, self.parent_id, self.request, cpu, self._cpu0))
         return False
 
     def set(self, **args) -> None:
@@ -248,7 +263,7 @@ class Tracer:
         self._enabled = enabled
         self._annotation = None
         self._gc_hook = None
-        self._gc_t0 = 0
+        self._gc_t0 = self._gc_cpu0 = 0
         # epoch pins perf_counter to the wall clock once, so exported
         # timestamps are comparable across processes' traces
         self._epoch_wall_us = time.time() * 1e6
@@ -356,11 +371,16 @@ class Tracer:
 
     def record(self, name: str, start_ns: int, end_ns: int, cat: str = "", *,
                cause: Optional[Cause] = None, request: Optional[tuple] = None,
+               cpu_ns: Optional[int] = None,
+               cpu_start_ns: Optional[int] = None,
                **args) -> Optional[Cause]:
         """A span whose two clock readings the caller already took: a
         wait measured from the far side of a queue, or work that is only
         worth a span once it is known what it was. Parent and request
-        default as span()'s do. Returns the new span's cause()."""
+        default as span()'s do. A caller that read this thread's CPU
+        clock at both ends passes `cpu_ns` (their difference) and
+        `cpu_start_ns`; a wait that crosses threads leaves them None.
+        Returns the new span's cause()."""
         if not self._enabled:
             return None
         st = self._thread_state()
@@ -373,17 +393,21 @@ class Tracer:
         st.appended += 1
         self._buf.append(SpanRecord(
             name, cat, start_ns, max(0, end_ns - start_ns), st.ident, st.name,
-            args or None, span_id, parent_id, request))
+            args or None, span_id, parent_id, request, cpu_ns, cpu_start_ns))
         return (span_id, request)
 
     def _on_gc(self, phase: str, info: dict) -> None:
-        # one collection runs at a time, process-wide: one start suffices
+        # one collection runs at a time, process-wide, on the thread that
+        # triggered it: one start suffices, and both CPU readings are its
         if phase == "start":
             self._gc_t0 = time.perf_counter_ns()
+            self._gc_cpu0 = time.thread_time_ns()
             return
+        cpu = time.thread_time_ns()
         t0, now = self._gc_t0, time.perf_counter_ns()
         if t0 and (info["generation"] == 2 or now - t0 >= GC_SPAN_FLOOR_NS):
             self.record("runtime.gc", t0, now, "runtime",
+                        cpu_ns=cpu - self._gc_cpu0, cpu_start_ns=self._gc_cpu0,
                         generation=info["generation"],
                         collected=info["collected"])
 
@@ -419,12 +443,15 @@ class Tracer:
         (complete) events plus thread-name metadata, ts/dur in µs. Each
         event also carries `span_id`, `parent_id` and, where it has one,
         `request` beside `args`; `dropped` counts what the ring lost.
+        An event with a thread CPU reading carries the format's own
+        `tts` / `tdur` (µs of its thread's CPU clock: "Thread duration"
+        in Perfetto); `dur - tdur` is what it waited off the CPU.
 
         Spans still open at snapshot time are included too, with
-        `dur = now - start` and `args.inflight = true`. The open spans
-        are read before the finished ones, so a finished child always
-        has its enclosing span present — finished in the buffer, or
-        synthesized as in-flight if it closed in between."""
+        `dur = now - start`, `tdur` up to now and `args.inflight = true`.
+        The open spans are read before the finished ones, so a finished
+        child always has its enclosing span present — finished in the
+        buffer, or synthesized as in-flight if it closed in between."""
         pid = os.getpid()
         with self._lock:
             states = list(self._threads.values())
@@ -437,7 +464,7 @@ class Tracer:
         seen_ids = set()
 
         def event(name, cat, start_ns, dur_ns, tid, args, span_id,
-                  parent_id, request) -> dict:
+                  parent_id, request, cpu_start_ns, cpu_ns) -> dict:
             ev = {
                 "name": name,
                 "cat": cat or "default",
@@ -451,6 +478,10 @@ class Tracer:
             }
             if request is not None:
                 ev["request"] = list(request)
+            if cpu_ns is not None:
+                ev["tdur"] = cpu_ns / 1e3
+                if cpu_start_ns is not None:
+                    ev["tts"] = cpu_start_ns / 1e3
             if args:
                 ev["args"] = args
             return ev
@@ -460,16 +491,21 @@ class Tracer:
             seen_ids.add(rec.span_id)
             events.append(event(
                 rec.name, rec.cat, rec.start_ns, rec.dur_ns, rec.thread_id,
-                rec.args, rec.span_id, rec.parent_id, rec.request))
+                rec.args, rec.span_id, rec.parent_id, rec.request,
+                rec.cpu_start_ns, rec.cpu_ns))
         for st, sp in open_spans:
             start_ns = sp._start_ns
             if not start_ns or sp.span_id in seen_ids:
                 continue  # not yet started, or closed since
             seen_threads.setdefault(st.ident, st.name)
+            cpu0 = getattr(sp, "_cpu0", None)  # unset: entering right now
+            now_cpu = _thread_cpu_ns(st.native_id)
+            cpu = (now_cpu - cpu0 if cpu0 is not None and now_cpu is not None
+                   and now_cpu >= cpu0 else None)
             events.append(event(
                 sp._name, sp._cat, start_ns, now_ns - start_ns, st.ident,
                 dict(sp._args or {}, inflight=True), sp.span_id,
-                sp.parent_id, sp.request))
+                sp.parent_id, sp.request, cpu0, cpu))
         meta = [
             {
                 "name": "thread_name",
@@ -504,6 +540,20 @@ class Tracer:
                     "args": dict(rec.args),
                 })
         return out
+
+
+def _thread_cpu_ns(native_id: int) -> Optional[int]:
+    """The CPU clock of one of this process's threads, by its kernel id:
+    Linux's per-thread CPU clock id, built as glibc's
+    pthread_getcpuclockid builds it, which the kernel answers for a live
+    thread of this process only (a thread that has ended: None, never a
+    read of freed memory); None off Linux."""
+    if native_id == threading.get_native_id():
+        return time.thread_time_ns()
+    try:
+        return time.clock_gettime_ns((~native_id << 3) | 6)
+    except (OSError, OverflowError, AttributeError):
+        return None
 
 
 def self_times(records: List[SpanRecord]) -> Dict[int, int]:

@@ -12,7 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
-from .libs.metrics import Registry
+from .libs.metrics import ProcessCPU, Registry
 
 
 class _Nop:
@@ -471,6 +471,7 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
     """DefaultMetricsProvider (each reference metrics.go
     PrometheusMetrics constructor)."""
     r = Registry()
+    r.register(ProcessCPU())
     ns = namespace
     cons = ConsensusMetrics(
         height=r.gauge(f"{ns}_consensus_height",

@@ -154,3 +154,41 @@ def test_live_node_scrape_passes_strict_check():
     assert total("crypto_sig_cache_key_hashes_total") == (
         total("crypto_sig_cache_hits_total")
         + total("crypto_sig_cache_misses_total")) > 0
+    # PR 38: the process's CPU, under its own name, no namespace
+    cpu = fams["process_cpu_seconds_total"]
+    assert cpu["type"] == "counter"
+    assert cpu["samples"][("process_cpu_seconds_total", ())] > 0
+
+
+def test_process_cpu_passes_the_strict_parse_and_never_falls():
+    """process_cpu_seconds_total is read when the registry renders: two
+    scrapes a little CPU apart both parse strictly, and the second is
+    not below the first."""
+    from tendermint_tpu.metrics import prometheus_metrics
+
+    r = prometheus_metrics("tendermint").registry
+    key = ("process_cpu_seconds_total", ())
+    first = cm.parse_exposition(r.render())["process_cpu_seconds_total"]
+    sum(i * i for i in range(200_000))
+    second = cm.parse_exposition(r.render())["process_cpu_seconds_total"]
+    assert first["type"] == second["type"] == "counter"
+    assert first["help"]
+    assert 0 < first["samples"][key] <= second["samples"][key]
+    # a registry-wide prune by label leaves it alone
+    assert r.remove_labels(peer_id="x") >= 0
+    assert "process_cpu_seconds_total " in r.render()
+
+
+def test_check_body_requires_the_process_cpu_family():
+    from tendermint_tpu.metrics import prometheus_metrics
+
+    m = prometheus_metrics("tendermint")
+    m.crypto.batch_verify_seconds.with_labels("cpu").observe(0.001)
+    m.crypto.signatures_verified.inc()
+    m.consensus.step_duration.with_labels("propose").observe(0.001)
+    body = m.registry.render()
+    cm.check_body(body)
+    lines = body.splitlines(keepends=True)
+    without = "".join(l for l in lines if "process_cpu_seconds_total" not in l)
+    with pytest.raises(cm.ExpositionError, match="process_cpu_seconds_total"):
+        cm.check_body(without)

@@ -769,3 +769,298 @@ def test_batch_post_and_block_span_tree(tmp_path):
     drained = [d for d in ev["txindex.drain"]]
     assert sum(d.args["txs"] for d in drained) == 3
     assert all(d.request == ("block", d.args["height"]) for d in drained)
+
+
+# --- thread CPU beside each span, the cache pass as a span (PR 38) ----------
+
+# a thread CPU reading lies inside its span's wall readings; the two
+# clocks come from different kernel sources, so allow them a grain apart
+GRAIN_NS = 2 * max(1, int(1e9 * time.get_clock_info("thread_time").resolution),
+                   int(1e9 * time.get_clock_info("perf_counter").resolution)) + 50_000
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_every_span_reads_its_threads_cpu_inside_its_wall():
+    t = Tracer(enabled=True)
+    with t.span("outer"):
+        with t.span("sleep"):
+            time.sleep(0.05)
+        with t.timed("busy") as sp:
+            _spin(0.03)
+    assert sp.seconds >= 0.03
+    ev = {e.name: e for e in t.events()}
+    for e in ev.values():
+        assert e.cpu_ns is not None and e.cpu_start_ns is not None
+        assert 0 <= e.cpu_ns <= e.dur_ns + GRAIN_NS, e
+    # a sleep is off the CPU; no floor is asserted on what a loaded
+    # host lets a busy loop run, only that it ran more than the sleep
+    assert ev["sleep"].cpu_ns < ev["sleep"].dur_ns / 2
+    assert ev["busy"].cpu_ns > ev["sleep"].cpu_ns
+    # a child's CPU is part of its parent's, on the same thread
+    assert ev["outer"].cpu_ns + GRAIN_NS >= ev["sleep"].cpu_ns + ev["busy"].cpu_ns
+    assert ev["outer"].cpu_start_ns <= ev["sleep"].cpu_start_ns
+
+
+def test_the_recorder_off_never_reads_the_thread_clock(monkeypatch):
+    from tendermint_tpu.crypto import batch as B
+    from tendermint_tpu.crypto.keys import PrivKeyEd25519
+    from tendermint_tpu.crypto.sigcache import SigCache
+
+    def boom():
+        raise AssertionError("time.thread_time_ns read with the recorder off")
+
+    priv = PrivKeyEd25519.generate()
+    v = B.CPUBatchVerifier()
+    v.add(b"m", priv.sign(b"m"), priv.pub_key().bytes())
+    prev = B._sig_cache
+    B.set_sig_cache(SigCache(64))
+    monkeypatch.setattr(time, "thread_time_ns", boom)
+    try:
+        t = Tracer()
+        with t.span("a") as sp:
+            sp.set(n=1)
+        with t.timed("b") as sp:
+            pass
+        assert sp.seconds >= 0
+        assert t.record("c", 0, 1) is None
+        with tracing.span("d"), tracing.timed("e"):
+            pass
+        assert not get_tracer().enabled
+        assert v.verify() == [True] and v.verify() == [True]  # miss, then hit
+    finally:
+        B.set_sig_cache(prev)
+    assert t.events() == []
+
+
+def test_record_takes_cpu_ns_and_leaves_it_none_unless_given():
+    t = Tracer(enabled=True)
+    t.record("ran", 100, 900, cpu_ns=700, cpu_start_ns=40, n=1)
+    t.record("waited", 100, 900)
+    ev = {e.name: e for e in t.events()}
+    assert (ev["ran"].cpu_ns, ev["ran"].cpu_start_ns) == (700, 40)
+    assert ev["ran"].args == {"n": 1}  # not among the args
+    assert ev["waited"].cpu_ns is None and ev["waited"].cpu_start_ns is None
+    # a positional record of the older fields still builds
+    r = tracing.SpanRecord("x", "", 0, 1, 1, "t", None, 1, 0, None)
+    assert r.cpu_ns is None
+
+
+def test_runtime_gc_carries_the_collecting_threads_cpu():
+    t = Tracer()
+    t.enable()
+    try:
+        with t.span("outer"):
+            gc.collect()
+    finally:
+        t.disable()
+    full = [e for e in _by_name(t.events())["runtime.gc"]
+            if e.args["generation"] == 2]
+    assert full
+    for e in full:
+        assert e.cpu_ns is not None and 0 <= e.cpu_ns <= e.dur_ns + GRAIN_NS
+        assert set(e.args) == {"generation", "collected"}
+
+
+def test_chrome_trace_carries_the_thread_clock_where_there_is_one():
+    t = Tracer(enabled=True)
+    with t.span("done"):
+        _spin(0.002)
+    t.record("wait", 1, 2)
+    hold, ready = threading.Event(), threading.Event()
+
+    def other():
+        with t.span("elsewhere"):
+            _spin(0.01)
+            ready.set()
+            hold.wait(5)
+
+    th = threading.Thread(target=other)
+    th.start()
+    try:
+        assert ready.wait(5)
+        with t.span("open"):
+            _spin(0.002)
+            doc = t.chrome_trace()
+    finally:
+        hold.set()
+        th.join(5)
+    spans = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+    done = spans["done"]
+    assert 0 < done["tdur"] <= done["dur"] + GRAIN_NS / 1e3
+    assert "tts" in done
+    assert "tdur" not in spans["wait"] and "tts" not in spans["wait"]
+    # open spans: their thread's CPU up to the snapshot, this thread's
+    # and another's alike
+    for name in ("open", "elsewhere"):
+        sp = spans[name]
+        assert sp["args"]["inflight"] is True
+        assert 0 < sp["tdur"] <= sp["dur"] + GRAIN_NS / 1e3, sp
+    assert spans["elsewhere"]["tdur"] >= 5_000  # it spun 10 ms before
+
+
+def _signed(n, tag=b""):
+    from tendermint_tpu.crypto.keys import PrivKeyEd25519
+
+    priv = PrivKeyEd25519.generate()
+    pk = priv.pub_key().bytes()
+    return [(b"%s%d" % (tag, i), priv.sign(b"%s%d" % (tag, i)), pk)
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("router", [False, True])
+def test_cache_look_is_one_span_a_batch_under_the_submitter(global_tracer,
+                                                            router):
+    from tendermint_tpu.crypto import batch as B
+    from tendermint_tpu.crypto.sigcache import SigCache
+
+    def verifier(items):
+        v = (B.AdaptiveBatchVerifier(B.CPUBatchVerifier, min_device_batch=1)
+             if router else B.CPUBatchVerifier())
+        for it in items:
+            v.add(*it)
+        return v
+
+    items = _signed(3)
+    prev = B._sig_cache
+    B.set_sig_cache(SigCache(1024))
+    try:
+        with global_tracer.span("caller", request=("block", 4)) as caller:
+            fut = verifier(items + items[:1]).verify_async()
+        assert fut.result(timeout=10) == [True] * 4
+        with global_tracer.span("again"):  # all cached: a look, no backend
+            assert verifier(items).verify() == [True] * 3
+    finally:
+        B.set_sig_cache(prev)
+    ev = _by_name(global_tracer.events())
+    first, second = ev["crypto.cacheLook"]
+    (bv,), (wait,) = ev["crypto.batchVerify"], ev["crypto.dispatchWait"]
+    assert first.parent_id == caller.span_id and first.request == ("block", 4)
+    assert first.thread_id == bv.thread_id != ev["caller"][0].thread_id
+    assert first.args == {"n": 4, "misses": 3}
+    # the pass lies inside the wait that ends where the batch opens
+    assert wait.start_ns <= first.start_ns and first.end_ns <= wait.end_ns
+    assert bv.args["n"] == 3 and bv.args["key_hashes"] == 4
+    assert second.parent_id == ev["again"][0].span_id
+    assert second.args == {"n": 3, "misses": 0}
+    assert first.cpu_ns is not None
+
+
+def test_no_cache_look_with_the_cache_off(global_tracer):
+    from tendermint_tpu.crypto import batch as B
+
+    assert B._sig_cache is None
+    v = B.CPUBatchVerifier()
+    for it in _signed(2):
+        v.add(*it)
+    assert v.verify_async().result(timeout=10) == [True] * 2
+    ev = _by_name(global_tracer.events())
+    assert "crypto.cacheLook" not in ev and len(ev["crypto.batchVerify"]) == 1
+
+
+def test_p2p_recv_block_carries_the_receive_threads_cpu(global_tracer):
+    sys.path.insert(0, os.path.dirname(__file__))
+    from test_crypto_async import _make_reactor
+
+    from tendermint_tpu.blockchain.reactor import BLOCKCHAIN_CHANNEL
+    from tendermint_tpu.types import serde
+
+    class Peer:
+        id = "p1"
+
+        def is_running(self):
+            return False
+
+    reactor, _, _, _, _ = _make_reactor(nblocks=1)
+    req = reactor.pool._requesters[1]
+    block, req.block = req.block, None
+    reactor.receive(BLOCKCHAIN_CHANNEL, Peer(),
+                    serde.pack(["block_response", serde.block_obj(block)]))
+    (rb,) = _by_name(global_tracer.events())["p2p.recvBlock"]
+    assert rb.args["height"] == 1 and rb.request == ("block", 1)
+    assert rb.cpu_ns is not None and 0 <= rb.cpu_ns <= rb.dur_ns + GRAIN_NS
+
+
+# --- the benchmark's readers of the thread clock and the process's CPU ------
+
+
+def _run(records, window=(0, 1000)):
+    import types
+
+    from benchmark.harness import trace as tr
+
+    trace = tr.Trace()
+    trace.sync_ns = 0
+    return types.SimpleNamespace(trace=trace, _spans_raw=list(records),
+                                 _sync_perf_ns=0,
+                                 trace_window=(window[0] * 10**6,
+                                               window[1] * 10**6))
+
+
+def _rec(name, start_ms, dur_ms, cpu_ms, span_id, parent_id=0, thread=1,
+         **args):
+    ms = 10**6
+    return tracing.SpanRecord(
+        name, "", start_ms * ms, dur_ms * ms, thread, f"t{thread}",
+        args or None, span_id, parent_id, None,
+        None if cpu_ms is None else int(cpu_ms * ms))
+
+
+SPANS = [
+    _rec("verify.pack", 0, 100, 20, 1),
+    _rec("runtime.gc", 50, 10, 8, 2, 1),
+    _rec("verify.pack", 200, 50, 10, 3),
+    _rec("other.thread", 210, 30, 30, 4, 3, thread=2),
+    _rec("crypto.dispatchWait", 190, 10, None, 5, 3),
+    _rec("rpc.handle", 300, 40, 4, 6, n=2),
+]
+
+
+@pytest.mark.parametrize("params,want", [
+    ({"name": "verify.pack", "what": "cpu_ms"}, 15.0),
+    # less the collection under it, not the other thread's child
+    ({"name": "verify.pack", "what": "self_cpu_ms"}, (12 + 10) / 2),
+    ({"name": "rpc.handle", "what": "cpu_ms", "per": "arg:n", "scale": 1000.0},
+     2000.0),
+    ({"name": "crypto.dispatchWait", "what": "cpu_ms"}, None),  # no reading
+    ({"name": "no.such.span", "what": "cpu_ms"}, None),
+])
+def test_span_cpu_reads_the_thread_clock_of_chosen_spans(params, want):
+    from benchmark.readers import span_cpu
+
+    got = span_cpu.read(params, _run(SPANS))
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_span_cpu_reads_null_at_a_program_without_the_field():
+    from benchmark.readers import span_cpu
+
+    old = [tracing.SpanRecord(r.name, "", r.start_ns, r.dur_ns, 1, "t", r.args,
+                              r.span_id, r.parent_id) for r in SPANS]
+    assert span_cpu.read({"name": "verify.pack", "what": "cpu_ms"},
+                         _run(old)) is None
+    assert span_cpu.read({"name": "verify.pack", "what": "cpu_ms"},
+                         _run(SPANS, window=(150, 400))) == pytest.approx(10.0)
+
+
+def test_prom_rate_per_window_and_per_fact():
+    import types
+
+    from benchmark.readers import prom_rate
+
+    fam = "process_cpu_seconds_total"
+    run = types.SimpleNamespace(
+        prom=({(fam, ()): 10.0, ("x", ()): 1.0}, {(fam, ()): 25.0, ("x", ()): 1.0}),
+        t_open=100.0, t_close=120.0, facts={"blocks": 50, "none": 0})
+    assert prom_rate.read({"family": fam, "per": "window"}, run) == pytest.approx(75.0)
+    assert prom_rate.read({"family": fam, "per": "fact:blocks"},
+                          run) == pytest.approx(300.0)
+    assert prom_rate.read({"family": fam, "per": "fact:none"}, run) is None
+    # a program without the family reads null, not 0
+    assert prom_rate.read({"family": "absent_total", "per": "window"}, run) is None
+    run.prom = ({("x", ()): 1.0}, {(fam, ()): 25.0})
+    assert prom_rate.read({"family": fam, "per": "window"}, run) is None
