@@ -351,6 +351,11 @@ REQUIRED_FAMILIES = (
     # PR-37 a batch meets the verified-signature cache once: a digest a
     # triple looked up (live wherever the cache is on and a batch ran)
     "crypto_sig_cache_key_hashes_total",
+    # the live vote path (declaration presence: a node that receives
+    # no gossiped vote records nothing)
+    "consensus_votes_received_total",
+    "consensus_votes_total",
+    "consensus_vote_run_size",
 )
 
 # families served under their own names, with no namespace: PR-38 the

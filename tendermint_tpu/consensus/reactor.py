@@ -25,6 +25,7 @@ import time
 from typing import Dict, Optional
 
 from ..libs.bit_array import BitArray
+from ..metrics import ConsensusMetrics
 from ..p2p.base_reactor import ChannelDescriptor, Reactor
 from ..types import serde
 from ..types.basic import BlockID, PartSetHeader
@@ -52,6 +53,8 @@ from .messages import (
 )
 
 LOG = logging.getLogger("consensus.reactor")
+
+NOP_CONSENSUS = ConsensusMetrics()
 
 STATE_CHANNEL = 0x20
 DATA_CHANNEL = 0x21
@@ -483,6 +486,10 @@ class ConsensusReactor(Reactor):
         super().__init__("ConsensusReactor")
         self.cs = consensus_state
         self.fast_sync = fast_sync
+        # a bare test stub may carry no metrics: count nothing then
+        received = getattr(consensus_state, "metrics", NOP_CONSENSUS).votes_received
+        self._count_received = {VOTE_TYPE_PREVOTE: received.with_labels("prevote").inc,
+                                VOTE_TYPE_PRECOMMIT: received.with_labels("precommit").inc}
         self._peer_states: Dict[str, PeerState] = {}
         self._peer_threads: Dict[str, list] = {}
         self._stop = threading.Event()
@@ -681,6 +688,9 @@ class ConsensusReactor(Reactor):
             if self.fast_sync:
                 return
             if isinstance(msg, VoteMessage):
+                count = self._count_received.get(msg.vote.type)
+                if count is not None:
+                    count()
                 rs = self.cs.get_round_state()
                 n = len(rs.validators) if rs.validators else 0
                 ps.ensure_vote_bit_arrays(rs.height, n)

@@ -21,6 +21,17 @@ batch of 1 → serial CPU verify, zero added latency; heavy traffic
 for fast sync) rides the same engine. With [crypto] async_dispatch on,
 the drained run's batch is dispatched (verify_async) BEFORE its WAL
 writes, so the fsync overlaps the device round trip.
+
+What the vote path records (README "Spans"): a drained run is one
+`consensus.voteRun` span, from the drain to the last vote's tally, and
+one sample of `tendermint_consensus_vote_run_size`. Inside it,
+`consensus.preverifyVotes` covers only the wait for the batch's mask
+(`finish()`), not the collection of the triples, which the run does
+before its WAL writes. Every vote the tally meets counts once in
+`tendermint_consensus_votes_total{result}`: added, bad_signature,
+duplicate (that validator's vote for the height, round and type is
+already in: the same again, or a conflicting one, which becomes
+evidence), unmapped (no height, round or validator to hold it).
 """
 
 from __future__ import annotations
@@ -50,7 +61,7 @@ from ..types.basic import (
 from ..types.block import Block, Commit
 from ..types.event_bus import EventBus
 from ..types.part_set import PartSet
-from ..types.vote_set import ErrVoteInvalid, VoteSet
+from ..types.vote_set import ErrVoteBadSignature, ErrVoteInvalid, VoteSet
 from . import cstypes
 from .cstypes import (
     STEP_COMMIT,
@@ -108,6 +119,9 @@ class ConsensusState:
         from ..types.event_bus import NopEventBus
 
         self.metrics = metrics if metrics is not None else ConsensusMetrics()
+        # what the tally made of each vote: one bound counter a result
+        self._count_vote = {r: self.metrics.votes.with_labels(r).inc for r in
+                            ("added", "bad_signature", "duplicate", "unmapped")}
 
         self.mempool = mempool
         self.evpool = evpool
@@ -429,37 +443,7 @@ class ConsensusState:
                     continue
                 try:
                     if item[0] == "msg" and isinstance(item[1][1], VoteMessage):
-                        votes = [item[1]]
-                        tail = None
-                        while len(votes) < MAX_VOTE_BATCH:
-                            try:
-                                nxt = self._queue.get_nowait()
-                            except queue.Empty:
-                                break
-                            if nxt[0] == "msg" and isinstance(nxt[1][1], VoteMessage):
-                                votes.append(nxt[1])
-                            else:
-                                tail = nxt
-                                break
-                        # dispatch the batched signature verification
-                        # BEFORE the WAL writes: the (fsync'd) write of
-                        # the drained run overlaps the device round trip
-                        finish = None
-                        if len(votes) > 1:
-                            finish = self._preverify_votes_begin(
-                                [m.vote for _, m in votes])
-                        try:
-                            for peer_id, msg in votes:
-                                if peer_id == "":
-                                    self.wal.write_sync((peer_id, msg))  # :604-609
-                                else:
-                                    self.wal.write((peer_id, msg))
-                            self._handle_vote_msgs(votes, finish)
-                        finally:
-                            # the tail was already dequeued — it must not
-                            # be lost to a WAL or vote-handling exception
-                            if tail is not None:
-                                self._handle_item(tail)
+                        self._vote_run(item[1])
                     elif item[0] == "msg" and isinstance(
                             item[1][1], HandelContributionMessage):
                         # same drain idiom for Handel contributions: a
@@ -491,6 +475,53 @@ class ConsensusState:
                     LOG.exception("error in consensus receive loop")
         finally:
             self._stopped.set()
+
+    def _vote_run(self, first) -> None:
+        """One drained run of VoteMessages, `first` at its head: the
+        contiguous run queued behind it is drained, its batch dispatched
+        BEFORE the run's WAL writes (the fsync'd writes overlap the
+        device round trip), then tallied. The run is one
+        `consensus.voteRun` span, from the drain to the last vote's
+        tally, and one sample of `vote_run_size`. A non-vote message
+        that ended the drain is handled after it, whatever happened."""
+        height = self.rs.height
+        tail = None
+        try:
+            with self.tracer.span("consensus.voteRun", cat="consensus",
+                                  request=("block", height),
+                                  height=height) as sp:
+                votes = [first]
+                while len(votes) < MAX_VOTE_BATCH:
+                    try:
+                        nxt = self._queue.get_nowait()
+                    except queue.Empty:
+                        break
+                    if nxt[0] == "msg" and isinstance(nxt[1][1], VoteMessage):
+                        votes.append(nxt[1])
+                    else:
+                        tail = nxt
+                        break
+                self.metrics.vote_run_size.observe(len(votes))
+                if self.tracer.enabled:
+                    pre = sum(1 for _, m in votes
+                              if m.vote.type == VOTE_TYPE_PREVOTE)
+                    sp.set(n=len(votes), prevotes=pre,
+                           precommits=len(votes) - pre)
+                finish = None
+                if len(votes) > 1:
+                    finish = self._preverify_votes_begin(
+                        [m.vote for _, m in votes])
+                for peer_id, msg in votes:
+                    if peer_id == "":
+                        self.wal.write_sync((peer_id, msg))  # :604-609
+                    else:
+                        self.wal.write((peer_id, msg))
+                self._handle_vote_msgs(votes, finish)
+        finally:
+            # the tail was already dequeued: it must not be lost to a
+            # WAL or vote-handling exception
+            if tail is not None:
+                self._handle_item(tail)
 
     @contextmanager
     def _mutating(self):
@@ -1307,10 +1338,12 @@ class ConsensusState:
     def _try_add_vote(self, vote: Vote, peer_id: str, verified: bool = False) -> bool:
         """reference tryAddVote :1468-1493 — conflicting votes become
         evidence. verified=True: signature already checked by the batched
-        pre-verification in _handle_vote_msgs."""
+        pre-verification in _handle_vote_msgs. Every vote is counted
+        once in `votes_total{result}`."""
         try:
             return self._add_vote(vote, peer_id, verified=verified)
         except ErrVoteConflictingVotes as e:
+            self._count_vote["duplicate"]()
             if self.priv_validator is not None and vote.validator_address == self.priv_validator.get_address():
                 LOG.error("found conflicting vote from ourselves: %s", vote)
                 return False
@@ -1324,18 +1357,23 @@ class ConsensusState:
                     )
             return False
         except ErrVoteInvalid as e:
+            self._count_vote["bad_signature" if isinstance(e, ErrVoteBadSignature)
+                             else "unmapped"]()
             LOG.warning("invalid vote from %s: %s", peer_id or "self", e)
             return False
 
     def _add_vote(self, vote: Vote, peer_id: str, verified: bool = False) -> bool:
         """reference addVote :1495-1639"""
         rs = self.rs
+        count = self._count_vote
 
         # late precommit for the previous height (reference :1504-1527)
         if vote.height + 1 == rs.height:
             if not (vote.type == VOTE_TYPE_PRECOMMIT and rs.step == STEP_NEW_HEIGHT and rs.last_commit is not None):
+                count["unmapped"]()
                 return False
             added = rs.last_commit.add_vote(vote, verified=verified)
+            count["added" if added else "duplicate"]()
             if added:
                 LOG.debug("added late precommit to last commit: %s", rs.last_commit)
                 self.timeline.mark_vote(vote.height, "precommit",
@@ -1350,11 +1388,20 @@ class ConsensusState:
 
         if vote.height != rs.height:
             LOG.debug("vote ignored: wrong height %d vs %d", vote.height, rs.height)
+            count["unmapped"]()
             return False
 
         added = rs.votes.add_vote(vote, peer_id, verified=verified)
         if not added:
+            # False: the same vote again, or a round the height keeps no
+            # set for (a peer's third catch-up round)
+            vs = (rs.votes.prevotes(vote.round) if vote.type == VOTE_TYPE_PREVOTE
+                  else rs.votes.precommits(vote.round))
+            held = (vs is not None
+                    and vs.get_by_index(vote.validator_index) is not None)
+            count["duplicate" if held else "unmapped"]()
             return False
+        count["added"]()
         self.timeline.mark_vote(
             vote.height,
             "prevote" if vote.type == VOTE_TYPE_PREVOTE else "precommit",

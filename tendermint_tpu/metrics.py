@@ -62,6 +62,13 @@ class ConsensusMetrics:
     # Handel-lite lane: gossiped aggregate precommit certificates that
     # verified and advanced our running aggregate (merged)
     agg_gossip_merges: object = NOP
+    # the live vote path (consensus/reactor.py receive, consensus/
+    # state.py's drain): VoteMessages received, labeled type=prevote|
+    # precommit; what the tally made of each, labeled result=added|
+    # bad_signature|duplicate|unmapped; votes in one drained run
+    votes_received: object = NOP
+    votes: object = NOP
+    vote_run_size: object = NOP
 
 
 @dataclass
@@ -521,6 +528,20 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             f"{ns}_consensus_agg_gossip_merges_total",
             "Gossiped aggregate precommit certificates merged into the "
             "running aggregate (BLS fast lane)."),
+        votes_received=r.counter(
+            f"{ns}_consensus_votes_received_total",
+            "Vote messages received from peers, by vote type.",
+            ("type",)),
+        votes=r.counter(
+            f"{ns}_consensus_votes_total",
+            "Votes the tally took or refused: added, bad_signature, "
+            "duplicate, unmapped (no height, round or validator to hold "
+            "it).", ("result",)),
+        vote_run_size=r.histogram(
+            f"{ns}_consensus_vote_run_size",
+            "Votes in one run the receive loop drained and verified as "
+            "one batch.",
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)),
     )
     p2p = P2PMetrics(
         peers=r.gauge(f"{ns}_p2p_peers", "Number of connected peers."),

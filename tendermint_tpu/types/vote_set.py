@@ -21,6 +21,10 @@ class ErrVoteInvalid(Exception):
     pass
 
 
+class ErrVoteBadSignature(ErrVoteInvalid):
+    """A vote that maps to its validator and whose signature is refused."""
+
+
 # Aggregate-certificate DoS bounds (Handel-lite lane): a certificate
 # claiming fewer signers than this rides the per-vote path instead of
 # paying a pairing; a peer whose certificates fail verification this
@@ -88,7 +92,7 @@ class VoteSet:
             if conflict == "dup":
                 return False
             if not verified and not vote.verify(self.chain_id, val.pub_key):
-                raise ErrVoteInvalid(f"invalid signature on {vote}")
+                raise ErrVoteBadSignature(f"invalid signature on {vote}")
             if conflict is not None:
                 raise ErrVoteConflictingVotes(conflict, vote)
             self._add_verified_locked(vote, val.voting_power)
@@ -136,7 +140,7 @@ class VoteSet:
             if first_conflict is not None:
                 raise ErrVoteConflictingVotes(first_conflict[0], first_conflict[1])
             if first_invalid is not None:
-                raise ErrVoteInvalid(f"invalid signature on {first_invalid}")
+                raise ErrVoteBadSignature(f"invalid signature on {first_invalid}")
             return added
 
     def _precheck(self, vote: Optional[Vote]) -> None:
