@@ -356,6 +356,7 @@ REQUIRED_FAMILIES = (
     "consensus_votes_received_total",
     "consensus_votes_total",
     "consensus_vote_run_size",
+    "consensus_wal_records_total",
 )
 
 # families served under their own names, with no namespace: PR-38 the

@@ -7,7 +7,7 @@ via message_to_obj/message_from_obj.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional
 
 from ..libs.bit_array import BitArray
@@ -60,6 +60,13 @@ class BlockPartMessage:
 @dataclass
 class VoteMessage:
     vote: Vote
+    # Not part of the message: the bytes it was received as, kept by the
+    # reactor that decoded them so that the WAL splices them into the
+    # vote's record and does not encode the vote again. The record
+    # replays as what was decoded from them; for a peer of this program
+    # (serde is deterministic) they are encode_msg()'s, byte for byte.
+    arrived_as: Optional[bytes] = field(default=None, repr=False,
+                                        compare=False)
 
 
 @dataclass

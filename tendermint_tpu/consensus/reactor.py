@@ -370,6 +370,20 @@ class PeerState:
                 if prs.last_commit is None:
                     prs.last_commit = BitArray(num_validators)
 
+    def has_vote_bit_arrays(self, height: int) -> bool:
+        """Would ensure_vote_bit_arrays(height, ·) and (height - 1, ·)
+        both leave the peer's state as it is? (The vote path skips them
+        then: a height's votes after its first find every array made.)"""
+        with self._lock:
+            prs = self.prs
+            return (prs.height == height and prs.prevotes is not None
+                    and prs.precommits is not None
+                    and prs.last_commit is not None
+                    and (prs.catchup_commit is not None
+                         or prs.catchup_commit_round < 0)
+                    and (prs.proposal_pol is not None
+                         or prs.proposal_pol_round < 0))
+
     # -- internals -----------------------------------------------------
 
     def _set_has_vote_locked(self, height: int, round_: int, type_: int, index: int) -> None:
@@ -688,13 +702,17 @@ class ConsensusReactor(Reactor):
             if self.fast_sync:
                 return
             if isinstance(msg, VoteMessage):
+                # the WAL splices these bytes into the vote's record
+                msg.arrived_as = msg_bytes
                 count = self._count_received.get(msg.vote.type)
                 if count is not None:
                     count()
-                rs = self.cs.get_round_state()
-                n = len(rs.validators) if rs.validators else 0
-                ps.ensure_vote_bit_arrays(rs.height, n)
-                ps.ensure_vote_bit_arrays(rs.height - 1, n)
+                # three integers, not a RoundState copy (reactor.go
+                # Receive: cs.Height, Validators.Size(), LastCommit.Size())
+                h, n, last_n = self.cs.vote_sizes
+                if not ps.has_vote_bit_arrays(h):
+                    ps.ensure_vote_bit_arrays(h, n)
+                    ps.ensure_vote_bit_arrays(h - 1, last_n)
                 ps.set_has_vote(msg.vote)
                 self.cs.add_peer_message(msg, peer.id)
             elif isinstance(msg, AggregateCommitMessage):

@@ -20,7 +20,8 @@ batch of 1 → serial CPU verify, zero added latency; heavy traffic
 (VoteSet.add_votes for commit reconstruction, ValidatorSet.verify_commit
 for fast sync) rides the same engine. With [crypto] async_dispatch on,
 the drained run's batch is dispatched (verify_async) BEFORE its WAL
-writes, so the fsync overlaps the device round trip.
+write, so the write (and an internal vote's fsync) overlaps the device
+round trip.
 
 What the vote path records (README "Spans"): a drained run is one
 `consensus.voteRun` span, from the drain to the last vote's tally, and
@@ -166,6 +167,12 @@ class ConsensusState:
         # the time and every gossip tick would skip — catch-up
         # starves.
         self._rs_published = None  # Optional[(gen, RoundState)]
+        # (height, validators' size, last commit's size): all the
+        # reactor's vote bookkeeping reads of RoundState (reference
+        # Receive's VoteChannel: cs.Height, cs.Validators.Size(),
+        # cs.LastCommit.Size()), one tuple swapped whole (GIL) wherever
+        # the receive loop replaces one of them, so a vote costs no copy
+        self.vote_sizes = (0, 0, 0)
         self.state = None  # set by update_to_state
 
         # message queues (reference :38 msgQueueSize=1000)
@@ -338,6 +345,7 @@ class ConsensusState:
         rs.last_commit = last_precommits
         rs.last_validators = state.last_validators
         rs.triggered_timeout_precommit = False
+        self._publish_vote_sizes()
 
         if self.handel is not None:
             self.handel.advance_height(height)
@@ -376,6 +384,7 @@ class ConsensusState:
             if not last_precommits.has_two_thirds_majority():
                 raise RuntimeError("reconstructed LastCommit lacks +2/3")
             self.rs.last_commit = last_precommits
+            self._publish_vote_sizes()
             return
         votes = [v for v in seen.precommits if v is not None]
         # bulk path: ONE batched (TPU) verification for the whole commit.
@@ -389,6 +398,13 @@ class ConsensusState:
         if not last_precommits.has_two_thirds_majority():
             raise RuntimeError("reconstructed LastCommit lacks +2/3")
         self.rs.last_commit = last_precommits
+        self._publish_vote_sizes()
+
+    def _publish_vote_sizes(self) -> None:
+        rs = self.rs
+        self.vote_sizes = (
+            rs.height, len(rs.validators) if rs.validators else 0,
+            rs.last_commit.size() if rs.last_commit is not None else 0)
 
     def _new_step(self) -> None:
         rs = self.get_round_state()
@@ -479,8 +495,9 @@ class ConsensusState:
     def _vote_run(self, first) -> None:
         """One drained run of VoteMessages, `first` at its head: the
         contiguous run queued behind it is drained, its batch dispatched
-        BEFORE the run's WAL writes (the fsync'd writes overlap the
-        device round trip), then tallied. The run is one
+        BEFORE the run's WAL write (one for the peers' votes; the write
+        and any internal vote's fsync overlap the device round trip),
+        then tallied. The run is one
         `consensus.voteRun` span, from the drain to the last vote's
         tally, and one sample of `vote_run_size`. A non-vote message
         that ended the drain is handled after it, whatever happened."""
@@ -511,11 +528,9 @@ class ConsensusState:
                 if len(votes) > 1:
                     finish = self._preverify_votes_begin(
                         [m.vote for _, m in votes])
-                for peer_id, msg in votes:
-                    if peer_id == "":
-                        self.wal.write_sync((peer_id, msg))  # :604-609
-                    else:
-                        self.wal.write((peer_id, msg))
+                # one write for the run's peer votes, each spliced from
+                # the bytes it arrived as; an internal one fsync'd alone
+                self.wal.write_run(votes)  # :604-609
                 self._handle_vote_msgs(votes, finish)
         finally:
             # the tail was already dequeued: it must not be lost to a
@@ -558,10 +573,7 @@ class ConsensusState:
                 with self._mutating():
                     self._handle_msg(msg, peer_id)
                 return
-            if peer_id == "":
-                self.wal.write_sync((peer_id, msg))  # :604-609
-            else:
-                self.wal.write((peer_id, msg))
+            self.wal.write_run([payload])  # :604-609
             with self._mutating():
                 self._handle_msg(msg, peer_id)
         elif kind == "timeout":
@@ -803,6 +815,7 @@ class ConsensusState:
             rs.round = round_
             rs.step = STEP_NEW_ROUND
             rs.validators = validators
+            self._publish_vote_sizes()
             if round_ != 0:
                 # round 0 fields were set in update_to_state (reference :760-768)
                 rs.proposal = None

@@ -69,6 +69,10 @@ class ConsensusMetrics:
     votes_received: object = NOP
     votes: object = NOP
     vote_run_size: object = NOP
+    # the WAL records the receive loop wrote for peers' messages
+    # (consensus/wal.py write_run), labeled source=arrived (spliced from
+    # the bytes a vote was received as) | encoded (built from the message)
+    wal_records: object = NOP
 
 
 @dataclass
@@ -542,6 +546,11 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             "Votes in one run the receive loop drained and verified as "
             "one batch.",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)),
+        wal_records=r.counter(
+            f"{ns}_consensus_wal_records_total",
+            "WAL records written for peers' messages: arrived (spliced "
+            "from the bytes a vote was received as) or encoded.",
+            ("source",)),
     )
     p2p = P2PMetrics(
         peers=r.gauge(f"{ns}_p2p_peers", "Number of connected peers."),

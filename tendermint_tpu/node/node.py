@@ -417,7 +417,8 @@ class Node:
                 wal_path = config.consensus.wal_file(root)
                 os.makedirs(os.path.dirname(wal_path), exist_ok=True)
                 wal = WAL(wal_path,
-                          corrupted_counter=self.metrics.consensus.wal_corrupted)
+                          corrupted_counter=self.metrics.consensus.wal_corrupted,
+                          records_counter=self.metrics.consensus.wal_records)
                 if self.fault_injector is not None:
                     from ..libs.storagechaos import wrap_wal
 

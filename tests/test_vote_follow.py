@@ -179,7 +179,8 @@ def _follow(chain: Chain, tmp_path, monkeypatch, batched: bool) -> dict:
         cs = ConsensusState(ConsensusConfig(), state,
                             sm.BlockExecutor(db, conns.consensus, event_bus=bus),
                             store, event_bus=bus,
-                            wal=WAL(str(tmp_path / f"wal-{batched}")),
+                            wal=WAL(str(tmp_path / f"wal-{batched}"),
+                                    records_counter=metrics.wal_records),
                             metrics=metrics)
         reactor = ConsensusReactor(cs)
         peer = _Peer()
@@ -211,6 +212,10 @@ def _follow(chain: Chain, tmp_path, monkeypatch, batched: bool) -> dict:
             bus.stop()
             conns.stop()
         hist = metrics.vote_run_size
+        logged = WAL(cs.wal.group.head_path)
+        wal_votes = [m for m in logged.iter_messages()
+                     if isinstance(m, tuple) and isinstance(m[1], VoteMessage)]
+        logged.group.close()
         return {
             "hashes": {h: store.load_block_meta(h).block_id.hash
                        for h in range(1, HEIGHTS + 1)},
@@ -226,6 +231,8 @@ def _follow(chain: Chain, tmp_path, monkeypatch, batched: bool) -> dict:
             "runs": len(runs),
             "run_count": hist._totals.get((), 0),
             "run_sum": hist._sums.get((), 0.0),
+            "wal_records": dict(metrics.wal_records._vals),
+            "wal_votes": wal_votes,
         }
 
 
@@ -273,3 +280,16 @@ def test_the_vote_path_counts_what_was_sent_and_refused(
     assert got["results"] == {("added",): 2 * n - 2, ("bad_signature",): 2}
     assert got["run_count"] == got["runs"]
     assert got["run_sum"] == 2 * n
+
+
+@pytest.mark.parametrize("batched", [True, False])
+def test_every_peer_vote_is_logged_from_the_bytes_it_arrived_as(
+        chain, tmp_path, monkeypatch, batched):
+    got = _follow(chain, tmp_path, monkeypatch, batched=batched)
+    pushed = [m for h in range(1, HEIGHTS + 1) for _, m in chain.pushes[h]]
+    sent = [(_Peer.id, m) for m in pushed if isinstance(m, VoteMessage)]
+    # the proposals and block parts are encoded again
+    assert got["wal_records"] == {("arrived",): len(sent),
+                                  ("encoded",): len(pushed) - len(sent)}
+    # in the order received, and each replays as the vote that was sent
+    assert got["wal_votes"] == sent
