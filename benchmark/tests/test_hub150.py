@@ -1,6 +1,6 @@
 """The cell `hub150-follow` (configuration `hub-150val`): it resolves from
-the manifest with one chip and its own files, every `.hub150` metric is
-a twin of an accepted metric or one of the vote path's own, and the
+the manifest with one chip and its own files, it reads the vote path's own
+per-layer entries and the entries it shares with the two-peer cell, and the
 chain `harness/rounds.py` builds is the network's: the program's
 proposer rotation, a proposal its proposer signed, prevotes and
 precommits every one of which verifies, and precommits that are the
@@ -23,11 +23,26 @@ from benchmark.harness import rounds as roundslib
 from benchmark.harness.reference import verify_one
 from benchmark.tests import faults, toy
 
-CELL, TWINS_OF = "hub150-follow", "sync10k-light-2peer"
+CELL, SHARES_WITH = "hub150-follow", "sync10k-light-2peer"
 OWN = {"vote_run_mean.hub150", "votes_added_per_100_received.hub150",
        "sig_cache_hit_pct.hub150"}
-KERNEL = {"ed25519_verify_roofline.hub150"}
-TWINS = {"device_route_pct.hub150"}
+# the vote run's share of the window, its wait for the mask, its WAL
+# writes, and its own thread's CPU a height
+VOTE_SPANS = {
+    "vote_run_pct.hub150": ("span_time", {"name": "consensus.voteRun",
+                                          "what": "pct_of_window"}),
+    "preverify_wait_pct.hub150": ("span_time", {
+        "name": "consensus.preverifyVotes", "what": "pct_of_window"}),
+    "wal_write_pct.hub150": ("span_time", {"name": "wal.write",
+                                           "what": "pct_of_window"}),
+    "vote_run_cpu_ms_per_block.hub150": ("span_cpu", {
+        "name": "consensus.voteRun", "what": "cpu_ms", "per": "request"})}
+# entries the fast-sync cells read as well: device, runtime, compile-once,
+# batch funnel and kernel
+SHARED = {"device_route_pct.sync", "device_idle_pct.sync",
+          "host_unattributed_pct.sync", "gc_pause_pct.sync",
+          "host_cpu_ms_per_block.sync", "compiles_in_window", "kernel_ready_s",
+          "ed25519_verify_roofline.sync10k", "ed25519_verify_us_per_sig.sync10k"}
 
 
 def test_the_cell_resolves_with_one_chip_and_its_own_files():
@@ -49,25 +64,26 @@ def test_the_cell_resolves_with_one_chip_and_its_own_files():
 
 def test_every_hub150_metric_is_a_twin_or_the_vote_paths_own():
     cell = manifest.Cell(CELL)
-    assert all(m["name"].endswith(".hub150") and m["workloads"] == [CELL]
-               for m in cell.per_layer)
-    twins = {m["name"]: m for m in manifest.Cell(TWINS_OF).per_layer}
-    kv = {m["name"]: m for m in manifest.Cell("kv-signed-steady").per_layer}
-    names = {m["name"] for m in cell.per_layer}
-    assert names == OWN | KERNEL | TWINS
+    by = {m["name"]: m for m in cell.per_layer}
+    assert set(by) == OWN | set(VOTE_SPANS) | SHARED
+    two_peer = {m["name"] for m in manifest.Cell(SHARES_WITH).per_layer}
+    assert SHARED <= two_peer
     for m in cell.per_layer:
         assert callable(importlib.import_module(
             f"benchmark.readers.{m['reader']}").read)
-        if m["name"] in OWN:
-            assert m["moves"] == "sync_blocks_per_s"
+        if m["name"] in SHARED:
+            assert SHARES_WITH in m["workloads"]
             continue
-        stem = m["name"][:-len(".hub150")]
-        twin = (kv[stem + ".kv"] if m["name"] in KERNEL
-                else twins[stem + ".sync10k2p"])
-        for key in ("unit", "better", "source", "layer", "reader", "params"):
-            assert m[key] == twin[key], (m["name"], key)
-    by = {m["name"]: m for m in cell.per_layer}
-    assert by["ed25519_verify_roofline.hub150"]["params"]["pattern"] == "ed25519_verify"
+        assert m["name"].endswith(".hub150") and m["workloads"] == [CELL]
+        assert m["moves"] == "sync_blocks_per_s"
+    for name, (reader, params) in VOTE_SPANS.items():
+        m = by[name]
+        assert (m["reader"], m["params"]) == (reader, params)
+        assert (m["layer"], m["source"]) == ("consensus vote path",
+                                             "program_span")
+    assert by["ed25519_verify_roofline.sync10k"]["params"]["pattern"] == \
+        "ed25519_verify"
+    assert by["host_cpu_ms_per_block.sync"]["params"]["per"] == "fact:blocks"
     assert by["vote_run_mean.hub150"]["params"] == {
         "family": "tendermint_consensus_vote_run_size"}
 

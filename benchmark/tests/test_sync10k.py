@@ -1,8 +1,8 @@
 """The cell `sync10k-light` (configuration `sync-10kval`): it resolves
-from the manifest, every `.sync10k` metric file loads with a reader that
-imports, and its own two files, cut to a committee of 24 on the CPU, make
-a run that is `correct` and, with `accept_all` planted, one that is not.
-Nothing of this is a measurement."""
+from the manifest, every per-layer entry that names it loads with a
+reader that imports, and its own two files, cut to a committee of 24 on
+the CPU, make a run that is `correct` and, with `accept_all` planted, one
+that is not. Nothing of this is a measurement."""
 
 import importlib
 import os
@@ -35,32 +35,52 @@ def test_the_cell_resolves_with_its_own_files():
                                                     "setup_s"}
 
 
+# What the cell reads: the entries whose `workloads` name it. A reading
+# the 500-validator cells take too is their entry (a `.sync` or unsuffixed
+# name); a `.sync10k` name is a reading first written for this cell.
+SHARED = {
+    "apply_save_ms_per_block.sync", "apply_validate_ms_per_block.sync",
+    "block_save_ms_per_block.sync", "compiles_in_window",
+    "device_idle_pct.sync", "device_route_pct.sync", "gc_pause_pct.sync",
+    "host_cpu_ms_per_block.sync", "host_unattributed_pct.sync",
+    "kernel_ready_s", "p2p_recv_calls_per_100_frames.sync",
+    "save_encodings_per_100_heights.sync",
+    "sig_cache_key_hashes_per_100_lookups.sync",
+    "sync_loop_cpu_ms_per_block.sync", "sync_pool_wait_pct.sync",
+    "sync_verify_wait_ms_per_block.sync", "verify_h2d_ms_per_batch.sync",
+    "verify_lookup_cpu_ms_per_batch.sync", "verify_pack_cpu_ms_per_batch.sync",
+    "verify_pack_ms_per_batch.sync", "verify_queue_wait_ms_per_batch.sync",
+    "verify_wait_ms_per_batch.sync"}
+OWN = {"ed25519_verify_roofline.sync10k", "ed25519_verify_us_per_sig.sync10k",
+       "p2p_recv_throttled_pct.sync10k", "part_set_ms_per_block.sync10k",
+       "recv_block_ms_per_block.sync10k", "verify_begin_ms_per_block.sync10k"}
+
+
 def test_every_sync10k_metric_loads_and_its_reader_imports():
     cell = manifest.Cell(CELL)
-    mine = [m for m in cell.per_layer if m["name"].endswith(".sync10k")]
-    assert len(mine) == 21 and len(mine) == len(cell.per_layer)
-    moves = {m["name"]: m["moves"] for m in mine}
+    by = {m["name"]: m for m in cell.per_layer}
+    assert set(by) == SHARED | OWN
+    moves = {m["name"]: m["moves"] for m in cell.per_layer}
     assert {n for n, e in moves.items() if e == "setup_s"} == {
-        "compiles_in_window.sync10k", "kernel_ready_s.sync10k"}
-    for m in mine:
-        assert m["workloads"] == [CELL]
+        "compiles_in_window", "kernel_ready_s"}
+    for m in cell.per_layer:
+        assert CELL in m["workloads"]
         reader = importlib.import_module(f"benchmark.readers.{m['reader']}")
         assert callable(reader.read)
-    by = {m["name"]: m for m in mine}
+    # the 500-validator cells read every shared entry too
+    sync500 = {m["name"] for m in manifest.Cell("sync500-light").per_layer}
+    assert SHARED <= sync500 and not OWN & sync500
     for kernel in ("ed25519_verify_us_per_sig", "ed25519_verify_roofline"):
         assert by[kernel + ".sync10k"]["params"]["pattern"] == "ed25519_verify"
-    assert by["sync_pool_wait_pct.sync10k"]["params"]["what"] == "pct_of_window"
-    # PR 29: the link's share of the window (0 until the supply is cured)
+    assert by["sync_pool_wait_pct.sync"]["params"]["what"] == "pct_of_window"
+    # the link's share of the window (0 while the limiter never sleeps)
     assert by["p2p_recv_throttled_pct.sync10k"]["params"] == {
         "name": "p2p.recvThrottle", "what": "pct_of_window"}
     assert by["p2p_recv_throttled_pct.sync10k"]["layer"] == "p2p link"
     assert cell.traffic["chain_blocks_per_s"] == 3
-    # the metrics with no list before this cell keep the accepted cells
-    man = manifest.manifest()
+    # the kv cell reads the compile-once pair as well
     for name in ("compiles_in_window", "kernel_ready_s"):
-        entry = next(m for m in man["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == ["sync500-light", "kv-signed-steady",
-                                      "sync500-busy"]
+        assert "kv-signed-steady" in by[name]["workloads"]
 
 
 def _toy_cell() -> toy.ToyCell:

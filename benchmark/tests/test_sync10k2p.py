@@ -1,8 +1,8 @@
 """The cell `sync10k-light-2peer` (configuration `sync-10kval-2peer`):
 it resolves from the manifest with one chip, its configuration is
-`sync-10kval` but for the second peer and the fourth guarantee, every
-`.sync10k2p` metric file is its `.sync10k` twin or one of the two new
-ones, and the reader the cell brings (`readers/span_overlap.py`) reads
+`sync-10kval` but for the second peer and the fourth guarantee, it
+reads every per-layer entry the one-peer cell reads and three of its
+own, and the reader the cell brings (`readers/span_overlap.py`) reads
 a list of spans made here. A toy run of its files, cut to a committee
 of 24 on the CPU, is `correct`; with a signature unchecked, or with the
 pool asking again for the refused height alone (what the program did
@@ -66,23 +66,25 @@ def test_the_cell_resolves_with_one_chip_and_its_own_files():
 
 
 def test_every_sync10k2p_metric_is_a_twin_or_one_of_the_two_new():
+    # the cell reads every entry the one-peer cell reads (the same entry,
+    # under its name) and three of its own: the two that only two peers
+    # have, and the validation's own CPU
     cell = manifest.Cell(CELL)
-    assert all(m["name"].endswith(".sync10k2p") and m["workloads"] == [CELL]
-               for m in cell.per_layer)
-    one = {m["name"]: m for m in manifest.Cell(ONE_PEER).per_layer}
-    assert len(cell.per_layer) == len(one) + len(NEW) == 25
+    by = {m["name"]: m for m in cell.per_layer}
+    one = {m["name"] for m in manifest.Cell(ONE_PEER).per_layer}
+    own = set(NEW) | {"apply_validate_cpu_ms_per_block.sync10k2p"}
+    assert one <= set(by) and set(by) - one == own
     for m in cell.per_layer:
         assert callable(importlib.import_module(
             f"benchmark.readers.{m['reader']}").read)
+        if m["name"] in own:
+            assert m["workloads"] == [CELL]
+            assert m["moves"] == "sync_blocks_per_s"
+        else:
+            assert {CELL, ONE_PEER} <= set(m["workloads"])
         if m["name"] in NEW:
             assert (m["layer"], m["source"]) == NEW[m["name"]]
-            assert m["moves"] == "sync_blocks_per_s" and m["unit"] == "%"
-            continue
-        twin = one[m["name"][:-len("2p")]]
-        for key in ("unit", "better", "source", "layer", "moves", "reader",
-                    "params"):
-            assert m[key] == twin[key], (m["name"], key)
-    by = {m["name"]: m for m in cell.per_layer}
+            assert m["unit"] == "%"
     assert by["p2p_recv_all_throttled_pct.sync10k2p"]["params"] == {
         "name": "p2p.recvThrottle", "arg": "peer",
         "distinct_from_config": "peers"}
