@@ -357,6 +357,9 @@ REQUIRED_FAMILIES = (
     "consensus_votes_total",
     "consensus_vote_run_size",
     "consensus_wal_records_total",
+    # the validator's key: a signature made and its last-sign state
+    # persisted (live on any validator node)
+    "privval_signatures_total",
 )
 
 # families served under their own names, with no namespace: PR-38 the

@@ -93,6 +93,10 @@ LOG = logging.getLogger("consensus")
 # the first vote in the run and the device bucket size
 MAX_VOTE_BATCH = 1024
 
+# put in the peer queue beside each own message, to end the receive
+# loop's wait; it carries nothing
+_WAKE = ("wake", None)
+
 
 class ConsensusState:
     """The consensus machine for one node (reference ConsensusState
@@ -175,8 +179,11 @@ class ConsensusState:
         self.vote_sizes = (0, 0, 0)
         self.state = None  # set by update_to_state
 
-        # message queues (reference :38 msgQueueSize=1000)
+        # message queues (reference :38 msgQueueSize=1000): peers' messages
+        # and timeouts in one, this node's own messages (its proposal, its
+        # parts, its votes) in another that the receive loop empties first
         self._queue: "queue.Queue" = queue.Queue(maxsize=2000)
+        self._internal: "queue.Queue" = queue.Queue()
         self.ticker = TimeoutTicker()
         self._thread: Optional[threading.Thread] = None
         self._tock_thread: Optional[threading.Thread] = None
@@ -196,6 +203,14 @@ class ConsensusState:
         self.on_vote_added: Optional[Callable] = None
 
         self.n_height_committed = 0  # metrics
+        # while the recorder is on: the instant of the step transition
+        # that signs a vote (enterPrevote, enterPrecommit), and each own
+        # vote signed and not yet tallied, (height, round, type) -> that
+        # instant (consensus.ownVoteLatency); (height, round, instant)
+        # of the last enterPropose (consensus.proposalWait)
+        self._vote_cause_ns = 0
+        self._own_votes: dict = {}
+        self._propose_entered = None
         # BLS aggregate lane diagnostics (stall_snapshot / monitor)
         self.n_agg_merges = 0
         self.last_agg_cert_bytes = 0
@@ -256,8 +271,20 @@ class ConsensusState:
             LOG.warning("consensus queue full; dropping peer message")
 
     def _send_internal(self, msg) -> None:
-        # internal messages must not drop (reference sendInternalMessage :332)
-        self._queue.put(("msg", ("", msg)))
+        """Queue one of this node's own messages (reference
+        sendInternalMessage :332): it must not drop, and it waits behind
+        no peer message. The reference keeps them in internalMsgQueue, a
+        channel beside peerMsgQueue; a single queue put an own vote
+        behind every vote already queued, so a validator among 150
+        reached its prevote only after the height had committed, and the
+        tally refused it (`unmapped`): the vote never entered its own
+        vote set. The receive loop takes this queue first; the token in
+        the peer queue only ends the loop's wait for it."""
+        self._internal.put(("msg", ("", msg)))
+        try:
+            self._queue.put_nowait(_WAKE)
+        except queue.Full:
+            pass  # the loop has work queued and looks here before it
 
     def get_round_state(self) -> RoundState:
         """Stamped snapshot (shallow; the receive loop is the only
@@ -454,9 +481,12 @@ class ConsensusState:
         try:
             while not self._done.is_set():
                 try:
-                    item = self._queue.get(timeout=0.1)
+                    item = self._internal.get_nowait()
                 except queue.Empty:
-                    continue
+                    try:
+                        item = self._queue.get(timeout=0.1)
+                    except queue.Empty:
+                        continue
                 try:
                     if item[0] == "msg" and isinstance(item[1][1], VoteMessage):
                         self._vote_run(item[1])
@@ -563,6 +593,8 @@ class ConsensusState:
         # reader into torn-skip fallbacks — gossip ticks would mostly
         # no-op under load)
         kind, payload = item
+        if kind == "wake":
+            return
         if kind == "msg":
             peer_id, msg = payload
             if isinstance(msg, HandelContributionMessage):
@@ -871,6 +903,9 @@ class ConsensusState:
             with self._step_span("enterPropose", "propose", height, round_):
                 rs.round = round_
                 rs.step = STEP_PROPOSE
+                if self.tracer.enabled:
+                    self._propose_entered = (height, round_,
+                                             time.perf_counter_ns())
                 self._new_step()
 
                 self._schedule_timeout(self.config.propose(round_), height, round_, STEP_PROPOSE)
@@ -992,6 +1027,7 @@ class ConsensusState:
             return
         LOG.debug("enterPrevote(%d/%d)", height, round_)
         with self._step_span("enterPrevote", "prevote", height, round_):
+            self._vote_cause_ns = time.perf_counter_ns()
             rs.round = round_
             rs.step = STEP_PREVOTE
             self._new_step()
@@ -1058,6 +1094,7 @@ class ConsensusState:
             return
         LOG.debug("enterPrecommit(%d/%d)", height, round_)
         with self._step_span("enterPrecommit", "precommit", height, round_):
+            self._vote_cause_ns = time.perf_counter_ns()
             rs.round = round_
             rs.step = STEP_PRECOMMIT
             self._new_step()
@@ -1330,6 +1367,16 @@ class ConsensusState:
 
             rs.proposal_block = serde.decode_block(rs.proposal_block_parts.assemble())
             LOG.info("received complete proposal block %s", rs.proposal_block)
+            entered = self._propose_entered
+            if entered is not None and entered[:2] == (rs.height, rs.round):
+                # enterPropose to the proposal complete: what the height
+                # waited for its proposer (itself, when `own`)
+                self._propose_entered = None
+                self.tracer.record(
+                    "consensus.proposalWait", entered[2],
+                    time.perf_counter_ns(), "consensus",
+                    request=("block", rs.height), height=rs.height,
+                    round=rs.round, own=not peer_id)
             self.event_bus.publish_complete_proposal(self.get_round_state())
 
             prevotes = rs.votes.prevotes(rs.round)
@@ -1395,6 +1442,8 @@ class ConsensusState:
                 self.event_bus.publish_vote(vote)
                 if self.on_vote_added is not None:
                     self.on_vote_added(vote)
+                if not peer_id and self._own_votes:
+                    self._own_vote_tallied(vote)
                 if self.config.skip_timeout_commit and rs.last_commit.has_all():
                     self._enter_new_round(rs.height, 0)
             return added
@@ -1422,12 +1471,27 @@ class ConsensusState:
         self.event_bus.publish_vote(vote)
         if self.on_vote_added is not None:
             self.on_vote_added(vote)
+        if not peer_id and self._own_votes:
+            self._own_vote_tallied(vote)
 
         if vote.type == VOTE_TYPE_PREVOTE:
             self._on_prevote_added(vote)
         elif vote.type == VOTE_TYPE_PRECOMMIT:
             self._on_precommit_added(vote)
         return True
+
+    def _own_vote_tallied(self, vote: Vote) -> None:
+        """An own vote is in the tally, its WAL record fsync'd before it
+        and its HasVote published: the gossip can send it from here.
+        `consensus.ownVoteLatency` spans the step transition that signed
+        it to this instant."""
+        t0 = self._own_votes.pop((vote.height, vote.round, vote.type), None)
+        if t0 is not None:
+            self.tracer.record(
+                "consensus.ownVoteLatency", t0, time.perf_counter_ns(),
+                "consensus", request=("block", vote.height),
+                type="prevote" if vote.type == VOTE_TYPE_PREVOTE else "precommit",
+                height=vote.height, round=vote.round)
 
     def _on_prevote_added(self, vote: Vote) -> None:
         """reference addVote prevote branch :1539-1601"""
@@ -1557,13 +1621,22 @@ class ConsensusState:
         idx, _ = rs.validators.get_by_address(self.priv_validator.get_address())
         if idx < 0:
             return None  # not a validator
-        try:
-            vote = self._sign_vote(type_, hash_, header)
-        except Exception:
-            if not self._replay_mode:
-                LOG.exception("failed signing %s vote", "prevote" if type_ == VOTE_TYPE_PREVOTE else "precommit")
-            return None
-        self._send_internal(VoteMessage(vote))
+        kind = "prevote" if type_ == VOTE_TYPE_PREVOTE else "precommit"
+        with self.tracer.span("consensus.signAddVote", cat="consensus",
+                              request=("block", rs.height), type=kind,
+                              height=rs.height, round=rs.round):
+            try:
+                vote = self._sign_vote(type_, hash_, header)
+            except Exception:
+                if not self._replay_mode:
+                    LOG.exception("failed signing %s vote", kind)
+                return None
+            self._send_internal(VoteMessage(vote))
+        if self.tracer.enabled:
+            own = self._own_votes
+            for key in [k for k in own if k[0] + 1 < vote.height]:
+                del own[key]  # signed, never tallied: a height left behind
+            own[(vote.height, vote.round, type_)] = self._vote_cause_ns
         if (self.handel is not None and type_ == VOTE_TYPE_PRECOMMIT
                 and hash_ != b"" and not self._replay_mode):
             # seed the Handel session with our own precommit — level 1

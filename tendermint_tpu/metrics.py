@@ -454,6 +454,16 @@ class RuntimeMetrics:
 
 
 @dataclass
+class PrivvalMetrics:
+    """The validator's key (ours; privval/file_pv.py)."""
+
+    # signatures made and their last-sign state persisted, labeled
+    # type=prevote|precommit|proposal; a re-sign that hands out the
+    # last signature again is not counted
+    signatures: object = NOP
+
+
+@dataclass
 class NodeMetrics:
     consensus: ConsensusMetrics = field(default_factory=ConsensusMetrics)
     p2p: P2PMetrics = field(default_factory=P2PMetrics)
@@ -471,6 +481,7 @@ class NodeMetrics:
     handel: HandelMetrics = field(default_factory=HandelMetrics)
     replica: ReplicaMetrics = field(default_factory=ReplicaMetrics)
     runtime: RuntimeMetrics = field(default_factory=RuntimeMetrics)
+    privval: PrivvalMetrics = field(default_factory=PrivvalMetrics)
     registry: Optional[Registry] = None
 
 
@@ -977,9 +988,15 @@ def prometheus_metrics(namespace: str = "tendermint") -> NodeMetrics:
             "generation (gc.freeze), which no collection walks; 0 until "
             "the verify warm-up has ended and after stop()."),
     )
+    privval = PrivvalMetrics(
+        signatures=r.counter(
+            f"{ns}_privval_signatures_total",
+            "Signatures the validator's key made, its last-sign state "
+            "persisted first: prevote, precommit or proposal.", ("type",)),
+    )
     return NodeMetrics(consensus=cons, p2p=p2p, abci=abci_m, mempool=mem,
                        state=state, crypto=crypto, statesync=statesync,
                        rpc=rpc, lockdep=lockdep, recovery=recovery,
                        determinism=determinism, incident=incident,
                        handel=handel, replica=replica, runtime=runtime,
-                       registry=r)
+                       privval=privval, registry=r)

@@ -157,6 +157,8 @@ class Node:
         else:
             self.metrics = nop_metrics()
         self._metrics_server = None
+        if hasattr(priv_validator, "signatures"):  # a FilePV, not a socket
+            priv_validator.signatures = self.metrics.privval.signatures
 
         # observability plumbing (ours; the reference's MetricsProvider
         # stops at per-reactor metrics): the crypto BatchVerifier sink

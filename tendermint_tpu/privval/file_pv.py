@@ -16,7 +16,8 @@ import tempfile
 import threading
 from typing import Optional
 
-from ..libs import fail
+from ..libs import fail, tracing
+from ..metrics import NOP
 
 from ..crypto import (
     PrivKey,
@@ -39,6 +40,10 @@ from ..types.basic import (
 STEP_PROPOSE = 1
 STEP_PREVOTE = 2
 STEP_PRECOMMIT = 3
+
+
+# the `type` of a `privval.sign` span and of privval_signatures_total
+_KIND = {VOTE_TYPE_PREVOTE: "prevote", VOTE_TYPE_PRECOMMIT: "precommit"}
 
 
 def vote_to_step(vote: Vote) -> int:
@@ -66,6 +71,9 @@ class FilePV:
         self.last_signature: bytes = b""
         self.last_sign_bytes: bytes = b""
         self._lock = threading.Lock()
+        # signatures this key made, labeled type (metrics
+        # privval_signatures_total; the node wires its counter in)
+        self.signatures = NOP
 
     # --- PrivValidator interface -------------------------------------------
 
@@ -77,13 +85,20 @@ class FilePV:
 
     def sign_vote(self, chain_id: str, vote: Vote) -> None:
         """Signs vote in place; raises DoubleSignError on regression
-        (reference priv_validator.go:176-183 → signVote :206-254)."""
-        with self._lock:
-            self._sign_vote(chain_id, vote)
+        (reference priv_validator.go:176-183 → signVote :206-254).
+        One `privval.sign` span, the persist of the last-sign state a
+        `privval.save` inside it; `resigned` says the signature was the
+        last one handed out again."""
+        with self._lock, tracing.span(
+                "privval.sign", cat="privval", type=_KIND.get(vote.type),
+                height=vote.height, round=vote.round) as sp:
+            sp.set(resigned=self._sign_vote(chain_id, vote))
 
     def sign_proposal(self, chain_id: str, proposal: Proposal) -> None:
-        with self._lock:
-            self._sign_proposal(chain_id, proposal)
+        with self._lock, tracing.span(
+                "privval.sign", cat="privval", type="proposal",
+                height=proposal.height, round=proposal.round) as sp:
+            sp.set(resigned=self._sign_proposal(chain_id, proposal))
 
     # --- internals ----------------------------------------------------------
 
@@ -108,7 +123,8 @@ class FilePV:
                     return True
         return False
 
-    def _sign_vote(self, chain_id: str, vote: Vote) -> None:
+    def _sign_vote(self, chain_id: str, vote: Vote) -> bool:
+        """Signs in place; True where the last signature was reused."""
         height, round_, step = vote.height, vote.round, vote_to_step(vote)
         same_hrs = self._check_hrs(height, round_, step)
         sign_bytes = vote.sign_bytes(chain_id)
@@ -118,14 +134,14 @@ class FilePV:
             # differs (crash between sign and broadcast; reference :233-247)
             if sign_bytes == self.last_sign_bytes:
                 vote.signature = self.last_signature
-                return
+                return True
             ts = _vote_only_differs_by_timestamp(
                 chain_id, self.last_sign_bytes, vote
             )
             if ts is not None:
                 vote.timestamp = ts
                 vote.signature = self.last_signature
-                return
+                return True
             raise DoubleSignError(
                 f"conflicting vote data at the same HRS {height}/{round_}/{step}"
             )
@@ -133,8 +149,10 @@ class FilePV:
         sig = self.priv_key.sign(sign_bytes)
         self._save_signed(height, round_, step, sign_bytes, sig)
         vote.signature = sig
+        self.signatures.with_labels(_KIND[vote.type]).inc()
+        return False
 
-    def _sign_proposal(self, chain_id: str, proposal: Proposal) -> None:
+    def _sign_proposal(self, chain_id: str, proposal: Proposal) -> bool:
         height, round_, step = proposal.height, proposal.round, STEP_PROPOSE
         same_hrs = self._check_hrs(height, round_, step)
         sign_bytes = proposal.sign_bytes(chain_id)
@@ -142,14 +160,14 @@ class FilePV:
         if same_hrs:
             if sign_bytes == self.last_sign_bytes:
                 proposal.signature = self.last_signature
-                return
+                return True
             ts = _proposal_only_differs_by_timestamp(
                 chain_id, self.last_sign_bytes, proposal
             )
             if ts is not None:
                 proposal.timestamp = ts
                 proposal.signature = self.last_signature
-                return
+                return True
             raise DoubleSignError(
                 f"conflicting proposal data at the same HRS {height}/{round_}/{step}"
             )
@@ -157,6 +175,8 @@ class FilePV:
         sig = self.priv_key.sign(sign_bytes)
         self._save_signed(height, round_, step, sign_bytes, sig)
         proposal.signature = sig
+        self.signatures.with_labels("proposal").inc()
+        return False
 
     def _save_signed(self, height: int, round_: int, step: int, sign_bytes: bytes, sig: bytes) -> None:
         """Persist-before-release (reference :256-280). A crash here —
@@ -169,7 +189,8 @@ class FilePV:
         self.last_signature = sig
         self.last_sign_bytes = sign_bytes
         fail.fail_point("Privval.BeforeSignStateSave")
-        self.save()
+        with tracing.span("privval.save", cat="privval"):
+            self.save()
 
     # --- persistence --------------------------------------------------------
 
